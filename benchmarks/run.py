@@ -39,6 +39,8 @@ def _write_artifacts():
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (
         cost_analysis, fig5_reliability, fig12_throughput, fig13_breakdown,
         fig14_ablation, fig15_dse, fig16_energy, kernels_bench,

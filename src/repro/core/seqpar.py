@@ -23,35 +23,10 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-try:  # jax >= 0.5 re-exports shard_map at top level
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-import inspect as _inspect
-
-if "check_vma" in _inspect.signature(_shard_map_impl).parameters:
-    shard_map = _shard_map_impl
-else:  # jax 0.4.x: replication check is `check_rep`, manual axes via `auto`
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True,
-                  axis_names=None, **kw):
-        if axis_names is not None:
-            kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=check_vma,
-                               **kw)
 
 from repro.kernels.flash_attention.ref import NEG_INF
-
-
-def _axis_size(name):
-    """jax.lax.axis_size where available; psum(1, axis) on jax 0.4.x
-    (constant-folds to the same static int inside shard_map)."""
-    try:
-        return jax.lax.axis_size(name)
-    except AttributeError:
-        return jax.lax.psum(1, name)
 
 
 # ---------------------------------------------------------------------------
@@ -142,7 +117,7 @@ def ring_attention_local(q, k, v, *, axis_name: str, causal: bool,
     [i·Sl, (i+1)·Sl).  n_dev-1 ppermutes stream every KV chunk past every
     q chunk; online softmax merges partials.
     """
-    n_dev = _axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     B, Sl, H, dh = q.shape
     q_pos = idx * Sl + jnp.arange(Sl)
@@ -196,7 +171,7 @@ def _shard_page_offset(page_axes: Sequence[str], np_local: int):
     """Linearized first-local-page index of this shard."""
     idx = 0
     for a in page_axes:
-        idx = idx * _axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx * np_local
 
 

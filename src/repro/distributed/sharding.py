@@ -10,21 +10,18 @@ from __future__ import annotations
 from typing import Any, Dict, Mapping, Optional, Sequence, Tuple, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 Rules = Mapping[str, Union[str, Tuple[str, ...], None]]
 
 
-def make_mesh_compat(shape: Sequence[int], axes: Sequence[str],
-                     devices=None) -> Mesh:
-    """`jax.make_mesh` with Auto axis types where the jax version has them
-    (>= 0.5); plain mesh otherwise (0.4.x has no axis_types kwarg)."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    except (ImportError, TypeError):
-        return jax.make_mesh(tuple(shape), tuple(axes), devices=devices)
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices=None) -> Mesh:
+    """`jax.make_mesh` with Auto axes: shardings propagate through jit
+    and logical-axis rules, as every mesh in this repo assumes (jax's own
+    default is Explicit axes)."""
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 # ---------------------------------------------------------------------------
 # Rule sets
@@ -150,10 +147,11 @@ def constrain(x, axes: Sequence[Optional[str]], rules: Rules,
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-def get_current_mesh() -> Optional[Mesh]:
-    try:
-        env = jax.interpreters.pxla.thread_resources.env
-        m = env.physical_mesh
-        return None if m.empty else m
-    except Exception:
-        return None
+def get_current_mesh() -> Optional[jax.sharding.AbstractMesh]:
+    """The ambient mesh installed by `jax.set_mesh` (None without one).
+
+    The abstract mesh, which is what jit traces see: axis names, sizes
+    and types (Manual inside a `shard_map`), enough for specs and
+    nested `shard_map`s."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 
 NEG_INF = -1e30
 
@@ -125,7 +124,7 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, dh), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
     )(q, k, v)

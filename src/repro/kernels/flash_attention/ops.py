@@ -48,10 +48,16 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = 512,
 ) -> jax.Array:
+    dynamic = is_global is not None or not isinstance(q_offset, int)
     if impl == "auto":
-        impl = default_impl()
-    if impl == "ref" or is_global is not None or not isinstance(q_offset, int):
-        # dynamic window toggles / traced offsets take the jnp path
+        # dynamic window toggles / traced offsets have no kernel
+        impl = "ref" if dynamic else default_impl()
+    elif dynamic and impl != "ref":
+        raise ValueError(
+            f"impl={impl!r}: the flash kernel takes a static window and "
+            "offset; dynamic flags (is_global) or a traced q_offset run "
+            "only with impl='ref' or 'auto'")
+    if impl == "ref":
         return flash_attention_ref(q, k, v, causal=causal, window=window,
                                    q_offset=q_offset, is_global=is_global,
                                    chunk_k=block_k)

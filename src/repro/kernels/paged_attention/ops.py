@@ -156,7 +156,13 @@ def paged_attention_partial(
     """
     _check_impl(impl)
     if impl == "auto":
-        impl = default_impl()
+        # dynamic local/global flags (scanned layers) have no kernel
+        impl = "ref" if is_global is not None else default_impl()
+    elif is_global is not None and impl != "ref":
+        raise ValueError(
+            f"impl={impl!r}: the paged kernels take a static window; "
+            "dynamic per-layer flags (is_global) run only with impl='ref' "
+            "or 'auto'")
     B, H, dh = q.shape
     shared = page_table is not None
     K = k_pages.shape[0] if shared else k_pages.shape[1]
@@ -164,8 +170,7 @@ def paged_attention_partial(
     NP = page_table.shape[1] if shared else k_pages.shape[2]
     P = resolve_partitions(partitions, NP)
 
-    if impl == "ref" or is_global is not None:
-        # dynamic local/global flags (scanned layers) take the jnp path
+    if impl == "ref":
         def piece(lo, npp):
             sl = lambda a, axis: jax.lax.dynamic_slice_in_dim(a, lo, npp,
                                                               axis)

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 
 def _kernel_w4(x_ref, q_ref, s_ref, o_ref, acc_scr, *, n_d: int):
     idx = pl.program_id(1)
@@ -95,6 +93,6 @@ def quant_gemv_pallas(x, q, scale, scheme: str, *, block_d: int = 512,
         out_shape=jax.ShapeDtypeStruct((M, F), out_dtype),
         scratch_shapes=[pltpu.VMEM((M, bf), jnp.float32)],
         interpret=interpret,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(x, q, scale)

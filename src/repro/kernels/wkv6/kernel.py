@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 
 def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, sT_ref,
             state_scr, *, chunk: int, n_chunks: int):
@@ -102,6 +100,6 @@ def wkv6_pallas(r, k, v, logw, u, s0, *, chunk: int = 32,
         ],
         scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)],
         interpret=interpret,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(r, k, v, logw, u, s0)

@@ -57,7 +57,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                 raise TimeoutError(f"cell exceeded {cell_timeout}s")
             signal.signal(signal.SIGALRM, _on_alarm)
             signal.alarm(cell_timeout)
-        with mesh:
+        with jax.set_mesh(mesh):
             cell = build_cell(arch, shape_name, mesh, multi_pod=multi_pod,
                               eng_overrides=eng_overrides)
             lowered = cell.jitted.lower(*cell.abstract_args)

@@ -10,13 +10,13 @@ from typing import Optional
 import jax
 from jax.sharding import Mesh
 
-from repro.distributed.sharding import make_mesh_compat
+from repro.distributed.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def mesh_from_devices(devices=None, model_parallel: int = 0) -> Mesh:
@@ -35,14 +35,8 @@ def mesh_from_devices(devices=None, model_parallel: int = 0) -> Mesh:
             m *= 2
         model_parallel = m
     assert n % model_parallel == 0, (n, model_parallel)
-    import numpy as np
-    arr = np.asarray(devices).reshape(n // model_parallel, model_parallel)
-    try:
-        from jax.sharding import AxisType
-        return Mesh(arr, ("data", "model"),
-                    axis_types=(AxisType.Auto, AxisType.Auto))
-    except (ImportError, TypeError):
-        return Mesh(arr, ("data", "model"))
+    return make_mesh((n // model_parallel, model_parallel),
+                     ("data", "model"), devices=devices)
 
 
 def mesh_axis_size(mesh: Optional[Mesh], name: str) -> int:

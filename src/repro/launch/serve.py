@@ -10,10 +10,12 @@ from __future__ import annotations
 import argparse
 import time
 
+import jax
 import numpy as np
 
 from repro.configs import EngineConfig
 from repro.core.dse import recommend_engine_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.api import (KVNANDServer, SamplingParams, ServerConfig,
                                accepted_tokens_per_step,
                                latency_percentile)
@@ -78,6 +80,7 @@ def serve(argv=None):
     ap.add_argument("--port", type=int, default=8777,
                     help="HTTP port for --http (0 = ephemeral)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.http:
         from repro.serving.async_server import main as http_main
@@ -127,8 +130,11 @@ def serve(argv=None):
     dt = time.time() - t0
     total_tokens = sum(len(o.token_ids) for o in outs)
     st = server.stats
+    dev = jax.devices()[0]
+    where = f"{dev.platform}:{dev.device_kind} x{len(jax.devices())}"
     print(f"[serve] {len(outs)} requests, {total_tokens} tokens in "
-          f"{dt:.1f}s ({total_tokens / dt:.1f} tok/s on CPU)")
+          f"{dt:.1f}s ({total_tokens / dt:.1f} tok/s on {where}, "
+          "first requests carry jit compiles)")
     print(f"[serve] scheduler={args.scheduler}: {st['steps']} steps, "
           f"{st['prefill_chunks']} prefill chunks, {st['compiles']} "
           f"compiles, {st['decode_stall_tokens']} decode-stall tokens "
@@ -139,7 +145,7 @@ def serve(argv=None):
           f"{latency_percentile(ttfts, 95) * 1e3:.0f} ms, "
           f"TPOT p50/p95 {latency_percentile(tpots, 50) * 1e3:.0f}/"
           f"{latency_percentile(tpots, 95) * 1e3:.0f} ms "
-          "(CPU; first requests carry jit compiles)")
+          f"({where}; first requests carry jit compiles)")
     if spec_k > 0 and st["spec_steps"]:
         per_step = accepted_tokens_per_step(st["spec_accepted"],
                                             st["spec_steps"])

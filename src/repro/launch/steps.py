@@ -40,12 +40,12 @@ def engine_config_for(cfg: ModelConfig, shape: ShapeConfig,
                       ) -> EngineConfig:
     kw: Dict[str, Any] = dict(remat="block")
     if shape.kind == "decode":
-        # Measured (EXPERIMENTS.md §Perf Q3/Q4): on TPU the compact plan
-        # beats the paper's discrete/HG plan at every context — page
-        # validity/masks are computed once instead of per head-group, and
-        # the HG overlap is a fixed-function-flash property that doesn't
-        # translate to fungible MXUs.  The paper-faithful discrete plan
-        # remains selectable (--variant discrete).
+        # The compact plan computes page validity/masks once instead of
+        # per head-group, and the paper's HG overlap is a property of
+        # fixed-function flash dies that a TPU's fungible MXUs do not
+        # share — so compact is the decode default.  (Not measured on a
+        # chip.)  The paper-faithful discrete plan remains selectable
+        # (--variant discrete).
         kw.update(variant="compact")
     if overrides:
         kw.update(overrides)

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from repro.checkpoint import checkpoint as ck
 from repro.configs import EngineConfig, get_config
 from repro.data.pipeline import DataConfig, DataIterator, make_source
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import mesh_from_devices
 from repro.models.registry import Model
 from repro.models.transformer import Runtime
@@ -65,6 +66,7 @@ def train(argv=None):
                     help="crash (exit 17) once at this step, pre-restore")
     ap.add_argument("--data-seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -114,7 +116,7 @@ def train(argv=None):
 
     losses = []
     step = start_step
-    with mesh:
+    with jax.set_mesh(mesh):
         for step in range(start_step, args.steps):
             if args.simulate_failure and step == args.simulate_failure \
                     and start_step < args.simulate_failure:

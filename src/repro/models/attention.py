@@ -126,16 +126,9 @@ def sharded_flash_attention(q, k, v, *, causal=True, window=None,
     """
     from repro.distributed.sharding import get_current_mesh
     mesh = get_current_mesh()
-    manual = set()
-    try:
-        amesh = jax.sharding.get_abstract_mesh()
-        if amesh is not None and not amesh.empty:
-            manual = {n for n, t in zip(amesh.axis_names, amesh.axis_types)
-                      if "Manual" in str(t)}
-            if manual:
-                mesh = amesh
-    except Exception:
-        pass
+    manual = set() if mesh is None else {
+        n for n, t in zip(mesh.axis_names, mesh.axis_types)
+        if t == jax.sharding.AxisType.Manual}
     if (mesh is not None and "model" in mesh.shape
             and mesh.shape["model"] > 1 and "model" not in manual
             and q.shape[1] % mesh.shape["model"] == 0

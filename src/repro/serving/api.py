@@ -189,11 +189,14 @@ class RequestOutput:
 class KVNANDServer:
     """Facade over engine + runtime + scheduler construction and the
     request lifecycle.  `cfg`/`params`/`rt` overrides let callers serve
-    a model they already built (e.g. freshly trained weights)."""
+    a model they already built (e.g. freshly trained weights);
+    `device` places the weights, the KV cache and every step's inputs on
+    one device (one replica per accelerator, `serving.replica`)."""
 
     def __init__(self, config: Optional[ServerConfig] = None, *,
                  cfg: Optional[ModelConfig] = None, params=None,
-                 rt: Optional[Runtime] = None):
+                 rt: Optional[Runtime] = None,
+                 device: Optional[jax.Device] = None):
         self.config = config = config or ServerConfig()
         if cfg is None:
             cfg = get_config(config.arch)
@@ -202,7 +205,9 @@ class KVNANDServer:
         self.cfg = cfg
         rt = rt or Runtime()
         if params is None:
-            params = Model(cfg, rt).init(jax.random.PRNGKey(config.seed))
+            with jax.default_device(device):
+                params = Model(cfg, rt).init(
+                    jax.random.PRNGKey(config.seed))
         spec_k = config.speculation_k
         if spec_k is None:
             spec_k = (config.engine.speculation_k
@@ -213,7 +218,8 @@ class KVNANDServer:
             seed=config.seed,
             prefill_chunk_tokens=config.prefill_chunk_tokens,
             step_token_budget=config.step_token_budget,
-            speculation_k=spec_k, tier_prefetch=config.tier_prefetch)
+            speculation_k=spec_k, tier_prefetch=config.tier_prefetch,
+            device=device)
         self._requests: Dict[int, Request] = {}
         self._streamed: Dict[int, int] = {}
         self._done_emitted: set = set()

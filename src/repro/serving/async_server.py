@@ -461,6 +461,8 @@ def main(argv=None) -> int:
     ap.add_argument("--no-overlap", action="store_true",
                     help="synchronous engine loop (ablation)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     async def _run():
         inner = KVNANDServer(ServerConfig(

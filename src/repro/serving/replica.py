@@ -49,7 +49,6 @@ from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.checkpoint import _flatten_with_paths
@@ -254,8 +253,7 @@ def _stage_page(batcher: ContinuousBatcher, dst: int,
     fn = batcher._stage_w_jit if window else batcher._stage_jit
     batcher._count_compile("migrate_stage_w" if window
                            else "tier_stage")
-    batcher.cache = fn(batcher.cache, jnp.asarray(dst, jnp.int32),
-                       {n: jnp.asarray(v) for n, v in vals.items()})
+    batcher.cache = fn(batcher.cache, *batcher._put((np.int32(dst), vals)))
 
 
 def import_request(batcher: ContinuousBatcher,
@@ -350,7 +348,7 @@ def import_request(batcher: ContinuousBatcher,
             rows[n] = env.arrays[f"state/{n}"]
     batcher._count_compile("migrate_rows")
     batcher.cache = batcher._migrate_rows_jit(
-        batcher.cache, jnp.asarray(i, jnp.int32), rows)
+        batcher.cache, *batcher._put((np.int32(i), rows)))
 
     # -- host bookkeeping: the slot now looks exactly like one whose
     # chunked prefill just handed off
@@ -381,12 +379,8 @@ def build_replica(config=None, *, cfg=None, params=None, rt=None,
     through the host, so envelopes cross device boundaries without any
     collective; `device=None` builds on the default device."""
     from repro.serving.api import KVNANDServer
-    if device is None:
-        return KVNANDServer(config, cfg=cfg, params=params, rt=rt)
-    if params is not None:
-        params = jax.device_put(params, device)
-    with jax.default_device(device):
-        return KVNANDServer(config, cfg=cfg, params=params, rt=rt)
+    return KVNANDServer(config, cfg=cfg, params=params, rt=rt,
+                        device=device)
 
 
 class PrefixPageIndex:

@@ -244,7 +244,8 @@ class ContinuousBatcher:
                  seed: int = 0, bucket_prompts: bool = True,
                  prefill_chunk_tokens: int = 64,
                  step_token_budget: Optional[int] = None,
-                 speculation_k: int = 0, tier_prefetch: bool = True):
+                 speculation_k: int = 0, tier_prefetch: bool = True,
+                 device: Optional[jax.Device] = None):
         eng = eng or EngineConfig(page_tokens=16, uniform_lengths=False)
         if eng.uniform_lengths:
             raise ValueError(
@@ -259,7 +260,11 @@ class ContinuousBatcher:
                 "starts stay page-aligned")
         self.cfg = cfg
         self.engine = KVNANDEngine(cfg, eng, rt or Runtime())
-        self.params = params
+        # replica placement: params, cache and every per-step input are
+        # committed to `device`, so each jitted step compiles once (None:
+        # the default device, uncommitted)
+        self.device = device
+        self.params = self._put(params)
         self.B = batch_slots
         self.max_context = max_context
         self.temperature = temperature
@@ -277,7 +282,9 @@ class ContinuousBatcher:
         self.seed = seed
         self.queue: Deque[Request] = deque()
         self.slots: List[Optional[Request]] = [None] * batch_slots
-        self.cache = self.engine.init_cache(batch_slots, max_context)
+        with jax.default_device(device):
+            self.cache = self._put(
+                self.engine.init_cache(batch_slots, max_context))
         self._lengths = np.zeros(batch_slots, np.int64)
         self._prefill_live: Dict[int, _PrefillState] = {}
         self._admit_seq = 0
@@ -335,8 +342,8 @@ class ContinuousBatcher:
             return toks, lps, c
 
         self._decode = jax.jit(_decode_fn, donate_argnums=(1,))
-        self._no_chain = (np.zeros(self.B, bool),
-                          jnp.zeros(self.B, jnp.int32))
+        self._no_chain = self._put((np.zeros(self.B, bool),
+                                    np.zeros(self.B, np.int32)))
 
         def _verify_fn(p, c, t, a, allowed, temps, tk, tp, seeds, pos):
             # sampling stays a scheduler concern: the engine calls back
@@ -382,6 +389,11 @@ class ContinuousBatcher:
         self._compile_keys = set()
         if self.shared:
             self._init_shared_pool(eng)
+
+    def _put(self, x):
+        """Host values (pytrees) -> device arrays on this batcher's
+        device."""
+        return jax.device_put(x, self.device)
 
     # -- shared-pool bookkeeping (allocator, tables, prefix cache) -----
     def _init_shared_pool(self, eng: EngineConfig):
@@ -496,8 +508,7 @@ class ContinuousBatcher:
         vals = self._store.pop(int(page))
         self._count_compile("tier_stage")
         self.cache = self._stage_jit(
-            self.cache, jnp.asarray(slot, jnp.int32),
-            {n: jnp.asarray(v) for n, v in vals.items()})
+            self.cache, self._put(np.int32(slot)), self._put(vals))
         self.stats["tier_promotes"] += 1
         return slot
 
@@ -540,9 +551,9 @@ class ContinuousBatcher:
             return
         upd = {}
         if self.alloc is not None:
-            upd["page_table_g"] = jnp.asarray(self._table_np)
+            upd["page_table_g"] = self._put(self._table_np)
         if self.alloc_w is not None:
-            upd["page_table_w"] = jnp.asarray(self._table_w_np)
+            upd["page_table_w"] = self._put(self._table_w_np)
         if upd:
             self.cache = dataclasses.replace(self.cache, **upd)
         self._tables_dirty = False
@@ -602,9 +613,9 @@ class ContinuousBatcher:
                     self.tier.pin(fresh)
                     self._table_np[i, lp] = self.tier.slot_of(fresh)
                 else:
-                    self.cache = self._cow_jit(self.cache,
-                                               jnp.asarray(old, jnp.int32),
-                                               jnp.asarray(fresh, jnp.int32))
+                    self.cache = self._cow_jit(
+                        self.cache, *self._put((np.int32(old),
+                                                np.int32(fresh))))
                     self._table_np[i, lp] = fresh
                 pages[lp] = fresh
                 self._tables_dirty = True
@@ -736,11 +747,11 @@ class ContinuousBatcher:
         p = req.params
         self._count_compile("sample_row")
         toks, lps = _sample_one(
-            jnp.asarray(logits),
-            np.asarray([self._seed_of(req)], np.uint32),
-            np.asarray([len(req.output)], np.int32),
-            np.float32(p.temperature), np.int32(p.top_k),
-            np.float32(p.top_p), true_vocab=self.cfg.vocab_size)
+            self._put(logits), *self._put((
+                np.asarray([self._seed_of(req)], np.uint32),
+                np.asarray([len(req.output)], np.int32),
+                np.float32(p.temperature), np.int32(p.top_k),
+                np.float32(p.top_p))), true_vocab=self.cfg.vocab_size)
         return int(toks[0]), float(lps[0])
 
     def _finish(self, i: int, reason: str):
@@ -962,7 +973,7 @@ class ContinuousBatcher:
             # request's OWN params and PRNG stream (accounting above is
             # final first: a stop/length finish frees the slot cleanly)
             tok, lp = self._sample_row(
-                jnp.asarray(hit.exact.logits)[None], req)
+                np.asarray(hit.exact.logits)[None], req)
             self._emit_token(i, req, tok, lp)
         return True
 
@@ -985,9 +996,9 @@ class ContinuousBatcher:
         fn = self._chunk_first if c0 == 0 else self._chunk_cont
         self._count_compile("chunk", c0 == 0, len(chunk))
         logits, self.cache = fn(
-            self.params, self.cache, jnp.asarray(chunk)[None],
-            jnp.asarray(i, jnp.int32), jnp.asarray(c0, jnp.int32),
-            jnp.asarray(cl, jnp.int32))
+            self.params, self.cache, *self._put((
+                np.asarray(chunk)[None], np.int32(i), np.int32(c0),
+                np.int32(cl))))
         ps.pos = c0 + len(chunk)
         self.stats["prefill_chunks"] += 1
         if ps.pos >= ps.n:                         # prompt fully prefilled
@@ -1154,10 +1165,9 @@ class ContinuousBatcher:
         # sampling params ride as traced per-slot arrays: any mix of
         # per-request combinations hits this one compiled signature
         toks, lps, self.cache = self._decode(
-            self.params, self.cache, tokens, ch, prev_t,
-            jnp.asarray(mask), jnp.asarray(self._temps),
-            jnp.asarray(self._topk), jnp.asarray(self._topp),
-            jnp.asarray(self._seeds), jnp.asarray(positions))
+            self.params, self.cache, self._put(tokens), *self._put(
+                (ch, prev_t, mask, self._temps, self._topk, self._topp,
+                 self._seeds, positions)))
         self._lengths[active] += 1
         cap = {i for i in active
                if self._lengths[i] + 1 >= self.max_context}
@@ -1262,11 +1272,9 @@ class ContinuousBatcher:
         self._mark_device_busy()
         self._count_compile("verify", self.B, S)
         (toks, lps, acc), self.cache = self._verify(
-            self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(mask), jnp.asarray(allowed),
-            jnp.asarray(self._temps), jnp.asarray(self._topk),
-            jnp.asarray(self._topp), jnp.asarray(self._seeds),
-            jnp.asarray(positions))
+            self.params, self.cache, *self._put(
+                (tokens, mask, allowed, self._temps, self._topk,
+                 self._topp, self._seeds, positions)))
         self._inflight.append(_Inflight(
             "verify", list(active), reqs, toks, lps, acc=acc,
             allowed=allowed))
@@ -1385,16 +1393,15 @@ class SpliceBatcher(ContinuousBatcher):
     def _prefill_slot(self, i: int, req: Request):
         """Prefill one sequence and splice its pools/length into slot i."""
         n = len(req.prompt)
-        toks = jnp.asarray(self._padded(req), jnp.int32)[None]
+        toks = self._put(np.asarray(self._padded(req), np.int32)[None])
         self._count_compile("prefill", toks.shape[1])
         if self.bucket_prompts:
             logits, c1 = self._prefill1_bucketed(
-                self.params, {"tokens": toks}, jnp.asarray(n, jnp.int32))
+                self.params, {"tokens": toks}, self._put(np.int32(n)))
         else:
             logits, c1 = self._prefill1(self.params, {"tokens": toks})
         self._count_compile("splice")
-        self.cache = self._splice(self.cache, c1,
-                                  jnp.asarray(i, jnp.int32))
+        self.cache = self._splice(self.cache, c1, self._put(np.int32(i)))
         self._lengths[i] = self._prefix + n
         tok, lp = self._sample_row(logits, req)
         self._emit_token(i, req, tok, lp)

@@ -13,6 +13,9 @@ REPO_SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 def run_multidevice(code: str, n_devices: int = 8, timeout: int = 480):
     env = dict(os.environ)
+    # children run on CPU host devices: a chip belongs to one process, and
+    # the parent may hold it
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{n_devices}")
     env["PYTHONPATH"] = REPO_SRC + os.pathsep + env.get("PYTHONPATH", "")

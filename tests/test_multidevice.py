@@ -1,7 +1,6 @@
 """Multi-device correctness (8 fake host devices via subprocess):
 ring attention, sharded paged decode + in-shard appends, compressed-DP
 train step, elastic checkpoint restore across topologies."""
-import jax
 import pytest
 
 from tests._mp import run_multidevice
@@ -9,8 +8,8 @@ from tests._mp import run_multidevice
 COMMON = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from repro.distributed.sharding import make_mesh_compat
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+from repro.distributed.sharding import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 assert len(jax.devices()) == 8
 """
 
@@ -26,7 +25,7 @@ q = jax.random.normal(ks[0], (B, S, H, dh))
 k = jax.random.normal(ks[1], (B, S, K, dh))
 v = jax.random.normal(ks[2], (B, S, K, dh))
 for causal, window in ((True, None), (True, 40), (False, None)):
-    with mesh:
+    with jax.set_mesh(mesh):
         out = jax.jit(lambda q, k, v: ring_attention(
             q, k, v, mesh, causal=causal, window=window))(q, k, v)
     ref = flash_attention(q, k, v, causal=causal, window=window, impl="ref")
@@ -52,7 +51,7 @@ base = jnp.broadcast_to((jnp.arange(NP)*T)[None], (B, NP)).astype(jnp.int32)
 q = jax.random.normal(ks[2], (B, H, dh))
 length = jnp.full((B,), 100, jnp.int32)
 # sharded partial+combine == single-device full
-with mesh:
+with jax.set_mesh(mesh):
     o_sh = jax.jit(lambda *a: seqpar.paged_decode_attention_sharded(
         *a, mesh, batch_axes=("data",), page_axes=("model",)))(
         q, kp, vp, base, length)
@@ -66,7 +65,7 @@ k1 = jax.random.normal(ks[3], (B, K, dh))
 v1 = jax.random.normal(ks[4], (B, K, dh))
 phys = jnp.full((B,), 5, jnp.int32)   # page 5 -> owned by shard 2 of 4
 slot = jnp.full((B,), 7, jnp.int32)
-with mesh:
+with jax.set_mesh(mesh):
     nk, nv = jax.jit(lambda *a: seqpar.sharded_append_uniform(
         *a, mesh, batch_axes=("data",), page_axes=("model",)))(
         pool_k, pool_v, 1, k1, v1, phys, slot)
@@ -85,7 +84,7 @@ L, B, K, NP, T, dh = 2, 4, 2, 8, 8, 16
 S = 50
 kv = jax.random.normal(jax.random.PRNGKey(0), (B, S, K, dh))
 pool = jnp.zeros((L, B, K, NP, T, dh))
-with mesh:
+with jax.set_mesh(mesh):
     out = jax.jit(lambda p, kv: seqpar.sharded_prefill_fill(
         p, kv, 1, mesh, batch_axes=("data",), page_axes=("model",)))(
         pool, kv)
@@ -115,7 +114,7 @@ for t in range(3):
     lg1, c1 = eng1.decode_step(params, c1, toks[:, 16+t:17+t])
 engN = KVNANDEngine(cfg, EngineConfig(page_tokens=4, kv_dtype="float32"),
                     rt, mesh=mesh)
-with mesh:
+with jax.set_mesh(mesh):
     lgN, cN = jax.jit(lambda p, b: engN.prefill(p, b, 28))(
         params, {"tokens": toks[:, :16]})
     step = jax.jit(lambda p, c, t: engN.decode_step(p, c, t))
@@ -148,7 +147,7 @@ lg1, c1 = eng1.prefill(params, {"tokens": toks[:, :16]}, 28)
 for t in range(3):
     lg1, c1 = eng1.decode_step(params, c1, toks[:, 16+t:17+t])
 engN = KVNANDEngine(cfg, ec, rt, mesh=mesh)
-with mesh:
+with jax.set_mesh(mesh):
     lgN, cN = jax.jit(lambda p, b: engN.prefill(p, b, 28))(
         params, {"tokens": toks[:, :16]})
     step = jax.jit(lambda p, c, t: engN.decode_step(p, c, t))
@@ -161,15 +160,11 @@ print("engine sharded quant == single device OK")
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5),
-    reason="manual-DP shard_map nested around an auto model axis needs "
-           "jax>=0.5 (0.4.x rejects inner specs naming manual axes)")
 def test_compressed_train_step_close_to_exact():
     run_multidevice("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.distributed.sharding import make_mesh_compat
-mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+from repro.distributed.sharding import make_mesh
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 from repro.configs import get_config, EngineConfig
 from repro.models.registry import Model
 from repro.models.transformer import Runtime
@@ -187,7 +182,7 @@ batch = {
                                cfg.vocab_size, jnp.int32),
   "labels": jax.random.randint(jax.random.PRNGKey(2), (8, 32), 0,
                                cfg.vocab_size, jnp.int32)}
-with mesh:
+with jax.set_mesh(mesh):
     s0 = init_train_state(params, acfg)
     step = jax.jit(make_train_step(cfg, rt, acfg, EngineConfig()))
     s1, m1 = step(s0, batch)
@@ -244,7 +239,7 @@ kv = jax.random.normal(jax.random.PRNGKey(0), (1, S, K, dh))
 
 # --- fill: intersection of local page range x owned batch row ---------
 pool = jnp.zeros((L, B, K, NP, T, dh))
-with mesh:
+with jax.set_mesh(mesh):
     out = jax.jit(lambda p, kv: seqpar.sharded_chunk_fill(
         p, kv, layer, slot, page0, S, mesh,
         batch_axes=("data",), page_axes=("model",)))(pool, kv)
@@ -256,7 +251,7 @@ np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
 # quantized variant carries per-page scales
 qpool = jnp.zeros((L, B, K, NP, T, dh), jnp.int8)
 qscale = jnp.zeros((L, B, K, NP), jnp.float32)
-with mesh:
+with jax.set_mesh(mesh):
     qo, so = jax.jit(lambda p, s, kv: seqpar.sharded_chunk_fill(
         p, kv, layer, slot, page0, S, mesh, batch_axes=("data",),
         page_axes=("model",), scale=s, kv_quant="kv8"))(qpool, qscale, kv)
@@ -277,7 +272,7 @@ q = jax.random.normal(ks[2], (1, 12, H, dh))
 base = (jnp.arange(NP, dtype=jnp.int32) * T)[None]
 start = jnp.asarray(40, jnp.int32)
 q_pos = 40 + jnp.arange(12, dtype=jnp.int32)
-with mesh:
+with jax.set_mesh(mesh):
     o_sh, m_sh, l_sh = jax.jit(lambda *a: seqpar.sharded_chunk_attention(
         *a, mesh, page_axes=("model",)))(q, kp, vp, base, start, q_pos)
 o_rf, m_rf, l_rf = paged_chunk_attention_ref(q, kp, vp, base, start, q_pos)
@@ -321,7 +316,7 @@ eng1 = KVNANDEngine(cfg, EngineConfig(page_tokens=4, kv_dtype="float32",
 lg1, _ = chunked(eng1, eng1.init_cache(4, ctx), False)
 engN = KVNANDEngine(cfg, EngineConfig(page_tokens=4, kv_dtype="float32",
                                       uniform_lengths=False), rt, mesh=mesh)
-with mesh:
+with jax.set_mesh(mesh):
     lgN, _ = chunked(engN, engN.init_cache(4, ctx), True)
 np.testing.assert_allclose(np.asarray(lg1), np.asarray(lgN),
                            atol=5e-4, rtol=5e-4)
@@ -349,7 +344,7 @@ base = jnp.broadcast_to((jnp.arange(NP) * T)[None], (B, NP)).astype(jnp.int32)
 length = jnp.array([7, 33, 64, 128], jnp.int32)
 ref, _, _ = paged_attention_partial(q, pool_k, pool_v, base, length,
                                     impl="ref", page_table=table)
-with mesh:
+with jax.set_mesh(mesh):
     out = jax.jit(lambda q, kp, vp, tbl, b, ln:
                   seqpar.paged_decode_attention_sharded_shared(
                       q, kp, vp, tbl, b, ln, mesh, batch_axes=("data",),
@@ -362,7 +357,7 @@ pools_v = jnp.zeros((L, K, P, T, dh))
 phys = jnp.array([3, 11, 19, 30], jnp.int32)
 slot = jnp.array([0, 5, 15, 2], jnp.int32)
 kn = jax.random.normal(ks[3], (B, K, dh))
-with mesh:
+with jax.set_mesh(mesh):
     ok, ov = jax.jit(lambda kp, vp, kn, vn, ph, sl:
                      seqpar.sharded_append_shared(
                          kp, vp, 1, kn, vn, ph, sl, mesh,

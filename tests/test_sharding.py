@@ -50,9 +50,8 @@ def test_multipod_batch_axes():
 def test_quantized_weight_shardings():
     import jax.numpy as jnp
     from repro.core.quant import quantize_weight, QuantizedWeight
-    from repro.distributed.sharding import make_mesh_compat
-    mesh = make_mesh_compat((1, 1), ("data", "model"),
-                            devices=jax.devices()[:1])
+    from repro.distributed.sharding import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
     w = jnp.ones((64, 32))
     qw = quantize_weight(w, "w4a16")
     specs = QuantizedWeight(("embed", "mlp"), ("mlp",), "w4a16", (64, 32))
