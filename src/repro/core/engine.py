@@ -151,6 +151,19 @@ class KVNANDEngine:
                                        **self._cache_kw(batch, max_context,
                                                         enc_len))
 
+    def decode_page_visits(self, cache: DecodeCache) -> int:
+        """Pages one decode (or verify) step's paged attention walks per
+        global-pool layer: rows × pages per row of the walk's grid — the
+        page-table width of a shared pool, the stripe's page count
+        otherwise (what `paged_attention_partial` sizes its grid from);
+        0 for archs with no global pool."""
+        if cache.k_pages_g is None:
+            return 0
+        rows = cache.lengths.shape[0]
+        if self.eng.shared_pool:
+            return rows * cache.page_table_g.shape[1]
+        return rows * cache.k_pages_g.shape[3]
+
     # ------------------------------------------------------------------
     # paged attention dispatch (single device vs sharded combine)
     # ------------------------------------------------------------------
@@ -226,10 +239,12 @@ class KVNANDEngine:
                         plan, pool, window, table=None):
         """Fused QKV gen + attention (KVNAND-C, Fig 10b).  kp/vp are the
         already-appended layer slices (+scales when the pool is quantized)."""
-        q, _, _ = attn_mod.project_qkv(pl_["attn"], self.cfg, x_norm,
-                                       lengths[:, None])
-        return self._paged_attn(q[:, 0], kp, vp, base, lengths + 1, plan,
-                                pool, window, ks, vs, table)
+        with jax.named_scope("qkv"):
+            q, _, _ = attn_mod.project_qkv(pl_["attn"], self.cfg, x_norm,
+                                           lengths[:, None])
+        with jax.named_scope("paged_attn"):
+            return self._paged_attn(q[:, 0], kp, vp, base, lengths + 1,
+                                    plan, pool, window, ks, vs, table)
 
     def _attend_discrete(self, pl_, x_norm, kp, vp, ks, vs, base, lengths,
                          plan, pool, window, table=None):
@@ -242,22 +257,27 @@ class KVNANDEngine:
         k_axis = 0 if kp.ndim == 4 else 1   # shared pools are [K, P, T, dh]
 
         def body(q_cur, i):
-            q_next = attn_mod.project_q_group(
-                pl_["attn"], cfg, x_tok, jnp.minimum(i + 1, K - 1), lengths)
+            with jax.named_scope("qkv"):
+                q_next = attn_mod.project_q_group(
+                    pl_["attn"], cfg, x_tok, jnp.minimum(i + 1, K - 1),
+                    lengths)
             # slice head group i on the K dim directly (no pool transpose)
-            kp_i = jax.lax.dynamic_slice_in_dim(kp, i, 1, k_axis)
-            vp_i = jax.lax.dynamic_slice_in_dim(vp, i, 1, k_axis)
-            ks_i = vs_i = None
-            if ks is not None:
-                ks_i = jax.lax.dynamic_slice_in_dim(ks, i, 1, k_axis)
-                vs_i = jax.lax.dynamic_slice_in_dim(vs, i, 1, k_axis)
-            o = self._paged_attn(q_cur, kp_i, vp_i, base, lengths + 1,
-                                 plan, pool, window, ks_i, vs_i,
-                                 table)  # [B, G, dh]
+            with jax.named_scope("pool_view"):
+                kp_i = jax.lax.dynamic_slice_in_dim(kp, i, 1, k_axis)
+                vp_i = jax.lax.dynamic_slice_in_dim(vp, i, 1, k_axis)
+                ks_i = vs_i = None
+                if ks is not None:
+                    ks_i = jax.lax.dynamic_slice_in_dim(ks, i, 1, k_axis)
+                    vs_i = jax.lax.dynamic_slice_in_dim(vs, i, 1, k_axis)
+            with jax.named_scope("paged_attn"):
+                o = self._paged_attn(q_cur, kp_i, vp_i, base, lengths + 1,
+                                     plan, pool, window, ks_i, vs_i,
+                                     table)  # [B, G, dh]
             return q_next, o
 
-        q0 = attn_mod.project_q_group(pl_["attn"], cfg, x_tok,
-                                      jnp.zeros((), jnp.int32), lengths)
+        with jax.named_scope("qkv"):
+            q0 = attn_mod.project_q_group(pl_["attn"], cfg, x_tok,
+                                          jnp.zeros((), jnp.int32), lengths)
         _, outs = jax.lax.scan(body, q0, jnp.arange(K))
         return outs.transpose(1, 0, 2, 3).reshape(B, cfg.n_heads,
                                                   cfg.d_head)
@@ -272,8 +292,9 @@ class KVNANDEngine:
         h = rms_norm(x, pl_["ln1"], cfg.norm_eps)
         use_window = (cfg.window is not None) and not is_glob
         # K/V for the new token (the paper's ❸→❹ write into G2/own pages)
-        _, k_new, v_new = attn_mod.project_qkv(pl_["attn"], cfg, h,
-                                               lengths[:, None])
+        with jax.named_scope("qkv"):
+            _, k_new, v_new = attn_mod.project_qkv(pl_["attn"], cfg, h,
+                                                   lengths[:, None])
         k1, v1 = k_new[:, 0], v_new[:, 0]
         T = self.eng.page_tokens
         slot = lengths % T
@@ -309,70 +330,76 @@ class KVNANDEngine:
         fmt = self.eng.kv_quant
         ksname = "k_scale_w" if use_window else "k_scale_g"
         vsname = "v_scale_w" if use_window else "v_scale_g"
-        if sharded and shared:
-            # shared pool sharded over P_total: the owning shard translates
-            # the global physical index to its local range and scatters
-            out = seqpar.sharded_append_shared(
-                pools[kname], pools[vname], idx, k1, v1, phys, slot,
-                self.mesh, batch_axes=plan.batch_axes, page_axes=page_axes,
-                k_scale=pools.get(ksname), v_scale=pools.get(vsname),
-                kv_quant=fmt)
-            if fmt != "none":
-                (pools[kname], pools[vname], pools[ksname],
-                 pools[vsname]) = out
-            else:
-                pools[kname], pools[vname] = out
-        elif sharded and self.eng.uniform_lengths:
-            # append INSIDE the owning shard (paper: direct G2-die write);
-            # a pjit-level update on the sharded page dim lowers to a
-            # full-pool ownership select per layer (§Perf iteration 2)
-            if fmt != "none":
-                (pools[kname], pools[vname], pools[ksname],
-                 pools[vsname]) = seqpar.sharded_append_uniform(
+        with jax.named_scope("kv_append"):
+            if sharded and shared:
+                # shared pool sharded over P_total: the owning shard
+                # translates the global physical index to its local range
+                # and scatters
+                out = seqpar.sharded_append_shared(
                     pools[kname], pools[vname], idx, k1, v1, phys, slot,
                     self.mesh, batch_axes=plan.batch_axes,
-                    page_axes=page_axes, k_scale=pools[ksname],
-                    v_scale=pools[vsname], kv_quant=fmt)
+                    page_axes=page_axes, k_scale=pools.get(ksname),
+                    v_scale=pools.get(vsname), kv_quant=fmt)
+                if fmt != "none":
+                    (pools[kname], pools[vname], pools[ksname],
+                     pools[vsname]) = out
+                else:
+                    pools[kname], pools[vname] = out
+            elif sharded and self.eng.uniform_lengths:
+                # append INSIDE the owning shard (paper: direct G2-die
+                # write); a pjit-level update on the sharded page dim
+                # lowers to a full-pool ownership select per layer (§Perf
+                # iteration 2)
+                if fmt != "none":
+                    (pools[kname], pools[vname], pools[ksname],
+                     pools[vsname]) = seqpar.sharded_append_uniform(
+                        pools[kname], pools[vname], idx, k1, v1, phys,
+                        slot, self.mesh, batch_axes=plan.batch_axes,
+                        page_axes=page_axes, k_scale=pools[ksname],
+                        v_scale=pools[vsname], kv_quant=fmt)
+                else:
+                    (pools[kname],
+                     pools[vname]) = seqpar.sharded_append_uniform(
+                        pools[kname], pools[vname], idx, k1, v1, phys,
+                        slot, self.mesh, batch_axes=plan.batch_axes,
+                        page_axes=page_axes)
+            elif fmt != "none":
+                # page-granular requantizing append (tentpole write path)
+                if shared:
+                    append = paged_kv.append_token_quant_shared
+                else:
+                    append = (paged_kv.append_token_quant_uniform
+                              if self.eng.uniform_lengths
+                              else paged_kv.append_token_quant)
+                pools[kname], pools[ksname] = append(
+                    pools[kname], pools[ksname], idx, phys, slot, k1, fmt)
+                pools[vname], pools[vsname] = append(
+                    pools[vname], pools[vsname], idx, phys, slot, v1, fmt)
+            elif shared:
+                pools[kname] = paged_kv.append_global_shared(
+                    pools[kname], idx, phys, slot, k1)
+                pools[vname] = paged_kv.append_global_shared(
+                    pools[vname], idx, phys, slot, v1)
             else:
-                pools[kname], pools[vname] = seqpar.sharded_append_uniform(
-                    pools[kname], pools[vname], idx, k1, v1, phys, slot,
-                    self.mesh, batch_axes=plan.batch_axes,
-                    page_axes=page_axes)
-        elif fmt != "none":
-            # page-granular requantizing append (tentpole write path)
-            if shared:
-                append = paged_kv.append_token_quant_shared
-            else:
-                append = (paged_kv.append_token_quant_uniform
-                          if self.eng.uniform_lengths
-                          else paged_kv.append_token_quant)
-            pools[kname], pools[ksname] = append(
-                pools[kname], pools[ksname], idx, phys, slot, k1, fmt)
-            pools[vname], pools[vsname] = append(
-                pools[vname], pools[vsname], idx, phys, slot, v1, fmt)
-        elif shared:
-            pools[kname] = paged_kv.append_global_shared(
-                pools[kname], idx, phys, slot, k1)
-            pools[vname] = paged_kv.append_global_shared(
-                pools[vname], idx, phys, slot, v1)
-        else:
-            pools[kname] = self._append_token(pools[kname], idx, phys, slot,
-                                              k1)
-            pools[vname] = self._append_token(pools[vname], idx, phys, slot,
-                                              v1)
-        kp = self._layer_slice(pools[kname], idx)
-        vp = self._layer_slice(pools[vname], idx)
-        ks = vs = None
-        if fmt != "none":
-            ks = self._layer_slice(pools[ksname], idx)
-            vs = self._layer_slice(pools[vsname], idx)
+                pools[kname] = self._append_token(pools[kname], idx, phys,
+                                                  slot, k1)
+                pools[vname] = self._append_token(pools[vname], idx, phys,
+                                                  slot, v1)
+        with jax.named_scope("pool_view"):
+            kp = self._layer_slice(pools[kname], idx)
+            vp = self._layer_slice(pools[vname], idx)
+            ks = vs = None
+            if fmt != "none":
+                ks = self._layer_slice(pools[ksname], idx)
+                vs = self._layer_slice(pools[vsname], idx)
 
         attend = (self._attend_discrete
                   if self.eng.variant == "discrete" or self.eng.hg_pipeline
                   else self._attend_compact)
         o = attend(pl_, h, kp, vp, ks, vs, base, lengths, plan,
                    "w" if use_window else "g", window, table)
-        aout = attn_mod.project_out(pl_["attn"], cfg, o[:, None])
+        with jax.named_scope("attn_out"):
+            aout = attn_mod.project_out(pl_["attn"], cfg, o[:, None])
         return h, aout, pools
 
     def _decode_block(self, pl_, x, pools, states, cross, l_idx, g_idx,
@@ -405,11 +432,12 @@ class KVNANDEngine:
             x = x + self._cross_attention(pl_["cross"], h, ck, cv, plan)
 
         h = rms_norm(x, pl_["ln2"], cfg.norm_eps)
-        if cfg.is_moe:
-            ff = moe(pl_["moe"], h, top_k=cfg.top_k,
-                     capacity_factor=self.rt.moe_capacity)
-        else:
-            ff = mlp(pl_["mlp"], h, cfg.gated_mlp)
+        with jax.named_scope("mlp"):
+            if cfg.is_moe:
+                ff = moe(pl_["moe"], h, top_k=cfg.top_k,
+                         capacity_factor=self.rt.moe_capacity)
+            else:
+                ff = mlp(pl_["mlp"], h, cfg.gated_mlp)
         return ((x + ff, states), pools)
 
     def _mask_state(self, *pairs):
@@ -557,7 +585,8 @@ class KVNANDEngine:
         updates["lengths"] = (lengths + 1 if active is None
                               else lengths + active.astype(lengths.dtype))
         new_cache = dataclasses.replace(cache, **updates)
-        logits = lm_head_logits(params, cfg, x)[:, 0]
+        with jax.named_scope("logits"):
+            logits = lm_head_logits(params, cfg, x)[:, 0]
         return logits, new_cache
 
     # ------------------------------------------------------------------
@@ -646,7 +675,9 @@ class KVNANDEngine:
             use_window = (cfg.window is not None) and not is_glob
             window = cfg.window if use_window else None
             h = rms_norm(xc, pl_["ln1"], cfg.norm_eps)
-            q, k, v = attn_mod.project_qkv(pl_["attn"], cfg, h, positions)
+            with jax.named_scope("qkv"):
+                q, k, v = attn_mod.project_qkv(pl_["attn"], cfg, h,
+                                               positions)
             # in-span causal partial: the mask is position-RELATIVE
             # (span token i sees span tokens <= i, window likewise), so
             # relative coordinates serve every slot at once.  The span's
@@ -662,9 +693,6 @@ class KVNANDEngine:
                 k_in, v_in, sc = k.astype(kv_dt), v.astype(kv_dt), 1.0
             else:
                 q_in, k_in, v_in, sc = q, k, v, scale
-            o, m, l = seqpar._attn_block_partial(
-                q_in, k_in, v_in, jnp.arange(S), jnp.zeros((), jnp.int32),
-                causal=True, window=window, is_global=None, scale=sc)
             # past partial vs the slot's already-written pages
             if use_window:
                 kname, vname, idx_l = "k_pages_w", "v_pages_w", w_idx
@@ -672,29 +700,37 @@ class KVNANDEngine:
             else:
                 kname, vname, idx_l = "k_pages_g", "v_pages_g", g_idx
                 base, table = base_g, self._table
-            kp = self._layer_slice(pools[kname], idx_l)
-            vp = self._layer_slice(pools[vname], idx_l)
-            ks = vs = None
-            if fmt != "none":
-                sfx = "w" if use_window else "g"
-                ks = self._layer_slice(pools[f"k_scale_{sfx}"], idx_l)
-                vs = self._layer_slice(pools[f"v_scale_{sfx}"], idx_l)
+            with jax.named_scope("pool_view"):
+                kp = self._layer_slice(pools[kname], idx_l)
+                vp = self._layer_slice(pools[vname], idx_l)
+                ks = vs = None
+                if fmt != "none":
+                    sfx = "w" if use_window else "g"
+                    ks = self._layer_slice(pools[f"k_scale_{sfx}"], idx_l)
+                    vs = self._layer_slice(pools[f"v_scale_{sfx}"], idx_l)
             from repro.kernels.paged_attention import paged_chunk_attention
-            o2, m2, l2 = paged_chunk_attention(
-                q, kp, vp, base, lengths, positions, window=window,
-                impl=self.eng.attn_impl, kv_quant=fmt, k_scale=ks,
-                v_scale=vs, page_table=table if shared else None,
-                partitions=self.eng.attn_partitions)
-            o, m, l = seqpar.merge_two(o, m, l, o2, m2, l2)
-            aout = attn_mod.project_out(pl_["attn"], cfg,
-                                        o.astype(h.dtype))
+            with jax.named_scope("paged_attn"):
+                o, m, l = seqpar._attn_block_partial(
+                    q_in, k_in, v_in, jnp.arange(S),
+                    jnp.zeros((), jnp.int32), causal=True, window=window,
+                    is_global=None, scale=sc)
+                o2, m2, l2 = paged_chunk_attention(
+                    q, kp, vp, base, lengths, positions, window=window,
+                    impl=self.eng.attn_impl, kv_quant=fmt, k_scale=ks,
+                    v_scale=vs, page_table=table if shared else None,
+                    partitions=self.eng.attn_partitions)
+                o, m, l = seqpar.merge_two(o, m, l, o2, m2, l2)
+            with jax.named_scope("attn_out"):
+                aout = attn_mod.project_out(pl_["attn"], cfg,
+                                            o.astype(h.dtype))
             xc = xc + aout
             h = rms_norm(xc, pl_["ln2"], cfg.norm_eps)
-            if cfg.is_moe:
-                ff = moe(pl_["moe"], h, top_k=cfg.top_k,
-                         capacity_factor=rt.moe_capacity)
-            else:
-                ff = mlp(pl_["mlp"], h, cfg.gated_mlp)
+            with jax.named_scope("mlp"):
+                if cfg.is_moe:
+                    ff = moe(pl_["moe"], h, top_k=cfg.top_k,
+                             capacity_factor=rt.moe_capacity)
+                else:
+                    ff = mlp(pl_["mlp"], h, cfg.gated_mlp)
             return xc + ff, k, v
 
         def fwd_body(xc, xs):
@@ -710,7 +746,8 @@ class KVNANDEngine:
             return xc, {"k": jnp.stack(kv_k), "v": jnp.stack(kv_v)}
 
         x, span_kv = jax.lax.scan(fwd_body, x, idx)
-        logits = lm_head_logits(params, cfg, x)            # [B, S, V]
+        with jax.named_scope("logits"):
+            logits = lm_head_logits(params, cfg, x)        # [B, S, V]
 
         n_acc, aux = accept(logits)
         n_write = jnp.clip(jnp.asarray(n_acc, jnp.int32) + 1, 0, S)
@@ -775,9 +812,10 @@ class KVNANDEngine:
                         pools[vname], idx_l, phys, slot_s, v_span)
             return pools, None
 
-        pools, _ = jax.lax.scan(append_body, pools,
-                                {"kv": span_kv, "g0": idx["g0"],
-                                 "w0": idx["w0"]})
+        with jax.named_scope("kv_append"):
+            pools, _ = jax.lax.scan(append_body, pools,
+                                    {"kv": span_kv, "g0": idx["g0"],
+                                     "w0": idx["w0"]})
 
         updates: Dict[str, Any] = dict(pools)
         if cache.page_pos_w is not None:
@@ -1170,7 +1208,8 @@ class KVNANDEngine:
                 cache.page_pos_w, vals[None], (slot, zero))
         cache = dataclasses.replace(cache, **updates)
         x_last = jax.lax.dynamic_slice_in_dim(x, v_len - 1, 1, 1)
-        logits = lm_head_logits(params, cfg, x_last)[:, 0]
+        with jax.named_scope("logits"):
+            logits = lm_head_logits(params, cfg, x_last)[:, 0]
         return logits, cache
 
     def _chunk_past_partial(self, pools, kname, vname, ksname, vsname, idx,
@@ -1183,40 +1222,46 @@ class KVNANDEngine:
         fmt = self.eng.kv_quant
         from repro.kernels.paged_attention import paged_chunk_attention
         if ck["shared"]:
-            kp = self._layer_slice(pools[kname], idx)     # [K, P, Ts, dh]
-            vp = self._layer_slice(pools[vname], idx)
-            ks = vs = None
-            if fmt != "none":
-                ks = self._layer_slice(pools[ksname], idx)
-                vs = self._layer_slice(pools[vsname], idx)
-            return paged_chunk_attention(
-                q, kp, vp, base, ck["start"], ck["q_pos"], window=window,
-                impl=self.eng.attn_impl, kv_quant=fmt, k_scale=ks,
-                v_scale=vs, page_table=trow[None],
-                partitions=self.eng.attn_partitions)
+            with jax.named_scope("pool_view"):
+                kp = self._layer_slice(pools[kname], idx)  # [K, P, Ts, dh]
+                vp = self._layer_slice(pools[vname], idx)
+                ks = vs = None
+                if fmt != "none":
+                    ks = self._layer_slice(pools[ksname], idx)
+                    vs = self._layer_slice(pools[vsname], idx)
+            with jax.named_scope("paged_attn"):
+                return paged_chunk_attention(
+                    q, kp, vp, base, ck["start"], ck["q_pos"],
+                    window=window, impl=self.eng.attn_impl, kv_quant=fmt,
+                    k_scale=ks, v_scale=vs, page_table=trow[None],
+                    partitions=self.eng.attn_partitions)
         Lp, B, K, NP, Ts, dh = pools[kname].shape
         zero = jnp.zeros((), jnp.int32)
         pidx = (idx, ck["slot"], zero, zero, zero, zero)
-        kp = jax.lax.dynamic_slice(pools[kname], pidx,
-                                   (1, 1, K, NP, Ts, dh))[0]
-        vp = jax.lax.dynamic_slice(pools[vname], pidx,
-                                   (1, 1, K, NP, Ts, dh))[0]
-        ks = vs = None
-        if fmt != "none":
-            sidx = pidx[:4]
-            ks = jax.lax.dynamic_slice(pools[ksname], sidx, (1, 1, K, NP))[0]
-            vs = jax.lax.dynamic_slice(pools[vsname], sidx, (1, 1, K, NP))[0]
-        if ck["mesh_on"] and ck["plan"].page_axes_g:
-            return seqpar.sharded_chunk_attention(
-                q, kp, vp, base, ck["start"], ck["q_pos"], self.mesh,
-                window=window, page_axes=ck["plan"].page_axes_g,
-                impl=self.eng.attn_impl, kv_quant=fmt,
-                k_scale=ks, v_scale=vs,
-                partitions=self.eng.attn_partitions)
-        return paged_chunk_attention(
-            q, kp, vp, base, ck["start"], ck["q_pos"], window=window,
-            impl=self.eng.attn_impl, kv_quant=fmt, k_scale=ks, v_scale=vs,
-            partitions=self.eng.attn_partitions)
+        with jax.named_scope("pool_view"):
+            kp = jax.lax.dynamic_slice(pools[kname], pidx,
+                                       (1, 1, K, NP, Ts, dh))[0]
+            vp = jax.lax.dynamic_slice(pools[vname], pidx,
+                                       (1, 1, K, NP, Ts, dh))[0]
+            ks = vs = None
+            if fmt != "none":
+                sidx = pidx[:4]
+                ks = jax.lax.dynamic_slice(pools[ksname], sidx,
+                                           (1, 1, K, NP))[0]
+                vs = jax.lax.dynamic_slice(pools[vsname], sidx,
+                                           (1, 1, K, NP))[0]
+        with jax.named_scope("paged_attn"):
+            if ck["mesh_on"] and ck["plan"].page_axes_g:
+                return seqpar.sharded_chunk_attention(
+                    q, kp, vp, base, ck["start"], ck["q_pos"], self.mesh,
+                    window=window, page_axes=ck["plan"].page_axes_g,
+                    impl=self.eng.attn_impl, kv_quant=fmt,
+                    k_scale=ks, v_scale=vs,
+                    partitions=self.eng.attn_partitions)
+            return paged_chunk_attention(
+                q, kp, vp, base, ck["start"], ck["q_pos"], window=window,
+                impl=self.eng.attn_impl, kv_quant=fmt, k_scale=ks,
+                v_scale=vs, partitions=self.eng.attn_partitions)
 
     def _chunk_block(self, pl_, x, positions, is_glob, pools, states,
                      l_idx, g_idx, w_idx):
@@ -1227,15 +1272,17 @@ class KVNANDEngine:
             return self._rwkv_chunk_block(pl_, x, pools, states, l_idx)
 
         h = rms_norm(x, pl_["ln1"], cfg.norm_eps)
-        q, k, v = attn_mod.project_qkv(pl_["attn"], cfg, h, positions)
+        with jax.named_scope("qkv"):
+            q, k, v = attn_mod.project_qkv(pl_["attn"], cfg, h, positions)
         use_window = (cfg.window is not None) and not is_glob
         window = cfg.window if use_window else None
         scale = cfg.d_head ** -0.5
 
         # in-chunk causal partial over the chunk's own (full-precision) K/V
-        o, m, l = seqpar._attn_block_partial(
-            q, k, v, ck["q_pos"], ck["start"], causal=True, window=window,
-            is_global=None, scale=scale)
+        with jax.named_scope("paged_attn"):
+            o, m, l = seqpar._attn_block_partial(
+                q, k, v, ck["q_pos"], ck["start"], causal=True,
+                window=window, is_global=None, scale=scale)
         if not ck["first"]:
             # past-context partial from the already-written pages
             if use_window:
@@ -1248,8 +1295,11 @@ class KVNANDEngine:
                     pools, "k_pages_g", "v_pages_g", "k_scale_g",
                     "v_scale_g", g_idx, q, ck["base_g"], None,
                     trow=ck.get("trow_g"))
-            o, m, l = seqpar.merge_two(o, m, l, o2, m2, l2)
-        aout = attn_mod.project_out(pl_["attn"], cfg, o.astype(h.dtype))
+            with jax.named_scope("paged_attn"):
+                o, m, l = seqpar.merge_two(o, m, l, o2, m2, l2)
+        with jax.named_scope("attn_out"):
+            aout = attn_mod.project_out(pl_["attn"], cfg,
+                                        o.astype(h.dtype))
 
         # fill the chunk's K/V into the slot's pages (whole pages, in place)
         fmt = self.eng.kv_quant
@@ -1266,21 +1316,23 @@ class KVNANDEngine:
         for prefix_, kv_seq in (("k", k), ("v", v)):
             name = names[0] if prefix_ == "k" else names[1]
             sname = names[2] if prefix_ == "k" else names[3]
-            if ck["mesh_on"] and ck["plan"].page_axes_g and not use_window:
-                out = seqpar.sharded_chunk_fill(
-                    pools[name], kv_seq, fill_idx, ck["slot"], ck["page0"],
-                    ck["v_len"], self.mesh,
-                    batch_axes=ck["plan"].batch_axes,
-                    page_axes=ck["plan"].page_axes_g,
-                    scale=pools.get(sname), kv_quant=fmt)
-            elif ck["shared"]:
-                out = fill_sh(pools[name], kv_seq, fill_idx, trow,
-                              ck["page0"], ck["v_len"],
-                              scale=pools.get(sname), kv_quant=fmt)
-            else:
-                out = fill(pools[name], kv_seq, fill_idx, ck["slot"],
-                           ck["page0"], ck["v_len"],
-                           scale=pools.get(sname), kv_quant=fmt)
+            with jax.named_scope("kv_append"):
+                if (ck["mesh_on"] and ck["plan"].page_axes_g
+                        and not use_window):
+                    out = seqpar.sharded_chunk_fill(
+                        pools[name], kv_seq, fill_idx, ck["slot"],
+                        ck["page0"], ck["v_len"], self.mesh,
+                        batch_axes=ck["plan"].batch_axes,
+                        page_axes=ck["plan"].page_axes_g,
+                        scale=pools.get(sname), kv_quant=fmt)
+                elif ck["shared"]:
+                    out = fill_sh(pools[name], kv_seq, fill_idx, trow,
+                                  ck["page0"], ck["v_len"],
+                                  scale=pools.get(sname), kv_quant=fmt)
+                else:
+                    out = fill(pools[name], kv_seq, fill_idx, ck["slot"],
+                               ck["page0"], ck["v_len"],
+                               scale=pools.get(sname), kv_quant=fmt)
             if fmt != "none":
                 pools[name], pools[sname] = out
             else:
@@ -1312,11 +1364,12 @@ class KVNANDEngine:
         x = x + aout
 
         h = rms_norm(x, pl_["ln2"], cfg.norm_eps)
-        if cfg.is_moe:
-            ff = moe(pl_["moe"], h, top_k=cfg.top_k,
-                     capacity_factor=rt.moe_capacity)
-        else:
-            ff = mlp(pl_["mlp"], h, cfg.gated_mlp)
+        with jax.named_scope("mlp"):
+            if cfg.is_moe:
+                ff = moe(pl_["moe"], h, top_k=cfg.top_k,
+                         capacity_factor=rt.moe_capacity)
+            else:
+                ff = mlp(pl_["mlp"], h, cfg.gated_mlp)
         return x + ff, pools, states
 
     def _rwkv_chunk_block(self, pl_, x, pools, states, l_idx):

@@ -107,6 +107,7 @@ def flash_attention_pallas(
 
     return pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, dh), lambda b, h, iq, ik: (b, h, iq, 0)),

@@ -163,8 +163,9 @@ def _kernel_shared(tbl_ref, *refs, **kw):
 
 
 def _paged_call(kernel, prefetch, q, k_pages, v_pages, scales, page_block,
-                page_index, *, B, NP, n_blocks, partitions, interpret):
-    """pallas_call over grid (B, K[, partitions], n_blocks).
+                page_index, *, name, B, NP, n_blocks, partitions,
+                interpret):
+    """pallas_call `name` over grid (B, K[, partitions], n_blocks).
 
     page_index(b, k, blk, *prefetch_refs) is the page block's index for
     global page-block `blk`; partitions > 1 adds a PARALLEL partition axis
@@ -206,6 +207,7 @@ def _paged_call(kernel, prefetch, q, k_pages, v_pages, scales, page_block,
     semantics = ("parallel",) * (len(grid) - 1) + ("arbitrary",)
     o, m, l = pl.pallas_call(
         kernel,
+        name=name,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
@@ -256,8 +258,8 @@ def paged_attention_pallas_shared(
         kernel, (table, page_base, length), q, k_pages, v_pages, scales,
         (1, 1, Ts, dh),
         lambda b, k, blk, tbl, *_: (k, tbl[b, blk], 0, 0),
-        B=B, NP=NP, n_blocks=npp, partitions=partitions,
-        interpret=interpret)
+        name="paged_attention_shared", B=B, NP=NP, n_blocks=npp,
+        partitions=partitions, interpret=interpret)
 
 
 def paged_attention_pallas(
@@ -301,5 +303,5 @@ def paged_attention_pallas(
         kernel, (page_base, length), q, k_pages, v_pages, scales,
         (1, 1, ppb, Ts, dh),
         lambda b, k, blk, *_: (b, k, blk, 0, 0),
-        B=B, NP=NP, n_blocks=npp // ppb, partitions=partitions,
-        interpret=interpret)
+        name="paged_attention", B=B, NP=NP, n_blocks=npp // ppb,
+        partitions=partitions, interpret=interpret)

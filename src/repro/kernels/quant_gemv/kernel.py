@@ -83,6 +83,7 @@ def quant_gemv_pallas(x, q, scale, scheme: str, *, block_d: int = 512,
 
     return pl.pallas_call(
         kernel,
+        name="quant_gemv",
         grid=(F // bf, n_d),
         in_specs=[
             pl.BlockSpec((M, bd), lambda f, d: (0, d)),

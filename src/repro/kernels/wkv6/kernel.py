@@ -84,6 +84,7 @@ def wkv6_pallas(r, k, v, logw, u, s0, *, chunk: int = 32,
     kernel = functools.partial(_kernel, chunk=chunk, n_chunks=n_chunks)
     return pl.pallas_call(
         kernel,
+        name="wkv6",
         grid=grid,
         in_specs=[
             seq_spec, seq_spec, seq_spec, seq_spec,

@@ -42,6 +42,8 @@ import threading
 import traceback
 from typing import Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from repro.serving.api import (KVNANDServer, SamplingParams, ServerConfig,
                                StreamEvent)
 from repro.serving.metrics import ServingMetrics
@@ -152,17 +154,21 @@ class AsyncKVNANDServer:
             kind, payload = self._cmd.get(timeout=self._acfg.idle_poll_s)
         except queue.Empty:
             return
-        self._apply(kind, payload)
+        with TraceAnnotation("kvnand.commands"):
+            self._apply(kind, payload)
 
     def _drain_commands(self) -> bool:
-        worked = False
-        while True:
-            try:
-                kind, payload = self._cmd.get_nowait()
-            except queue.Empty:
-                return worked
-            self._apply(kind, payload)
-            worked = True
+        # the engine thread is the queue's only consumer, so a non-empty
+        # queue holds at least one command for the loop below
+        if self._cmd.empty():
+            return False
+        with TraceAnnotation("kvnand.commands"):
+            while True:
+                try:
+                    kind, payload = self._cmd.get_nowait()
+                except queue.Empty:
+                    return True
+                self._apply(kind, payload)
 
     def _apply(self, kind: str, payload):
         if kind == "abort":
@@ -170,7 +176,7 @@ class AsyncKVNANDServer:
             # the abort's terminal marker event surfaces at the next
             # collect/step via _drain_events; route it even when the
             # scheduler goes idle
-            self._route_events(self._server._drain_events())
+            self._deliver(self._server._drain_events())
             return
         sub: _Submission = payload
         if self._engine_exc is not None:
@@ -193,6 +199,10 @@ class AsyncKVNANDServer:
             lambda: None if fut.cancelled() else setter(value))
 
     def _route_events(self, events: List[StreamEvent]):
+        with TraceAnnotation("kvnand.route", events=len(events)):
+            self._deliver(events)
+
+    def _deliver(self, events: List[StreamEvent]):
         for ev in events:
             q = self._subs.get(ev.uid)
             if q is not None:

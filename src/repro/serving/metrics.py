@@ -59,6 +59,10 @@ _STAT_COUNTERS = (
      "Overlapped-pipeline rows discarded at collect (DESIGN.md §14)"),
     ("deadline_drops", "kvnand_deadline_drops_total",
      "Queued requests expired past their deadline"),
+    ("decode_pages_walked", "kvnand_decode_pages_walked_total",
+     "Page visits per layer made by decode/verify attention walks"),
+    ("decode_pages_live", "kvnand_decode_pages_live_total",
+     "Pages holding the active rows' context in those walks"),
 )
 
 

@@ -121,6 +121,7 @@ from typing import Deque, Dict, List, Optional, Set
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import EngineConfig, ModelConfig
 from repro.core import paged_kv
@@ -335,10 +336,11 @@ class ContinuousBatcher:
             # dispatch free of eager per-step ops on the host path
             t = jnp.where(chain[:, None], prev_t[:, None], t)
             logits, c = self.engine.decode_step(p, c, t, active=a)
-            toks, lps = sample_with_logprobs(
-                logits, request_keys(seeds, pos),
-                true_vocab=self.cfg.vocab_size, temperature=temps,
-                top_k=tk, top_p=tp)
+            with jax.named_scope("sampling"):
+                toks, lps = sample_with_logprobs(
+                    logits, request_keys(seeds, pos),
+                    true_vocab=self.cfg.vocab_size, temperature=temps,
+                    top_k=tk, top_p=tp)
             return toks, lps, c
 
         self._decode = jax.jit(_decode_fn, donate_argnums=(1,))
@@ -350,10 +352,11 @@ class ContinuousBatcher:
             # into `speculative_accept` with the span logits, so the one
             # jitted step covers forward + accept + gated span append
             def _accept(logits):
-                toks, lps, acc = speculative_accept(
-                    logits, t[:, 1:], seeds, pos, allowed,
-                    true_vocab=self.cfg.vocab_size, temperature=temps,
-                    top_k=tk, top_p=tp)
+                with jax.named_scope("sampling"):
+                    toks, lps, acc = speculative_accept(
+                        logits, t[:, 1:], seeds, pos, allowed,
+                        true_vocab=self.cfg.vocab_size, temperature=temps,
+                        top_k=tk, top_p=tp)
                 return acc, (toks, lps, acc)
 
             aux, c = self.engine.verify_step(p, c, t, accept=_accept,
@@ -385,7 +388,12 @@ class ContinuousBatcher:
                       "tier_promotes": 0, "tier_demotes": 0,
                       "tier_prefetch_pages": 0, "tier_peak_hot": 0,
                       "phantom_tokens": 0, "deadline_drops": 0,
-                      "device_idle_s": 0.0}
+                      "device_idle_s": 0.0,
+                      "decode_pages_walked": 0, "decode_pages_live": 0}
+        # page visits per layer of one decode/verify step's paged walk:
+        # every row of the batch times the pages of the kernel's grid
+        # (0: no global pool, nothing counted)
+        self._walk_pages = self.engine.decode_page_visits(self.cache)
         self._compile_keys = set()
         if self.shared:
             self._init_shared_pool(eng)
@@ -985,28 +993,32 @@ class ContinuousBatcher:
             c0 = ps.pos
             chunk, cl = ps.tokens[c0:c0 + self.chunk_tokens], \
                 min(self.chunk_tokens, ps.n - c0)
-        if self.shared:
-            # lazy page allocation: back every page this chunk will write
-            T = self.engine.eng.page_tokens
-            span = c0 + cl + (self._prefix if c0 == 0 else 0)
-            if self.alloc is not None:
-                for lp in range(c0 // T, -(-span // T)):
-                    self._ensure_page(i, lp)
-            self._push_tables()
-        fn = self._chunk_first if c0 == 0 else self._chunk_cont
-        self._count_compile("chunk", c0 == 0, len(chunk))
-        logits, self.cache = fn(
-            self.params, self.cache, *self._put((
-                np.asarray(chunk)[None], np.int32(i), np.int32(c0),
-                np.int32(cl))))
+        with TraceAnnotation("kvnand.prefill_enqueue", tokens=int(cl)):
+            if self.shared:
+                # lazy page allocation: back every page this chunk writes
+                T = self.engine.eng.page_tokens
+                span = c0 + cl + (self._prefix if c0 == 0 else 0)
+                if self.alloc is not None:
+                    for lp in range(c0 // T, -(-span // T)):
+                        self._ensure_page(i, lp)
+                self._push_tables()
+            fn = self._chunk_first if c0 == 0 else self._chunk_cont
+            self._count_compile("chunk", c0 == 0, len(chunk))
+            logits, self.cache = fn(
+                self.params, self.cache, *self._put((
+                    np.asarray(chunk)[None], np.int32(i), np.int32(c0),
+                    np.int32(cl))))
         ps.pos = c0 + len(chunk)
         self.stats["prefill_chunks"] += 1
         if ps.pos >= ps.n:                         # prompt fully prefilled
             del self._prefill_live[i]
             self._lengths[i] = self._prefix + ps.n
-            if self.prefix_cache is not None:
-                self._register_prefix(i, ps, np.asarray(logits[0]))
-            tok, lp = self._sample_row(logits, ps.req)
+            # the host blocks here until the chunk (and every step queued
+            # before it) has run on the device
+            with TraceAnnotation("kvnand.first_token_wait"):
+                if self.prefix_cache is not None:
+                    self._register_prefix(i, ps, np.asarray(logits[0]))
+                tok, lp = self._sample_row(logits, ps.req)
             self._emit_token(i, ps.req, tok, lp)
 
     def step(self) -> int:
@@ -1050,7 +1062,8 @@ class ContinuousBatcher:
             # verify steps draft from host-visible history, and the
             # pipeline is one step deep — drain before dispatching again
             self.collect()
-        self._admit()
+        with TraceAnnotation("kvnand.admit"):
+            self._admit()
         n_decoding = sum(1 for i, r in enumerate(self.slots)
                          if r is not None and i not in self._prefill_live
                          and not r.hold)
@@ -1077,10 +1090,7 @@ class ContinuousBatcher:
                   and not r.hold
                   and not self._will_finish(i, int(i in pending))]
         if active:
-            if self.spec_k > 0:
-                self._dispatch_verify(active)
-            else:
-                self._dispatch_sequential(active)
+            self._dispatch_decode(active)
         self.stats["steps"] += 1
         return chunks_done
 
@@ -1091,16 +1101,26 @@ class ContinuousBatcher:
         timestamps are stamped here, when tokens are host-visible — then
         run the queue-ahead tier prefetch.  Returns slots advanced; a
         no-op (apart from the prefetch tick) when nothing is in flight."""
-        emitted = 0
-        if self._inflight:
-            inf = self._inflight.popleft()
-            if inf.kind == "verify":
-                emitted = self._collect_verify(inf)
-            else:
-                emitted = self._collect_decode(inf)
-        self._tier_prefetch_tick()
+        emitted = self._complete(
+            self._inflight.popleft() if self._inflight else None)
         if not self._inflight:
             self._idle_since = time.monotonic()
+        return emitted
+
+    def _complete(self, inf: Optional[_Inflight]) -> int:
+        """Fetch a dispatched step's outputs (the device wait), then
+        emit its rows and run the tier prefetch tick (host work)."""
+        got = None
+        if inf is not None:
+            with TraceAnnotation("kvnand.fetch", rows=len(inf.active)):
+                got = jax.device_get((inf.toks, inf.lps, inf.acc))
+        with TraceAnnotation("kvnand.emit"):
+            emitted = 0
+            if inf is not None:
+                emitted = (self._emit_verify(inf, *got)
+                           if inf.kind == "verify"
+                           else self._emit_decode(inf, *got[:2]))
+            self._tier_prefetch_tick()
         return emitted
 
     @property
@@ -1117,13 +1137,28 @@ class ContinuousBatcher:
         otherwise (or when no row may accept) the sequential step."""
         if not active:
             return 0
-        if self.spec_k > 0:
-            self._dispatch_verify(active)
-        else:
-            self._dispatch_sequential(active)
-        inf = self._inflight.popleft()
-        return (self._collect_verify(inf) if inf.kind == "verify"
-                else self._collect_decode(inf))
+        self._dispatch_decode(active)
+        return self._complete(self._inflight.popleft())
+
+    def _dispatch_decode(self, active: List[int]):
+        """Enqueue the decode batch over `active` slots: a verify step
+        under speculation, else the sequential step."""
+        with TraceAnnotation("kvnand.decode_enqueue", rows=len(active)):
+            if self.spec_k > 0:
+                self._dispatch_verify(active)
+            else:
+                self._dispatch_sequential(active)
+
+    def _count_walk(self, context: np.ndarray):
+        """Decode-walk counters of one enqueued step: the page visits
+        per layer its paged attention makes (`decode_pages_walked`) and
+        the pages holding the active rows' `context` tokens
+        (`decode_pages_live`)."""
+        if not self._walk_pages:
+            return
+        T = self.engine.eng.page_tokens
+        self.stats["decode_pages_walked"] += self._walk_pages
+        self.stats["decode_pages_live"] += int(np.sum(-(-context // T)))
 
     def _dispatch_sequential(self, active: List[int]):
         """Enqueue one masked decode over `active` slots, sampling each
@@ -1169,6 +1204,7 @@ class ContinuousBatcher:
                 (ch, prev_t, mask, self._temps, self._topk, self._topp,
                  self._seeds, positions)))
         self._lengths[active] += 1
+        self._count_walk(self._lengths[active])
         cap = {i for i in active
                if self._lengths[i] + 1 >= self.max_context}
         self._inflight.append(_Inflight(
@@ -1176,11 +1212,10 @@ class ContinuousBatcher:
             {i: self.slots[i] for i in active}, toks, lps,
             cap_finish=cap))
 
-    def _collect_decode(self, inf: _Inflight) -> int:
-        """Emit one collected sequential step: a single host transfer
-        fetches tokens and logprobs together, then each surviving row
+    def _emit_decode(self, inf: _Inflight, toks: np.ndarray,
+                     lps: np.ndarray) -> int:
+        """Emit one collected sequential step: each surviving row
         advances through the finish rules."""
-        toks, lps = jax.device_get((inf.toks, inf.lps))
         emitted = 0
         for i in inf.active:
             req = inf.reqs[i]
@@ -1271,6 +1306,7 @@ class ContinuousBatcher:
             self._push_tables()
         self._mark_device_busy()
         self._count_compile("verify", self.B, S)
+        self._count_walk(self._lengths[active] + S)
         (toks, lps, acc), self.cache = self._verify(
             self.params, self.cache, *self._put(
                 (tokens, mask, allowed, self._temps, self._topk,
@@ -1279,14 +1315,14 @@ class ContinuousBatcher:
             "verify", list(active), reqs, toks, lps, acc=acc,
             allowed=allowed))
 
-    def _collect_verify(self, inf: _Inflight) -> int:
+    def _emit_verify(self, inf: _Inflight, toks: np.ndarray,
+                     lps: np.ndarray, acc: np.ndarray) -> int:
         """Emit one collected verify step: every slot emits its accepted
         prefix plus the correction/bonus token through the same
         `_emit_token` finish rules and per-request PRNG streams as the
         sequential path — outputs identical token for token, only the
         tokens-per-step changes.  Length advance and span rollback are
         acceptance-dependent, so they live here on the collect side."""
-        toks, lps, acc = jax.device_get((inf.toks, inf.lps, inf.acc))
         allowed = inf.allowed
         emitted = 0
         for i in inf.active:
