@@ -168,23 +168,26 @@ class KVNANDEngine:
     # paged attention dispatch (single device vs sharded combine)
     # ------------------------------------------------------------------
     def _paged_attn(self, q, kp, vp, base, length, plan: ShardPlan,
-                    pool: str, window, ks=None, vs=None, table=None):
+                    pool: str, window, ks=None, vs=None, table=None,
+                    layer=None):
         """ks/vs: per-page×head dequant scales (None -> bf16 pool).
 
         kp/vp with a batch dim ([B, K, NP, T, dh]) read the slot's private
         stripe; 4-D pools ([K, P_total, T, dh]) are the SHARED pool and
-        `table` [B, NP] supplies the logical→physical walk.
+        `table` [B, NP] supplies the logical→physical walk.  With `layer`
+        (single device only) they are the stacked pools, one dim more,
+        read at that layer in place.
         """
         kv_quant = self.eng.kv_quant if ks is not None else "none"
         page_axes = plan.page_axes_g if pool == "g" else plan.page_axes_w
-        shared = kp.ndim == 4
+        shared = kp.ndim == (4 if layer is None else 5)
         if self.mesh is None or self.mesh.size == 1 or not page_axes:
             o, _, _ = paged_attention_partial(
                 q, kp, vp, base, length, window=window,
                 impl=self.eng.attn_impl, kv_quant=kv_quant,
                 k_scale=ks, v_scale=vs,
                 page_table=table if shared else None,
-                partitions=self.eng.attn_partitions)
+                partitions=self.eng.attn_partitions, layer=layer)
             return o
         if shared:
             return seqpar.paged_decode_attention_sharded_shared(
@@ -236,15 +239,17 @@ class KVNANDEngine:
     # per-layer attention (compact vs discrete)
     # ------------------------------------------------------------------
     def _attend_compact(self, pl_, x_norm, kp, vp, ks, vs, base, lengths,
-                        plan, pool, window, table=None):
+                        plan, pool, window, table=None, layer=None):
         """Fused QKV gen + attention (KVNAND-C, Fig 10b).  kp/vp are the
-        already-appended layer slices (+scales when the pool is quantized)."""
+        already-appended layer slices (+scales when the pool is quantized),
+        or the stacked pools and their `layer`."""
         with jax.named_scope("qkv"):
             q, _, _ = attn_mod.project_qkv(pl_["attn"], self.cfg, x_norm,
                                            lengths[:, None])
         with jax.named_scope("paged_attn"):
             return self._paged_attn(q[:, 0], kp, vp, base, lengths + 1,
-                                    plan, pool, window, ks, vs, table)
+                                    plan, pool, window, ks, vs, table,
+                                    layer)
 
     def _attend_discrete(self, pl_, x_norm, kp, vp, ks, vs, base, lengths,
                          plan, pool, window, table=None):
@@ -322,7 +327,7 @@ class KVNANDEngine:
         if self._active is not None:
             # interleaved scheduler: slots mid-prefill (or empty) must not
             # append — redirect their page index out of range so the
-            # mode="drop" scatter discards the write
+            # writer discards the write
             phys = jnp.where(self._active, phys, drop)
         page_axes = (plan.page_axes_w if use_window else plan.page_axes_g)
         sharded = (self.mesh is not None and self.mesh.size > 1
@@ -385,19 +390,28 @@ class KVNANDEngine:
                                                   slot, k1)
                 pools[vname] = self._append_token(pools[vname], idx, phys,
                                                   slot, v1)
-        with jax.named_scope("pool_view"):
-            kp = self._layer_slice(pools[kname], idx)
-            vp = self._layer_slice(pools[vname], idx)
-            ks = vs = None
-            if fmt != "none":
-                ks = self._layer_slice(pools[ksname], idx)
-                vs = self._layer_slice(pools[vsname], idx)
-
-        attend = (self._attend_discrete
-                  if self.eng.variant == "discrete" or self.eng.hg_pipeline
-                  else self._attend_compact)
-        o = attend(pl_, h, kp, vp, ks, vs, base, lengths, plan,
-                   "w" if use_window else "g", window, table)
+        pool = "w" if use_window else "g"
+        if (fmt == "none" and (self.mesh is None or self.mesh.size == 1)
+                and self.eng.variant != "discrete"
+                and not self.eng.hg_pipeline):
+            # the kernel reads this layer of the stacked pools in place:
+            # a layer slice would be a copy of it, in every layer
+            o = self._attend_compact(pl_, h, pools[kname], pools[vname],
+                                     None, None, base, lengths, plan, pool,
+                                     window, table, layer=idx)
+        else:
+            with jax.named_scope("pool_view"):
+                kp = self._layer_slice(pools[kname], idx)
+                vp = self._layer_slice(pools[vname], idx)
+                ks = vs = None
+                if fmt != "none":
+                    ks = self._layer_slice(pools[ksname], idx)
+                    vs = self._layer_slice(pools[vsname], idx)
+            attend = (self._attend_discrete
+                      if self.eng.variant == "discrete"
+                      or self.eng.hg_pipeline else self._attend_compact)
+            o = attend(pl_, h, kp, vp, ks, vs, base, lengths, plan, pool,
+                       window, table)
         with jax.named_scope("attn_out"):
             aout = attn_mod.project_out(pl_["attn"], cfg, o[:, None])
         return h, aout, pools

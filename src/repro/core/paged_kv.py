@@ -757,12 +757,14 @@ def append_global_shared(pool, layer, phys, slot, val):
     """Ragged one-token append into a shared stacked pool.
 
     pool: [L, K, P, Ts, dh]; phys/slot: [B] per-sequence physical page and
-    in-page slot; val: [B, K, dh].  phys >= P drops the write.
+    in-page slot; val: [B, K, dh].  phys >= P drops the write.  One
+    in-place write per sequence (`_put_tokens`), so the pool keeps its
+    layout.
     """
-    # layer (traced scalar) + phys/slot are non-adjacent advanced indices:
-    # scatter result dims are [B, K, dh]
-    return pool.at[layer, :, phys, slot].set(
-        val.astype(pool.dtype), mode="drop")
+    zero = jnp.zeros((), jnp.int32)
+    return _put_tokens(
+        pool, val[:, None, :, None, None, :], phys, slot,
+        lambda b, ph, sl: (layer, zero, ph, sl, zero))
 
 
 def append_token_quant_shared(pool, scale, layer, phys, slot, val,
@@ -910,26 +912,48 @@ def copy_page_shared(pool, src, dst):
 # cache pool leaves anywhere outside this module — DESIGN.md §15)
 # ---------------------------------------------------------------------------
 
+def _put_tokens(pool, upd, phys, slot, start):
+    """Write row b's token block upd[b] at start(b, phys[b], slot[b]),
+    one in-place dynamic_update_slice per row.
+
+    A scatter here would lower to whole-pool layout copies: XLA gives the
+    scatter the layout it prefers and converts the pool into it and back.
+    A dynamic_update_slice keeps the pool's layout.  It clamps where the
+    scatter's mode="drop" discarded, so a row at the drop sentinel
+    (phys >= the pool's page count) writes back the bytes already at the
+    clamped place, read in the same chain: it changes no page."""
+    n_pages = pool.shape[-3]
+    upd = upd.astype(pool.dtype)
+    for b in range(upd.shape[0]):
+        at = tuple(jnp.asarray(i, jnp.int32)
+                   for i in start(b, phys[b], slot[b]))
+        old = jax.lax.dynamic_slice(pool, at, upd.shape[1:])
+        pool = jax.lax.dynamic_update_slice(
+            pool, jnp.where(phys[b] < n_pages, upd[b], old), at)
+    return pool
+
+
 def append_token_inplace(pool, layer, phys, slot, val, *,
                          uniform_lengths: bool = False):
     """pool: [L, B, K, NP, T, dh]; write one token's K or V in place.
 
     Uniform-length fast path: all sequences advance in lockstep (static
     decode batching — every dry-run cell), so the append is ONE
-    dynamic_update_slice.  The general per-sequence path lowers to a
-    scatter, which XLA implements with whole-pool layout transposes
-    (measured 3× pool traffic per layer) — only the ragged continuous-
-    batching scheduler pays it.
+    dynamic_update_slice.  The ragged path (continuous batching) writes
+    each sequence's [K, dh] token with its own dynamic_update_slice at
+    (layer, b, 0, phys, slot, 0), and phys >= NP drops the write
+    (`_put_tokens`): the pool keeps the layout it has, so the paged
+    kernel can read it in place.
     """
     if uniform_lengths:
         upd = val[None, :, :, None, None, :].astype(pool.dtype)
         zero = jnp.zeros((), jnp.int32)
         return jax.lax.dynamic_update_slice(
             pool, upd, (layer, zero, zero, phys[0], slot[0], zero))
-    B = val.shape[0]
-    b_idx = jnp.arange(B)
-    return pool.at[layer, b_idx, :, phys, slot].set(
-        val.astype(pool.dtype), mode="drop")
+    zero = jnp.zeros((), jnp.int32)
+    return _put_tokens(
+        pool, val[:, None, None, :, None, None, :], phys, slot,
+        lambda b, ph, sl: (layer, b, zero, ph, sl, zero))
 
 
 def stage_hot_slot(cache: "DecodeCache", slot, vals) -> "DecodeCache":

@@ -13,6 +13,14 @@ them (the paper's head-group granule):
     logical→physical walk happens in SMEM before the DMA, never in the
     inner loop.
 
+Either pool may also come whole, with its leading layer axis
+([L, B, K, NP, T, dh] / [L, K, P_total, T, dh], the decode cache's own
+leaves), beside a traced `layer` index.  The index is one more
+scalar-prefetch operand that only the page index maps read: the layer
+dim is squeezed out of the page block, so the body sees the same block
+either way, and the decode step reads its layer's pages from the pool
+in place instead of copying the layer out first.
+
 page_base [B, NP] and length [B] arrive via scalar prefetch (SMEM): token
 validity is data-derived, so there is no gather in the inner loop.  SMEM
 yields scalars only, so per-page values (bases, kv8/kv4 scales) are read
@@ -156,10 +164,25 @@ def _kernel(base_ref, len_ref,                       # scalar prefetch (SMEM)
         l_ref[out] = l_scr[...]
 
 
-def _kernel_shared(tbl_ref, *refs, **kw):
-    """Shared-pool body: the table is consumed by the index maps only."""
-    del tbl_ref
-    _kernel(*refs, ppb=1, **kw)
+def _index_only(kernel, n: int):
+    """`kernel` behind n leading scalar-prefetch refs (layer index, page
+    table) that only the index maps read."""
+    def body(*refs):
+        kernel(*refs[n:])
+    return body
+
+
+def _with_layer(layer, prefetch, page_block, page_index):
+    """Prefetch, page block and page index map for a pool with or
+    without its leading layer axis.  With a layer, the traced index is
+    prefetched first and the squeezed (None) layer dim of the block is
+    addressed by it, so the body's block is unchanged."""
+    if layer is None:
+        return prefetch, page_block, page_index
+    lyr = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
+    return ((lyr,) + prefetch, (None,) + page_block,
+            lambda b, k, blk, lyr_ref, *pf: (lyr_ref[0],)
+            + page_index(b, k, blk, *pf))
 
 
 def _paged_call(kernel, prefetch, q, k_pages, v_pages, scales, page_block,
@@ -170,7 +193,8 @@ def _paged_call(kernel, prefetch, q, k_pages, v_pages, scales, page_block,
     page_index(b, k, blk, *prefetch_refs) is the page block's index for
     global page-block `blk`; partitions > 1 adds a PARALLEL partition axis
     whose per-partition partials land in [B, K, partitions, ...] outputs
-    for the caller's `merge.merge_partials`."""
+    for the caller's `merge.merge_partials`.  `kernel` takes the scalar
+    prefetch refs that the body reads (page_base, length) first."""
     K, G, dh = q.shape[1], q.shape[2], q.shape[3]
     if partitions == 1:
         grid = (B, K, n_blocks)
@@ -218,7 +242,7 @@ def _paged_call(kernel, prefetch, q, k_pages, v_pages, scales, page_block,
 
 def paged_attention_pallas_shared(
     q: jax.Array,          # [B, K, G, dh]
-    k_pages: jax.Array,    # [K, P_total, T, dh] (kv4: [K, P, T/2, dh])
+    k_pages: jax.Array,    # [(L,) K, P_total, T, dh] (kv4: [.., T/2, dh])
     v_pages: jax.Array,
     page_table: jax.Array,  # [B, NP] int32 physical indices (in range)
     page_base: jax.Array,  # [B, NP] absolute pos of slot 0 (<0 = unwritten)
@@ -230,6 +254,7 @@ def paged_attention_pallas_shared(
     k_scale: Optional[jax.Array] = None,   # [K, P_total] f32
     v_scale: Optional[jax.Array] = None,
     partitions: int = 1,
+    layer: Optional[jax.Array] = None,     # index into L (5-D pools)
 ):
     """Shared-pool paged decode attention: grid (B, K, NP) with the page
     table scalar-prefetched so the BLOCK INDEX MAP addresses the global
@@ -241,8 +266,14 @@ def paged_attention_pallas_shared(
     partials [B, K, partitions, ...] for the caller to merge
     (`merge.merge_partials`); the sequential scratch accumulation then
     only spans one partition's pages (the paper's head-group × split-page
-    parallel read, with NPU-side aggregation)."""
-    Ts, dh = k_pages.shape[2:]
+    parallel read, with NPU-side aggregation).
+
+    With `layer`, k/v_pages are the whole stacked pool [L, K, P_total,
+    T, dh] and the walk reads layer `layer` of it in place; scales stay
+    per layer ([K, P_total])."""
+    assert k_pages.ndim == (4 if layer is None else 5), (k_pages.shape,
+                                                         layer)
+    Ts, dh = k_pages.shape[-2:]
     B, NP = page_table.shape
     assert NP % partitions == 0, (NP, partitions)
     npp = NP // partitions
@@ -251,20 +282,22 @@ def paged_attention_pallas_shared(
     if kv_quant != "none":
         assert k_scale is not None and v_scale is not None, kv_quant
         scales = [_slot_scales(k_scale, table), _slot_scales(v_scale, table)]
-    kernel = functools.partial(_kernel_shared, n_blocks=npp, window=window,
+    prefetch, block, index = _with_layer(
+        layer, (table, page_base, length), (1, 1, Ts, dh),
+        lambda b, k, blk, tbl, *_: (k, tbl[b, blk], 0, 0))
+    kernel = functools.partial(_kernel, ppb=1, n_blocks=npp, window=window,
                                scale=dh ** -0.5, kv_quant=kv_quant,
                                partitioned=(partitions > 1))
     return _paged_call(
-        kernel, (table, page_base, length), q, k_pages, v_pages, scales,
-        (1, 1, Ts, dh),
-        lambda b, k, blk, tbl, *_: (k, tbl[b, blk], 0, 0),
+        _index_only(kernel, len(prefetch) - 2), prefetch, q, k_pages,
+        v_pages, scales, block, index,
         name="paged_attention_shared", B=B, NP=NP, n_blocks=npp,
         partitions=partitions, interpret=interpret)
 
 
 def paged_attention_pallas(
     q: jax.Array,          # [B, K, G, dh]
-    k_pages: jax.Array,    # [B, K, NP, T, dh] (kv4: [B, K, NP, T/2, dh])
+    k_pages: jax.Array,    # [(L,) B, K, NP, T, dh] (kv4: [.., T/2, dh])
     v_pages: jax.Array,
     page_base: jax.Array,  # [B, NP] int32
     length: jax.Array,     # [B] int32
@@ -276,6 +309,7 @@ def paged_attention_pallas(
     k_scale: Optional[jax.Array] = None,   # [B, K, NP] f32 per-page scales
     v_scale: Optional[jax.Array] = None,
     partitions: int = 1,
+    layer: Optional[jax.Array] = None,     # index into L (6-D pools)
 ):
     """Sequence-striped paged decode attention.
 
@@ -285,8 +319,14 @@ def paged_attention_pallas(
     partition's pages, while the partition axis is PARALLEL — each
     (kv-head, partition) pair is an independent walk whose partial lands
     in [B, K, partitions, ...] outputs for the caller's
-    `merge.merge_partials`."""
-    B, K, NP, Ts, dh = k_pages.shape
+    `merge.merge_partials`.
+
+    With `layer`, k/v_pages are the whole stacked pool [L, B, K, NP, T,
+    dh] and the walk reads layer `layer` of it in place; scales stay per
+    layer ([B, K, NP])."""
+    assert k_pages.ndim == (5 if layer is None else 6), (k_pages.shape,
+                                                         layer)
+    B, K, NP, Ts, dh = k_pages.shape[-5:]
     assert NP % partitions == 0, (NP, partitions)
     npp = NP // partitions
     ppb = min(pages_per_block, npp)
@@ -295,13 +335,15 @@ def paged_attention_pallas(
     if kv_quant != "none":
         assert k_scale is not None and v_scale is not None, kv_quant
         scales = [_slot_scales(k_scale), _slot_scales(v_scale)]
+    prefetch, block, index = _with_layer(
+        layer, (page_base, length), (1, 1, ppb, Ts, dh),
+        lambda b, k, blk, *_: (b, k, blk, 0, 0))
     kernel = functools.partial(_kernel, ppb=ppb, n_blocks=npp // ppb,
                                window=window, scale=dh ** -0.5,
                                kv_quant=kv_quant,
                                partitioned=(partitions > 1))
     return _paged_call(
-        kernel, (page_base, length), q, k_pages, v_pages, scales,
-        (1, 1, ppb, Ts, dh),
-        lambda b, k, blk, *_: (b, k, blk, 0, 0),
+        _index_only(kernel, len(prefetch) - 2), prefetch, q, k_pages,
+        v_pages, scales, block, index,
         name="paged_attention", B=B, NP=NP, n_blocks=npp // ppb,
         partitions=partitions, interpret=interpret)

@@ -127,7 +127,7 @@ def paged_chunk_attention(q, k_pages, v_pages, page_base, start, q_pos, *,
 
 def paged_attention_partial(
     q: jax.Array,          # [B, H, dh]
-    k_pages: jax.Array,    # [B, K, NP, T, dh] (kv4: packed [B, K, NP, T/2, dh])
+    k_pages: jax.Array,    # [(L,) B, K, NP, T, dh] (kv4: packed T/2)
     v_pages: jax.Array,
     page_base: jax.Array,  # [B, NP]
     length: jax.Array,     # [B]
@@ -141,6 +141,7 @@ def paged_attention_partial(
     v_scale: Optional[jax.Array] = None,
     page_table: Optional[jax.Array] = None,  # [B, NP] shared-pool tables
     partitions: int = 0,   # 0 = auto from page count; must divide NP
+    layer: Optional[jax.Array] = None,  # index into a stacked pool's L
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Returns (ō [B,H,dh] locally normalized, m [B,H], ℓ [B,H]).
 
@@ -153,6 +154,11 @@ def paged_attention_partial(
     merged via `merge_partials` (0 resolves per `resolve_partitions`):
     the ref path scans them (1/P-bounded intermediates), the Pallas path
     runs them as a parallel grid axis per kv-head group.
+
+    With `layer`, k/v_pages are the stacked pool with its leading layer
+    axis ([L, B, K, NP, T, dh] / shared [L, K, P_total, T, dh]; scales
+    stay per layer): the Pallas path reads that layer in place, the ref
+    path slices it out first.
     """
     _check_impl(impl)
     if impl == "auto":
@@ -165,12 +171,17 @@ def paged_attention_partial(
             "or 'auto'")
     B, H, dh = q.shape
     shared = page_table is not None
-    K = k_pages.shape[0] if shared else k_pages.shape[1]
+    lead = 0 if layer is None else 1
+    K = k_pages.shape[lead] if shared else k_pages.shape[lead + 1]
     G = H // K
-    NP = page_table.shape[1] if shared else k_pages.shape[2]
+    NP = page_table.shape[1] if shared else k_pages.shape[lead + 2]
     P = resolve_partitions(partitions, NP)
 
     if impl == "ref":
+        if layer is not None:
+            k_pages = jax.lax.dynamic_index_in_dim(k_pages, layer, 0, False)
+            v_pages = jax.lax.dynamic_index_in_dim(v_pages, layer, 0, False)
+
         def piece(lo, npp):
             sl = lambda a, axis: jax.lax.dynamic_slice_in_dim(a, lo, npp,
                                                               axis)
@@ -202,7 +213,7 @@ def paged_attention_partial(
             length.astype(jnp.int32), window=window,
             interpret=(impl == "interpret"),
             kv_quant=kv_quant, k_scale=k_scale, v_scale=v_scale,
-            partitions=P)
+            partitions=P, layer=layer)
         if P > 1:
             o, m, l = merge_partials(o, m, l, axis=2)
         return (o.reshape(B, H, dh).astype(q.dtype),
@@ -215,7 +226,7 @@ def paged_attention_partial(
         window=window, pages_per_block=ppb,
         interpret=(impl == "interpret"),
         kv_quant=kv_quant, k_scale=k_scale, v_scale=v_scale,
-        partitions=P)
+        partitions=P, layer=layer)
     if P > 1:
         o, m, l = merge_partials(o, m, l, axis=2)
     return (o.reshape(B, H, dh).astype(q.dtype),

@@ -251,3 +251,43 @@ def test_engine_active_mask_freezes_inactive_slots():
                                   before_k)
     assert int(cache2.lengths[1]) == int(cache.lengths[1])
     assert int(cache2.lengths[0]) == int(cache.lengths[0]) + 1
+
+
+def test_decode_step_kernel_in_place_matches_ref():
+    """The decode step whose kernel reads each layer of the stacked pools
+    in place (Pallas interpret) matches the jnp ref path, with one row
+    inactive: same logits for the active rows, same pools, and the
+    inactive row's stripe and length untouched."""
+    cfg, rt, params = _model()
+    kw = dict(page_tokens=8, kv_dtype="float32", uniform_lengths=False)
+    B, ctx = 3, 64
+    cache0 = None
+    for impl in ("ref", "interpret"):
+        eng = KVNANDEngine(cfg, EngineConfig(attn_impl=impl, **kw), rt)
+        if cache0 is None:
+            cache0 = eng.init_cache(B, ctx)
+            for b, n in ((0, 13), (1, 5), (2, 21)):
+                _, cache0 = eng.prefill_chunk(
+                    params, cache0,
+                    {"tokens": jnp.arange(1, n + 1, dtype=jnp.int32)[None]},
+                    jnp.asarray(b), jnp.asarray(0), jnp.asarray(n),
+                    first=True)
+        cache, logits = cache0, []
+        act = jnp.array([True, False, True])
+        for tok in (3, 7):
+            lg, cache = eng.decode_step(
+                params, cache, jnp.full((B, 1), tok, jnp.int32), active=act)
+            logits.append(lg)
+        if impl == "ref":
+            want, want_cache = logits, cache
+    scale = float(jnp.abs(want[0]).max())
+    for got, ref in zip(logits, want):
+        assert float(jnp.abs(got[::2] - ref[::2]).max()) / scale < 2e-5
+    for pool in ("k_pages_g", "v_pages_g"):
+        np.testing.assert_allclose(np.asarray(getattr(cache, pool)),
+                                   np.asarray(getattr(want_cache, pool)),
+                                   atol=1e-5, rtol=1e-5)
+        np.testing.assert_array_equal(
+            np.asarray(getattr(cache, pool)[:, 1]),
+            np.asarray(getattr(cache0, pool)[:, 1]))
+    np.testing.assert_array_equal(np.asarray(cache.lengths), [15, 5, 23])

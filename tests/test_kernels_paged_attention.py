@@ -224,3 +224,39 @@ def test_empty_shard_is_safe():
     o, _, _ = merge_two(o_full, m_full, l_full, o2, m2, l2)
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_full),
                                atol=1e-6)
+
+
+@pytest.mark.parametrize("impl", ["ref", "interpret"])
+@pytest.mark.parametrize("partitions", [1, 2])
+@pytest.mark.parametrize("kv_quant", ["none", "kv8"])
+@pytest.mark.parametrize("layout", ["striped", "shared"])
+def test_stacked_pool_at_layer_matches_layer_slice(layout, kv_quant,
+                                                   partitions, impl):
+    """The walk over layer l of a stacked pool (layer index traced, pool
+    read in place) returns exactly what the call on pool[l] returns."""
+    L, B, K, G, NP, T, dh, P, layer = 3, 2, 2, 2, 8, 8, 32, 20, 1
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    lead = (L, B, K, NP) if layout == "striped" else (L, K, P)
+    kp = jax.random.normal(ks[0], lead + (T, dh), jnp.float32)
+    vp = jax.random.normal(ks[1], lead + (T, dh), jnp.float32)
+    q = jax.random.normal(ks[2], (B, K * G, dh), jnp.float32)
+    length = jnp.asarray([61, 23], jnp.int32)
+    base = jnp.broadcast_to((jnp.arange(NP) * T)[None], (B, NP)
+                            ).astype(jnp.int32)
+    kw = dict(impl=impl, partitions=partitions, pages_per_block=2)
+    if layout == "shared":
+        kw["page_table"] = jax.random.permutation(ks[3], P)[:B * NP
+                                                            ].reshape(B, NP)
+    if kv_quant == "none":
+        kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+    else:
+        (kp, sk), (vp, sv) = (quantize_kv_page(kp, kv_quant),
+                              quantize_kv_page(vp, kv_quant))
+        kw.update(kv_quant=kv_quant, k_scale=sk[layer], v_scale=sv[layer])
+
+    stacked = jax.jit(lambda kp, vp, lyr: paged_attention_partial(
+        q, kp, vp, base, length, layer=lyr, **kw))(kp, vp, jnp.int32(layer))
+    sliced = jax.jit(lambda kp, vp: paged_attention_partial(
+        q, kp, vp, base, length, **kw))(kp[layer], vp[layer])
+    for got, want in zip(stacked, sliced):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

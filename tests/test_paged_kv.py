@@ -3,6 +3,7 @@ property tests over the page-mapping invariants (paper §IV-D)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.configs import EngineConfig, get_config
@@ -79,3 +80,35 @@ def test_cache_spec_page_rounding(ctx, t, shards):
     NP = spec["k_pages_g"][0][3]
     assert NP % shards == 0
     assert NP * t >= ctx
+
+
+@pytest.mark.parametrize("layout", ["stripe", "shared"])
+def test_ragged_append_matches_drop_scatter(layout):
+    """The per-row in-place append writes what a mode="drop" scatter
+    writes: active rows land their token, and a row at the drop sentinel
+    changes no page, not even the last one (where an unguarded
+    dynamic_update_slice would clamp it to)."""
+    L, B, K, NP, T, dh, P, layer = 3, 4, 2, 4, 8, 16, 10, 2
+    lead = (L, B, K, NP) if layout == "stripe" else (L, K, P)
+    drop = NP if layout == "stripe" else P
+    pool = jax.random.normal(jax.random.PRNGKey(0), lead + (T, dh))
+    val = jax.random.normal(jax.random.PRNGKey(1), (B, K, dh))
+    # rows 1 and 3 sit at the sentinel, which clamps onto the last page;
+    # row 2 writes that page, at the slot row 3 would clamp onto
+    phys = jnp.asarray([0, drop, drop - 1, drop], jnp.int32)
+    slot = jnp.asarray([3, T - 1, 5, 5], jnp.int32)
+    if layout == "stripe":
+        want = pool.at[layer, jnp.arange(B), :, phys, slot].set(
+            val, mode="drop")
+        got = jax.jit(lambda p, ph, sl, v: paged_kv.append_token_inplace(
+            p, jnp.int32(layer), ph, sl, v))(pool, phys, slot, val)
+        for b in (1, 3):
+            np.testing.assert_array_equal(np.asarray(got[:, b]),
+                                          np.asarray(pool[:, b]))
+    else:
+        want = pool.at[layer, :, phys, slot].set(val, mode="drop")
+        got = jax.jit(lambda p, ph, sl, v: paged_kv.append_global_shared(
+            p, jnp.int32(layer), ph, sl, v))(pool, phys, slot, val)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    changed = np.argwhere(np.asarray(got != pool).any(-1))
+    assert len(changed) == 2 * K            # rows 0 and 2 only
