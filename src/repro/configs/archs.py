@@ -16,3 +16,5 @@ from repro.configs import llama2_7b        # noqa: F401
 from repro.configs import llama3_1_8b      # noqa: F401
 from repro.configs import llama3_1_70b     # noqa: F401
 from repro.configs import mixtral_8x7b     # noqa: F401
+# Benchmark configurations beyond the above
+from repro.configs import trinity_mini     # noqa: F401

@@ -34,11 +34,24 @@ class ModelConfig:
     n_experts: int = 0
     top_k: int = 0
     capacity_factor: float = 1.25
+    router: str = "softmax"          # expert scores: "softmax" | "sigmoid"
+    route_scale: float = 1.0         # top-k weights (normalized) x this
+    expert_bias: bool = False        # score bias for selection only
+    n_shared_experts: int = 0        # always-on experts, each d_ff wide
+    n_experts_held: int = 0          # experts this chip holds (0 -> all)
+    expert_offset: int = 0           # index of the first held expert
+    n_dense_layers: int = 0          # leading layers with a dense FFN
+    dense_d_ff: int = 0              # their FFN width (0 -> d_ff)
     # attention flavour
     attn_bias: bool = False          # Qwen-style QKV bias
     window: Optional[int] = None     # sliding-window size (local attention)
     global_every: int = 0            # gemma3: every Nth layer is global
     rope_theta: float = 10_000.0
+    rope_global: bool = True         # False: global layers are NoPE
+    qk_norm: bool = False            # per-head RMSNorm on q and k
+    attn_gate: bool = False          # sigmoid(x Wgate) * attn before wo
+    sandwich_norm: bool = False      # RMSNorm after each sub-block too
+    embed_scale: float = 1.0         # x0 = embed(tok) * embed_scale
     # ssm / hybrid
     ssm_state: int = 0
     n_meta_tokens: int = 0           # hymba learnable meta tokens
@@ -63,6 +76,16 @@ class ModelConfig:
             raise ValueError(
                 f"{self.name}: n_heads={self.n_heads} not divisible by "
                 f"n_kv_heads={self.n_kv_heads}")
+        if self.router not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router {self.router!r}")
+        if self.expert_offset + self.experts_held > self.n_experts:
+            raise ValueError(
+                f"{self.name}: held experts [{self.expert_offset}, "
+                f"{self.expert_offset + self.experts_held}) exceed "
+                f"n_experts={self.n_experts}")
+        if self.n_dense_layers > self.n_layers:
+            raise ValueError(f"{self.name}: n_dense_layers="
+                             f"{self.n_dense_layers} > n_layers")
 
     # ------------------------------------------------------------------
     @property
@@ -86,6 +109,15 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.n_experts_held or self.n_experts
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers if self.is_moe else 0
 
     @property
     def is_attention_free(self) -> bool:
@@ -113,19 +145,28 @@ class ModelConfig:
 
     # ------------------------------------------------------------------
     def param_count(self) -> int:
-        """Exact dense-equivalent parameter count (all experts)."""
+        """Exact dense-equivalent parameter count (every routed expert,
+        held here or not)."""
         d, dh = self.d_model, self.d_head
         qkv = d * (self.q_dim + 2 * self.kv_dim)
         if self.attn_bias:
             qkv += self.q_dim + 2 * self.kv_dim
         o = self.q_dim * d
         attn = qkv + o
-        ffn_one = (3 if self.gated_mlp else 2) * d * self.d_ff
+        if self.attn_gate:
+            attn += d * self.q_dim
+        if self.qk_norm:
+            attn += 2 * dh
+        ffn_mult = 3 if self.gated_mlp else 2
+        ffn_one = ffn_mult * d * self.d_ff
         if self.is_moe:
-            ffn = self.n_experts * ffn_one + d * self.n_experts  # + router
+            ffn = (self.n_experts + self.n_shared_experts) * ffn_one \
+                + d * self.n_experts                          # + router
+            if self.expert_bias:
+                ffn += self.n_experts
         else:
             ffn = ffn_one
-        norms = 2 * d
+        norms = (4 if self.sandwich_norm else 2) * d
         per_layer = attn + ffn + norms
 
         if self.family == "ssm":  # rwkv6: replace attn with time-mix
@@ -135,7 +176,10 @@ class ModelConfig:
             ssm = 2 * d * d + d * (2 * self.ssm_state) + d  # in/out, B/C, dt
             per_layer = attn + ssm + ffn_one + norms
 
-        total = self.n_layers * per_layer
+        dense_layer = attn + ffn_mult * d * (self.dense_d_ff or self.d_ff) \
+            + norms
+        total = ((self.n_layers - self.n_dense_layers) * per_layer
+                 + self.n_dense_layers * dense_layer)
         total += self.padded_vocab * d  # embed
         if not self.tie_embeddings:
             total += self.padded_vocab * d  # lm head
@@ -155,7 +199,7 @@ class ModelConfig:
         d = self.d_model
         ffn_all = self.n_experts * 3 * d * self.d_ff
         ffn_act = self.top_k * 3 * d * self.d_ff
-        return self.param_count() - self.n_layers * (ffn_all - ffn_act)
+        return self.param_count() - self.n_moe_layers * (ffn_all - ffn_act)
 
     def kv_bytes_per_token(self, dtype_bytes: int = 2) -> int:
         if self.is_attention_free:
@@ -176,7 +220,7 @@ class ModelConfig:
         return replace(
             self,
             name=self.name + "-reduced",
-            n_layers=min(self.n_layers, 2),
+            n_layers=min(self.n_layers, 2) + min(self.n_dense_layers, 1),
             d_model=128,
             n_heads=n_heads,
             n_kv_heads=n_kv,
@@ -185,6 +229,10 @@ class ModelConfig:
             vocab_size=512,
             n_experts=min(self.n_experts, 4),
             top_k=min(self.top_k, 2),
+            n_experts_held=min(self.n_experts_held, 4),
+            expert_offset=0,
+            n_dense_layers=min(self.n_dense_layers, 1),
+            dense_d_ff=256 if self.dense_d_ff else 0,
             window=min(self.window, 64) if self.window else None,
             global_every=min(self.global_every, 2) if self.global_every else 0,
             ssm_state=min(self.ssm_state, 8),

@@ -24,12 +24,14 @@ against 4 KB of appended KV at qwen1.5-0.5b/decode_32k scale).
 
 Layer heterogeneity (gemma3 5:1 local:global, hymba sparse-global) scans
 over repeating layer *groups*; global/window pools are indexed by per-group
-base offsets carried as scanned index arrays.
+base offsets carried as scanned index arrays.  Models whose layers are not
+all alike (leading dense-FFN layers before MoE ones) keep one param stack
+per kind, each scanned in its own groups, in layer order (`LayerStack`).
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,8 +44,10 @@ from repro.kernels.paged_attention import paged_attention_partial
 from repro.models import attention as attn_mod
 from repro.models import rwkv6 as rwkv_mod
 from repro.models import ssm as ssm_mod
-from repro.models.layers import dense, embed_lookup, mlp, moe, rms_norm
-from repro.models.transformer import Runtime, embed_inputs, lm_head_logits
+from repro.models.layers import dense, rms_norm
+from repro.models.transformer import (Runtime, embed_inputs, embed_tokens,
+                                      ffn, layer_stacks, lm_head_logits,
+                                      post_norm)
 
 STATE_LEAVES = ("rwkv_state", "rwkv_shift", "rwkv_shift2", "ssm_state",
                 "conv_tail")
@@ -99,6 +103,63 @@ def plan_sharding(mesh: Optional[Mesh], batch: int,
 
 
 # ---------------------------------------------------------------------------
+# Layer stacks
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class LayerStack:
+    """One param stack (`params[name]`, a leading layer axis), run as a
+    lax.scan over `n_groups` groups of `period` layers whose global/window
+    pattern repeats.  Pool indices: a group's global (window) layers take
+    the global (window) pool's next `g_per_group` (`w_per_group`)
+    indices, `g_off`/`w_off` per position in the group."""
+    name: str
+    first: int                      # absolute index of its first layer
+    n_groups: int
+    pattern: Tuple[bool, ...]       # is_global per position in a group
+    g0: int                         # pool indices of its first layer
+    w0: int
+    g_off: Tuple[int, ...]
+    w_off: Tuple[int, ...]
+    g_per_group: int
+    w_per_group: int
+
+    @property
+    def period(self) -> int:
+        return len(self.pattern)
+
+    def bases(self) -> Dict[str, jax.Array]:
+        """Per-group base indices (scanned): layer, global, window pool."""
+        n = jnp.arange(self.n_groups, dtype=jnp.int32)
+        return {k: n * step + start if start else n * step
+                for k, start, step in (("l0", self.first, self.period),
+                                       ("g0", self.g0, self.g_per_group),
+                                       ("w0", self.w0, self.w_per_group))}
+
+
+def layer_stacks_of(cfg: ModelConfig) -> Tuple[LayerStack, ...]:
+    stacks = []
+    g = w = 0
+    for name, first, n in layer_stacks(cfg):
+        period, pattern = paged_kv.layer_pattern(cfg, first, n)
+        g_off, w_off = [], []
+        gp = wp = 0
+        for is_glob in pattern:
+            g_off.append(gp)
+            w_off.append(wp)
+            if cfg.family != "ssm":
+                if cfg.window is not None and not is_glob:
+                    wp += 1
+                else:
+                    gp += 1
+        stacks.append(LayerStack(name, first, n // period, pattern, g, w,
+                                 tuple(g_off), tuple(w_off), gp, wp))
+        g += gp * (n // period)
+        w += wp * (n // period)
+    return tuple(stacks)
+
+
+# ---------------------------------------------------------------------------
 # Engine
 # ---------------------------------------------------------------------------
 
@@ -109,21 +170,7 @@ class KVNANDEngine:
         self.eng = eng or EngineConfig()
         self.rt = rt or Runtime()
         self.mesh = mesh
-        self.period, self.pattern = paged_kv.layer_pattern(cfg)
-        # per-period static offsets into the global/window pools
-        self._g_off = []
-        self._w_off = []
-        g = w = 0
-        for is_glob in self.pattern:
-            use_window = (cfg.window is not None) and not is_glob
-            self._g_off.append(g)
-            self._w_off.append(w)
-            if cfg.family != "ssm":
-                if use_window:
-                    w += 1
-                else:
-                    g += 1
-        self.g_per_group, self.w_per_group = g, w
+        self.stacks = layer_stacks_of(cfg)
 
     # ------------------------------------------------------------------
     # cache construction
@@ -151,18 +198,67 @@ class KVNANDEngine:
                                        **self._cache_kw(batch, max_context,
                                                         enc_len))
 
-    def decode_page_visits(self, cache: DecodeCache) -> int:
+    def decode_page_visits(self, cache: DecodeCache, pool: str = "g") -> int:
         """Pages one decode (or verify) step's paged attention walks per
-        global-pool layer: rows × pages per row of the walk's grid — the
-        page-table width of a shared pool, the stripe's page count
-        otherwise (what `paged_attention_partial` sizes its grid from);
-        0 for archs with no global pool."""
-        if cache.k_pages_g is None:
+        layer of the global (`pool="g"`) or window (`"w"`) pool: rows ×
+        pages per row of the walk's grid — the page-table width of a
+        shared pool, the stripe's (ring's) page count otherwise (what
+        `paged_attention_partial` sizes its grid from); 0 for archs with
+        no such pool."""
+        pages = cache.k_pages_g if pool == "g" else cache.k_pages_w
+        if pages is None:
             return 0
         rows = cache.lengths.shape[0]
         if self.eng.shared_pool:
-            return rows * cache.page_table_g.shape[1]
-        return rows * cache.k_pages_g.shape[3]
+            table = cache.page_table_g if pool == "g" else cache.page_table_w
+            return rows * table.shape[1]
+        return rows * pages.shape[3]
+
+    def _walk(self, params, body: Callable, carry, extra=None):
+        """Every layer in order: ``body(carry, pl_, l, g, w, is_glob, xj)
+        -> (carry, y)`` with the layer's params `pl_` (None when `params`
+        is None), its layer / global-pool / window-pool indices (traced)
+        and whether it is global (static).  One lax.scan over groups per
+        stack.  `extra`: the per-stack ys of an earlier walk, `xj` the
+        layer's entry.  Returns (carry, per-stack ys)."""
+        out = []
+        for si, st in enumerate(self.stacks):
+            xs = st.bases()
+            if params is not None:
+                xs["p"] = jax.tree.map(
+                    lambda a, st=st: a.reshape((st.n_groups, st.period)
+                                               + a.shape[1:]),
+                    params[st.name])
+            if extra is not None:
+                xs["x"] = extra[si]
+
+            def group_body(carry, xs, st=st):
+                ys = []
+                for j, is_glob in enumerate(st.pattern):
+                    pick = lambda a, j=j: a[j]   # noqa: E731
+                    pl_ = (jax.tree.map(pick, xs["p"]) if "p" in xs
+                           else None)
+                    xj = jax.tree.map(pick, xs["x"]) if "x" in xs else None
+                    carry, y = body(carry, pl_, xs["l0"] + j,
+                                    xs["g0"] + st.g_off[j],
+                                    xs["w0"] + st.w_off[j], is_glob, xj)
+                    ys.append(y)
+                if all(y is None for y in ys):
+                    return carry, None
+                return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)
+
+            carry, ys = jax.lax.scan(group_body, carry, xs)
+            out.append(ys)
+        return carry, out
+
+    def _ffn_half(self, pl_, x, rows=None):
+        """x + FFN(norm(x)) (post-normed where the layer has it); returns
+        (x, held pairs of an MoE layer or None)."""
+        cfg = self.cfg
+        h = rms_norm(x, pl_["ln2"], cfg.norm_eps)
+        with jax.named_scope("mlp"):
+            ff, held = ffn(pl_, cfg, h, rows=rows)
+        return x + post_norm(pl_, "ln2_post", cfg, ff), held
 
     # ------------------------------------------------------------------
     # paged attention dispatch (single device vs sharded combine)
@@ -239,20 +335,21 @@ class KVNANDEngine:
     # per-layer attention (compact vs discrete)
     # ------------------------------------------------------------------
     def _attend_compact(self, pl_, x_norm, kp, vp, ks, vs, base, lengths,
-                        plan, pool, window, table=None, layer=None):
+                        plan, pool, window, table=None, layer=None,
+                        rope=True):
         """Fused QKV gen + attention (KVNAND-C, Fig 10b).  kp/vp are the
         already-appended layer slices (+scales when the pool is quantized),
         or the stacked pools and their `layer`."""
         with jax.named_scope("qkv"):
             q, _, _ = attn_mod.project_qkv(pl_["attn"], self.cfg, x_norm,
-                                           lengths[:, None])
+                                           lengths[:, None], rope=rope)
         with jax.named_scope("paged_attn"):
             return self._paged_attn(q[:, 0], kp, vp, base, lengths + 1,
                                     plan, pool, window, ks, vs, table,
                                     layer)
 
     def _attend_discrete(self, pl_, x_norm, kp, vp, ks, vs, base, lengths,
-                         plan, pool, window, table=None):
+                         plan, pool, window, table=None, rope=True):
         """Head-group pipelined attention (KVNAND-D, Fig 10a): q-GEMV of
         group i+1 is independent of group i's attention -> overlapped."""
         cfg = self.cfg
@@ -265,7 +362,7 @@ class KVNANDEngine:
             with jax.named_scope("qkv"):
                 q_next = attn_mod.project_q_group(
                     pl_["attn"], cfg, x_tok, jnp.minimum(i + 1, K - 1),
-                    lengths)
+                    lengths, rope=rope)
             # slice head group i on the K dim directly (no pool transpose)
             with jax.named_scope("pool_view"):
                 kp_i = jax.lax.dynamic_slice_in_dim(kp, i, 1, k_axis)
@@ -282,7 +379,8 @@ class KVNANDEngine:
 
         with jax.named_scope("qkv"):
             q0 = attn_mod.project_q_group(pl_["attn"], cfg, x_tok,
-                                          jnp.zeros((), jnp.int32), lengths)
+                                          jnp.zeros((), jnp.int32), lengths,
+                                          rope=rope)
         _, outs = jax.lax.scan(body, q0, jnp.arange(K))
         return outs.transpose(1, 0, 2, 3).reshape(B, cfg.n_heads,
                                                   cfg.d_head)
@@ -296,10 +394,12 @@ class KVNANDEngine:
         shared = self.eng.shared_pool
         h = rms_norm(x, pl_["ln1"], cfg.norm_eps)
         use_window = (cfg.window is not None) and not is_glob
+        rope = attn_mod.use_rope(cfg, is_glob)
         # K/V for the new token (the paper's ❸→❹ write into G2/own pages)
         with jax.named_scope("qkv"):
             _, k_new, v_new = attn_mod.project_qkv(pl_["attn"], cfg, h,
-                                                   lengths[:, None])
+                                                   lengths[:, None],
+                                                   rope=rope)
         k1, v1 = k_new[:, 0], v_new[:, 0]
         T = self.eng.page_tokens
         slot = lengths % T
@@ -398,7 +498,7 @@ class KVNANDEngine:
             # a layer slice would be a copy of it, in every layer
             o = self._attend_compact(pl_, h, pools[kname], pools[vname],
                                      None, None, base, lengths, plan, pool,
-                                     window, table, layer=idx)
+                                     window, table, layer=idx, rope=rope)
         else:
             with jax.named_scope("pool_view"):
                 kp = self._layer_slice(pools[kname], idx)
@@ -411,17 +511,19 @@ class KVNANDEngine:
                       if self.eng.variant == "discrete"
                       or self.eng.hg_pipeline else self._attend_compact)
             o = attend(pl_, h, kp, vp, ks, vs, base, lengths, plan, pool,
-                       window, table)
+                       window, table, rope=rope)
         with jax.named_scope("attn_out"):
-            aout = attn_mod.project_out(pl_["attn"], cfg, o[:, None])
+            aout = attn_mod.project_out(pl_["attn"], cfg, o[:, None], h)
         return h, aout, pools
 
     def _decode_block(self, pl_, x, pools, states, cross, l_idx, g_idx,
                       w_idx, lengths, plan, is_glob):
+        """One layer of the decode step: returns ((x, states), pools,
+        held pairs of an MoE layer or None)."""
         cfg = self.cfg
 
         if cfg.family == "ssm":
-            return self._rwkv_decode_block(pl_, x, states, l_idx), pools
+            return self._rwkv_decode_block(pl_, x, states, l_idx), pools, None
 
         h, aout, pools = self._decode_attn_layer(
             pl_, x, pools, g_idx, w_idx, lengths, plan, is_glob)
@@ -437,7 +539,7 @@ class KVNANDEngine:
             states["ssm_state"] = states["ssm_state"].at[l_idx].set(s_new)
             states["conv_tail"] = states["conv_tail"].at[l_idx].set(
                 tail_new.astype(states["conv_tail"].dtype))
-        x = x + aout
+        x = x + post_norm(pl_, "ln1_post", cfg, aout)
 
         if cross is not None:
             h = rms_norm(x, pl_["ln_cross"], cfg.norm_eps)
@@ -445,14 +547,8 @@ class KVNANDEngine:
             cv = self._layer_slice(cross["cross_v"], l_idx)
             x = x + self._cross_attention(pl_["cross"], h, ck, cv, plan)
 
-        h = rms_norm(x, pl_["ln2"], cfg.norm_eps)
-        with jax.named_scope("mlp"):
-            if cfg.is_moe:
-                ff = moe(pl_["moe"], h, top_k=cfg.top_k,
-                         capacity_factor=self.rt.moe_capacity)
-            else:
-                ff = mlp(pl_["mlp"], h, cfg.gated_mlp)
-        return ((x + ff, states), pools)
+        x, held = self._ffn_half(pl_, x, rows=self._active)
+        return (x, states), pools, held
 
     def _mask_state(self, *pairs):
         """Freeze recurrent-state updates for inactive slots: each pair is
@@ -521,7 +617,8 @@ class KVNANDEngine:
                 if getattr(cache, n) is not None}
 
     def decode_step(self, params, cache: DecodeCache, tokens: jax.Array,
-                    active: Optional[jax.Array] = None):
+                    active: Optional[jax.Array] = None, *,
+                    route_counts: bool = False):
         """tokens: [B, 1] -> (logits [B, V], updated cache).
 
         active: optional [B] bool mask (interleaved continuous batching):
@@ -529,6 +626,10 @@ class KVNANDEngine:
         no KV append, no length advance, and frozen recurrent state, so a
         decode step never perturbs a stripe another path is filling.  Their
         logits are computed (the batch is dense) and ignored by the host.
+
+        route_counts: also return the token-expert pairs of the active
+        rows that landed on held experts, summed over the MoE layers
+        (an int32 scalar; 0 for a model without them).
         """
         cfg, rt = self.cfg, self.rt
         if active is not None and self.eng.uniform_lengths:
@@ -561,36 +662,20 @@ class KVNANDEngine:
         else:
             self._page_pos_w_new = None
 
-        x = embed_lookup(params["embedding"], tokens, rt.activ_dtype)
-
-        n_groups = cfg.n_layers // self.period
-        grouped_params = jax.tree.map(
-            lambda a: a.reshape((n_groups, self.period) + a.shape[1:]),
-            params["layers"])
+        x = embed_tokens(params, cfg, tokens, rt)
         pools = self._collect(cache, POOL_G + POOL_W)
         states = self._collect(cache, STATE_LEAVES)
         cross = self._collect(cache, ("cross_k", "cross_v")) or None
 
-        idx = {
-            "p": grouped_params,
-            "l0": jnp.arange(n_groups, dtype=jnp.int32) * self.period,
-            "g0": jnp.arange(n_groups, dtype=jnp.int32) * self.g_per_group,
-            "w0": jnp.arange(n_groups, dtype=jnp.int32) * self.w_per_group,
-        }
-
-        def group_body(carry, xs):
+        def layer(carry, pl_, l_idx, g_idx, w_idx, is_glob, _):
             xc, pools, states = carry
-            for j, is_glob in enumerate(self.pattern):
-                pl_ = jax.tree.map(lambda a, j=j: a[j], xs["p"])
-                out, pools = self._decode_block(
-                    pl_, xc, pools, states, cross,
-                    xs["l0"] + j, xs["g0"] + self._g_off[j],
-                    xs["w0"] + self._w_off[j], lengths, plan, is_glob)
-                xc, states = out
-            return (xc, pools, states), None
+            (xc, states), pools, held = self._decode_block(
+                pl_, xc, pools, states, cross, l_idx, g_idx, w_idx,
+                lengths, plan, is_glob)
+            return (xc, pools, states), held
 
-        (x, pools, states), _ = jax.lax.scan(
-            group_body, (x, pools, states), idx)
+        (x, pools, states), held = self._walk(params, layer,
+                                              (x, pools, states))
 
         updates: Dict[str, Any] = dict(pools)
         updates.update(states)
@@ -601,6 +686,10 @@ class KVNANDEngine:
         new_cache = dataclasses.replace(cache, **updates)
         with jax.named_scope("logits"):
             logits = lm_head_logits(params, cfg, x)[:, 0]
+        if route_counts:
+            n_held = sum((jnp.sum(h) for h in jax.tree.leaves(held)),
+                         jnp.zeros((), jnp.int32))
+            return logits, new_cache, n_held
         return logits, new_cache
 
     # ------------------------------------------------------------------
@@ -668,30 +757,20 @@ class KVNANDEngine:
                   if cache.page_table_g is not None else None)
 
         positions = lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
-        x = embed_lookup(params["embedding"], tokens, rt.activ_dtype)
-
-        n_groups = cfg.n_layers // self.period
-        grouped_params = jax.tree.map(
-            lambda a: a.reshape((n_groups, self.period) + a.shape[1:]),
-            params["layers"])
+        x = embed_tokens(params, cfg, tokens, rt)
         pools = self._collect(cache, POOL_G + POOL_W)
         fmt = self.eng.kv_quant
 
-        idx = {
-            "p": grouped_params,
-            "g0": jnp.arange(n_groups, dtype=jnp.int32) * self.g_per_group,
-            "w0": jnp.arange(n_groups, dtype=jnp.int32) * self.w_per_group,
-        }
-
-        def attn_layer(pl_, xc, g_idx, w_idx, is_glob):
-            """One attention layer of the span forward; returns the layer
-            output and the span's fresh (k, v) for the append phase."""
+        def attn_layer(xc, pl_, l_idx, g_idx, w_idx, is_glob, _):
+            """One layer of the span forward; returns the layer output
+            and the span's fresh (k, v) for the append phase."""
             use_window = (cfg.window is not None) and not is_glob
             window = cfg.window if use_window else None
             h = rms_norm(xc, pl_["ln1"], cfg.norm_eps)
             with jax.named_scope("qkv"):
-                q, k, v = attn_mod.project_qkv(pl_["attn"], cfg, h,
-                                               positions)
+                q, k, v = attn_mod.project_qkv(
+                    pl_["attn"], cfg, h, positions,
+                    rope=attn_mod.use_rope(cfg, is_glob))
             # in-span causal partial: the mask is position-RELATIVE
             # (span token i sees span tokens <= i, window likewise), so
             # relative coordinates serve every slot at once.  The span's
@@ -736,30 +815,14 @@ class KVNANDEngine:
                 o, m, l = seqpar.merge_two(o, m, l, o2, m2, l2)
             with jax.named_scope("attn_out"):
                 aout = attn_mod.project_out(pl_["attn"], cfg,
-                                            o.astype(h.dtype))
-            xc = xc + aout
-            h = rms_norm(xc, pl_["ln2"], cfg.norm_eps)
-            with jax.named_scope("mlp"):
-                if cfg.is_moe:
-                    ff = moe(pl_["moe"], h, top_k=cfg.top_k,
-                             capacity_factor=rt.moe_capacity)
-                else:
-                    ff = mlp(pl_["mlp"], h, cfg.gated_mlp)
-            return xc + ff, k, v
-
-        def fwd_body(xc, xs):
-            kv_k, kv_v = [], []
-            for j, is_glob in enumerate(self.pattern):
-                pl_ = jax.tree.map(lambda a, j=j: a[j], xs["p"])
-                xc, k, v = attn_layer(pl_, xc, xs["g0"] + self._g_off[j],
-                                      xs["w0"] + self._w_off[j], is_glob)
-                kv_k.append(k)
-                kv_v.append(v)
+                                            o.astype(h.dtype), h)
+            xc = xc + post_norm(pl_, "ln1_post", cfg, aout)
+            xc, _ = self._ffn_half(pl_, xc)
             # span K/V ride the ys stack — tiny ([period, B, S, K, dh])
             # next to the pool carries the memory discipline protects
-            return xc, {"k": jnp.stack(kv_k), "v": jnp.stack(kv_v)}
+            return xc, {"k": k, "v": v}
 
-        x, span_kv = jax.lax.scan(fwd_body, x, idx)
+        x, span_kv = self._walk(params, attn_layer, x)
         with jax.named_scope("logits"):
             logits = lm_head_logits(params, cfg, x)        # [B, S, V]
 
@@ -791,45 +854,41 @@ class KVNANDEngine:
                 drop_w, pw = NPw, ring
             phys_w = jnp.where(write, pw, drop_w)
 
-        def append_body(pools, xs):
-            for j, is_glob in enumerate(self.pattern):
-                use_window = (cfg.window is not None) and not is_glob
-                k_span = xs["kv"]["k"][j]                  # [B, S, K, dh]
-                v_span = xs["kv"]["v"][j]
-                if use_window:
-                    idx_l, phys = xs["w0"] + self._w_off[j], phys_w
-                    names = ("k_pages_w", "v_pages_w", "k_scale_w",
-                             "v_scale_w")
-                else:
-                    idx_l, phys = xs["g0"] + self._g_off[j], phys_g
-                    names = ("k_pages_g", "v_pages_g", "k_scale_g",
-                             "v_scale_g")
-                kname, vname, ksname, vsname = names
-                if fmt != "none":
-                    append = (paged_kv.append_span_quant_shared if shared
-                              else paged_kv.append_span_quant)
-                    pools[kname], pools[ksname] = append(
-                        pools[kname], pools[ksname], idx_l, phys, slot_s,
-                        k_span, fmt)
-                    pools[vname], pools[vsname] = append(
-                        pools[vname], pools[vsname], idx_l, phys, slot_s,
-                        v_span, fmt)
-                elif shared:
-                    pools[kname] = paged_kv.append_span_shared(
-                        pools[kname], idx_l, phys, slot_s, k_span)
-                    pools[vname] = paged_kv.append_span_shared(
-                        pools[vname], idx_l, phys, slot_s, v_span)
-                else:
-                    pools[kname] = paged_kv.append_span(
-                        pools[kname], idx_l, phys, slot_s, k_span)
-                    pools[vname] = paged_kv.append_span(
-                        pools[vname], idx_l, phys, slot_s, v_span)
+        def append_layer(pools, _, l_idx, g_idx, w_idx, is_glob, kv):
+            use_window = (cfg.window is not None) and not is_glob
+            k_span, v_span = kv["k"], kv["v"]              # [B, S, K, dh]
+            if use_window:
+                idx_l, phys = w_idx, phys_w
+                names = ("k_pages_w", "v_pages_w", "k_scale_w",
+                         "v_scale_w")
+            else:
+                idx_l, phys = g_idx, phys_g
+                names = ("k_pages_g", "v_pages_g", "k_scale_g",
+                         "v_scale_g")
+            kname, vname, ksname, vsname = names
+            if fmt != "none":
+                append = (paged_kv.append_span_quant_shared if shared
+                          else paged_kv.append_span_quant)
+                pools[kname], pools[ksname] = append(
+                    pools[kname], pools[ksname], idx_l, phys, slot_s,
+                    k_span, fmt)
+                pools[vname], pools[vsname] = append(
+                    pools[vname], pools[vsname], idx_l, phys, slot_s,
+                    v_span, fmt)
+            elif shared:
+                pools[kname] = paged_kv.append_span_shared(
+                    pools[kname], idx_l, phys, slot_s, k_span)
+                pools[vname] = paged_kv.append_span_shared(
+                    pools[vname], idx_l, phys, slot_s, v_span)
+            else:
+                pools[kname] = paged_kv.append_span(
+                    pools[kname], idx_l, phys, slot_s, k_span)
+                pools[vname] = paged_kv.append_span(
+                    pools[vname], idx_l, phys, slot_s, v_span)
             return pools, None
 
         with jax.named_scope("kv_append"):
-            pools, _ = jax.lax.scan(append_body, pools,
-                                    {"kv": span_kv, "g0": idx["g0"],
-                                     "w0": idx["w0"]})
+            pools, _ = self._walk(None, append_layer, pools, extra=span_kv)
 
         updates: Dict[str, Any] = dict(pools)
         if cache.page_pos_w is not None:
@@ -915,33 +974,18 @@ class KVNANDEngine:
                                 "w": cache.page_table_w}
         self._prefill_plan = plan_sharding(
             self.mesh, B, paged_kv.pool_page_count(cache.k_pages_g, shared))
-        n_groups = cfg.n_layers // self.period
-        grouped_params = jax.tree.map(
-            lambda a: a.reshape((n_groups, self.period) + a.shape[1:]),
-            params["layers"])
         pools = self._collect(cache, POOL_G + POOL_W)
         states = self._collect(cache, STATE_LEAVES)
         cross = self._collect(cache, ("cross_k", "cross_v"))
 
-        idx = {
-            "p": grouped_params,
-            "l0": jnp.arange(n_groups, dtype=jnp.int32) * self.period,
-            "g0": jnp.arange(n_groups, dtype=jnp.int32) * self.g_per_group,
-            "w0": jnp.arange(n_groups, dtype=jnp.int32) * self.w_per_group,
-        }
-
-        def group_body(carry, xs):
+        def layer(carry, pl_, l_idx, g_idx, w_idx, is_glob, _):
             xc, pools, states, cross_c = carry
-            for j, is_glob in enumerate(self.pattern):
-                pl_ = jax.tree.map(lambda a, j=j: a[j], xs["p"])
-                xc, pools, states, cross_c = self._prefill_block(
-                    pl_, xc, positions, enc_out, is_glob, pools, states,
-                    cross_c, xs["l0"] + j, xs["g0"] + self._g_off[j],
-                    xs["w0"] + self._w_off[j])
-            return (xc, pools, states, cross_c), None
+            return self._prefill_block(
+                pl_, xc, positions, enc_out, is_glob, pools, states,
+                cross_c, l_idx, g_idx, w_idx), None
 
-        (x, pools, states, cross), _ = jax.lax.scan(
-            group_body, (x, pools, states, cross), idx)
+        (x, pools, states, cross), _ = self._walk(
+            params, layer, (x, pools, states, cross))
 
         updates: Dict[str, Any] = dict(pools)
         updates.update(states)
@@ -980,11 +1024,12 @@ class KVNANDEngine:
             return x, pools, states, cross
 
         h = rms_norm(x, pl_["ln1"], cfg.norm_eps)
-        q, k, v = attn_mod.project_qkv(pl_["attn"], cfg, h, positions)
+        q, k, v = attn_mod.project_qkv(pl_["attn"], cfg, h, positions,
+                                       rope=attn_mod.use_rope(cfg, is_glob))
         window = cfg.window if (cfg.window and not is_glob) else None
         o = attn_mod.sharded_flash_attention(
             q, k, v, causal=True, window=window, impl=rt.attn_impl)
-        aout = attn_mod.project_out(pl_["attn"], cfg, o)
+        aout = attn_mod.project_out(pl_["attn"], cfg, o, h)
 
         use_window = (cfg.window is not None) and not is_glob
         plan = self._prefill_plan
@@ -1031,7 +1076,7 @@ class KVNANDEngine:
             states["ssm_state"] = states["ssm_state"].at[l_idx].set(s_new)
             states["conv_tail"] = states["conv_tail"].at[l_idx].set(
                 tail_new.astype(states["conv_tail"].dtype))
-        x = x + aout
+        x = x + post_norm(pl_, "ln1_post", cfg, aout)
 
         if cfg.is_encoder_decoder and enc_out is not None:
             h = rms_norm(x, pl_["ln_cross"], cfg.norm_eps)
@@ -1043,13 +1088,8 @@ class KVNANDEngine:
             cross["cross_k"] = cross["cross_k"].at[l_idx].set(ck)
             cross["cross_v"] = cross["cross_v"].at[l_idx].set(cv)
 
-        h = rms_norm(x, pl_["ln2"], cfg.norm_eps)
-        if cfg.is_moe:
-            ff = moe(pl_["moe"], h, top_k=cfg.top_k,
-                     capacity_factor=rt.moe_capacity)
-        else:
-            ff = mlp(pl_["mlp"], h, cfg.gated_mlp)
-        return x + ff, pools, states, cross
+        x, _ = self._ffn_half(pl_, x)
+        return x, pools, states, cross
 
     def _rwkv_prefill_block(self, pl_, x, states, l_idx):
         cfg = self.cfg
@@ -1139,8 +1179,7 @@ class KVNANDEngine:
         if first:
             x, _ = embed_inputs(params, cfg, batch, rt)
         else:
-            x = embed_lookup(params["embedding"], batch["tokens"],
-                             rt.activ_dtype)
+            x = embed_tokens(params, cfg, batch["tokens"], rt)
         B1, S = x.shape[:2]
         prefix = S - batch["tokens"].shape[1]
         q_pos = start + jnp.arange(S, dtype=jnp.int32)
@@ -1183,32 +1222,15 @@ class KVNANDEngine:
                 self._ck["trow_w"] = jax.lax.dynamic_slice(
                     cache.page_table_w, (slot, zero), (1, NPw))[0]
 
-        n_groups = cfg.n_layers // self.period
-        grouped_params = jax.tree.map(
-            lambda a: a.reshape((n_groups, self.period) + a.shape[1:]),
-            params["layers"])
         pools = self._collect(cache, POOL_G + POOL_W)
         states = self._collect(cache, STATE_LEAVES)
 
-        idx = {
-            "p": grouped_params,
-            "l0": jnp.arange(n_groups, dtype=jnp.int32) * self.period,
-            "g0": jnp.arange(n_groups, dtype=jnp.int32) * self.g_per_group,
-            "w0": jnp.arange(n_groups, dtype=jnp.int32) * self.w_per_group,
-        }
-
-        def group_body(carry, xs):
+        def layer(carry, pl_, l_idx, g_idx, w_idx, is_glob, _):
             xc, pools, states = carry
-            for j, is_glob in enumerate(self.pattern):
-                pl_ = jax.tree.map(lambda a, j=j: a[j], xs["p"])
-                xc, pools, states = self._chunk_block(
-                    pl_, xc, positions, is_glob, pools, states,
-                    xs["l0"] + j, xs["g0"] + self._g_off[j],
-                    xs["w0"] + self._w_off[j])
-            return (xc, pools, states), None
+            return self._chunk_block(pl_, xc, positions, is_glob, pools,
+                                     states, l_idx, g_idx, w_idx), None
 
-        (x, pools, states), _ = jax.lax.scan(
-            group_body, (x, pools, states), idx)
+        (x, pools, states), _ = self._walk(params, layer, (x, pools, states))
 
         updates: Dict[str, Any] = dict(pools)
         updates.update(states)
@@ -1287,7 +1309,9 @@ class KVNANDEngine:
 
         h = rms_norm(x, pl_["ln1"], cfg.norm_eps)
         with jax.named_scope("qkv"):
-            q, k, v = attn_mod.project_qkv(pl_["attn"], cfg, h, positions)
+            q, k, v = attn_mod.project_qkv(
+                pl_["attn"], cfg, h, positions,
+                rope=attn_mod.use_rope(cfg, is_glob))
         use_window = (cfg.window is not None) and not is_glob
         window = cfg.window if use_window else None
         scale = cfg.d_head ** -0.5
@@ -1313,7 +1337,7 @@ class KVNANDEngine:
                 o, m, l = seqpar.merge_two(o, m, l, o2, m2, l2)
         with jax.named_scope("attn_out"):
             aout = attn_mod.project_out(pl_["attn"], cfg,
-                                        o.astype(h.dtype))
+                                        o.astype(h.dtype), h)
 
         # fill the chunk's K/V into the slot's pages (whole pages, in place)
         fmt = self.eng.kv_quant
@@ -1375,16 +1399,9 @@ class KVNANDEngine:
                 states["conv_tail"],
                 tail_new[None].astype(states["conv_tail"].dtype),
                 (l_idx, ck["slot"], 0, 0))
-        x = x + aout
-
-        h = rms_norm(x, pl_["ln2"], cfg.norm_eps)
-        with jax.named_scope("mlp"):
-            if cfg.is_moe:
-                ff = moe(pl_["moe"], h, top_k=cfg.top_k,
-                         capacity_factor=rt.moe_capacity)
-            else:
-                ff = mlp(pl_["mlp"], h, cfg.gated_mlp)
-        return x + ff, pools, states
+        x = x + post_norm(pl_, "ln1_post", cfg, aout)
+        x, _ = self._ffn_half(pl_, x)
+        return x, pools, states
 
     def _rwkv_chunk_block(self, pl_, x, pools, states, l_idx):
         cfg = self.cfg

@@ -77,15 +77,18 @@ def pool_page_count(pool_leaf, shared: bool) -> int:
 # Layer grouping: smallest repeating local/global period (scan-friendly)
 # ---------------------------------------------------------------------------
 
-def layer_pattern(cfg: ModelConfig) -> Tuple[int, Tuple[bool, ...]]:
-    """Returns (period, pattern) with pattern[i] == layer i is global."""
-    flags = tuple(cfg.is_global_layer(i) for i in range(cfg.n_layers))
-    for p in range(1, cfg.n_layers + 1):
-        if cfg.n_layers % p:
+def layer_pattern(cfg: ModelConfig, first: int = 0, n: Optional[int] = None
+                  ) -> Tuple[int, Tuple[bool, ...]]:
+    """Returns (period, pattern) of layers [first, first + n) (default:
+    every layer) with pattern[i] == layer first + i is global."""
+    n = cfg.n_layers - first if n is None else n
+    flags = tuple(cfg.is_global_layer(i) for i in range(first, first + n))
+    for p in range(1, n + 1):
+        if n % p:
             continue
-        if all(flags[i] == flags[i % p] for i in range(cfg.n_layers)):
+        if all(flags[i] == flags[i % p] for i in range(n)):
             return p, flags[:p]
-    return cfg.n_layers, flags
+    return n, flags
 
 
 # ---------------------------------------------------------------------------
@@ -122,6 +125,12 @@ class DecodeCache:
     cross_v: Optional[jax.Array] = None
     # bookkeeping
     lengths: Optional[jax.Array] = None     # [B] tokens written so far
+
+
+# window rings hold a whole number of these page blocks: the Pallas decode
+# kernel walks pages in blocks of up to 8 (`paged_attention_partial`'s
+# `pages_per_block`), which must divide the ring
+RING_PAGE_BLOCK = 8
 
 
 def _n_layers_split(cfg: ModelConfig) -> Tuple[int, int]:
@@ -178,7 +187,12 @@ def cache_spec(cfg: ModelConfig, eng: EngineConfig, batch: int,
                     spec["v_scale_g"] = ((Lg, batch, K, NPg), jnp.float32)
             spec["page_table_g"] = ((batch, NPg), jnp.int32)
         if Lw:
-            NPw = round_np(ceil_div(cfg.window, T) + 1, page_shards_w)
+            # the ring: the window's pages plus the one being filled,
+            # rounded up to whole blocks of the decode kernel's page walk
+            # (stale ring pages fall outside the window and are masked)
+            NPw = round_np(ceil_div(ceil_div(cfg.window, T) + 1,
+                                    RING_PAGE_BLOCK) * RING_PAGE_BLOCK,
+                           page_shards_w)
             if eng.shared_pool:
                 Pw = round_np(eng.total_pages_w or batch * NPw,
                               page_shards_w)

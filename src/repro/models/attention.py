@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.kernels.flash_attention import flash_attention
-from repro.models.layers import ParamBuilder, apply_rope, dense
+from repro.models.layers import ParamBuilder, apply_rope, dense, rms_norm
 
 
 def init_attention(b: ParamBuilder, cfg: ModelConfig, *, cross: bool = False):
@@ -33,6 +33,11 @@ def init_attention(b: ParamBuilder, cfg: ModelConfig, *, cross: bool = False):
         b.param("wk_b", (K, dh), (None, "head_dim"), init="zeros")
         b.param("wv_b", (K, dh), (None, "head_dim"), init="zeros")
     b.param("wo_w", (cfg.q_dim, d), ("heads", "embed"))
+    if cfg.qk_norm and not cross:
+        b.param("q_norm", (dh,), ("norm",), init="zeros")
+        b.param("k_norm", (dh,), ("norm",), init="zeros")
+    if cfg.attn_gate and not cross:
+        b.param("wgate_w", (K, d, G * dh), (None, "embed", "heads"))
 
 
 def _proj(p, name: str, x: jax.Array, dequant_fn=None) -> jax.Array:
@@ -52,12 +57,17 @@ def project_qkv(
     params: Dict[str, Any], cfg: ModelConfig, x: jax.Array,
     positions: Optional[jax.Array], *, rope: bool = True,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """x: [B, S, D] -> q [B, S, H, dh], k/v [B, S, K, dh] (RoPE applied)."""
+    """x: [B, S, D] -> q [B, S, H, dh], k/v [B, S, K, dh] (per-head
+    RMSNorm on q and k where the layer has it, then RoPE unless `rope` is
+    False)."""
     B, S, _ = x.shape
     K, G, dh = cfg.n_kv_heads, cfg.group_size, cfg.d_head
     q = _proj(params, "wq", x).reshape(B, S, K * G, dh)
     k = _proj(params, "wk", x)                                 # [B, S, K, dh]
     v = _proj(params, "wv", x)
+    if "q_norm" in params:
+        q = rms_norm(q, params["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, params["k_norm"], cfg.norm_eps)
     if rope:
         if positions is None:
             positions = jnp.arange(S)[None, :]
@@ -67,7 +77,8 @@ def project_qkv(
 
 
 def project_q_group(params, cfg: ModelConfig, x_tok: jax.Array,
-                    group: jax.Array, positions: jax.Array) -> jax.Array:
+                    group: jax.Array, positions: jax.Array, *,
+                    rope: bool = True) -> jax.Array:
     """One head-group's q projection (the KVNAND-D pipelined GEMV).
 
     x_tok: [B, D] (single decode token); group: scalar index; returns
@@ -85,14 +96,32 @@ def project_q_group(params, cfg: ModelConfig, x_tok: jax.Array,
                                              keepdims=False).astype(q.dtype)
     B = x_tok.shape[0]
     q = q.reshape(B, 1, cfg.group_size, cfg.d_head)
+    if "q_norm" in params:
+        q = rms_norm(q, params["q_norm"], cfg.norm_eps)
+    if not rope:
+        return q[:, 0]
     return apply_rope(q, positions[:, None], cfg.rope_theta)[:, 0]
 
 
+def use_rope(cfg: ModelConfig, is_global) -> bool:
+    """Whether a layer ropes q and k: every layer, or only the window
+    layers where `cfg.rope_global` is False (global layers are NoPE)."""
+    return cfg.rope_global or not is_global
+
+
 def project_out(params: Dict[str, Any], cfg: ModelConfig,
-                attn: jax.Array) -> jax.Array:
-    """attn: [B, S, H, dh] -> [B, S, D]."""
+                attn: jax.Array, x: Optional[jax.Array] = None) -> jax.Array:
+    """attn: [B, S, H, dh] -> [B, S, D].  Layers with an output gate
+    multiply attn by sigmoid(x Wgate) first (x: the attention's input
+    [B, S, D])."""
     B, S = attn.shape[:2]
-    return dense(params, "wo", attn.reshape(B, S, cfg.q_dim))
+    attn = attn.reshape(B, S, cfg.q_dim)
+    if "wgate_w" in params:
+        with jax.named_scope("attn_gate"):
+            gate = jax.nn.sigmoid(_proj(params, "wgate", x).reshape(
+                B, S, cfg.q_dim).astype(jnp.float32))
+            attn = (attn.astype(jnp.float32) * gate).astype(attn.dtype)
+    return dense(params, "wo", attn)
 
 
 def attention_train(
@@ -103,6 +132,12 @@ def attention_train(
 ) -> jax.Array:
     """Full-sequence attention (train/prefill). kv_x enables cross-attention."""
     if kv_x is None:
+        if not cfg.rope_global and is_global is not None:
+            # NoPE global layers under a traced flag: RoPE at position 0
+            # is the identity
+            if positions is None:
+                positions = jnp.arange(x.shape[1])[None, :]
+            positions = jnp.where(is_global, 0, positions)
         q, k, v = project_qkv(params, cfg, x, positions)
     else:  # cross-attention: queries from x, keys/values from encoder output
         B, S, _ = x.shape
@@ -112,7 +147,7 @@ def attention_train(
         causal = False
     out = sharded_flash_attention(q, k, v, causal=causal, window=window,
                                   is_global=is_global, impl=impl)
-    return project_out(params, cfg, out)
+    return project_out(params, cfg, out, x)
 
 
 def sharded_flash_attention(q, k, v, *, causal=True, window=None,

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,7 +40,7 @@ from repro.models.layers import (
 class Runtime:
     activ_dtype: Any = jnp.float32
     attn_impl: str = "auto"          # flash attention dispatch
-    moe_capacity: float = 1.25
+    moe_capacity: Optional[float] = 1.25   # None: dropless MoE
     vlm_patches: int = 256           # stub patch-prefix length (pixtral)
     enc_frames_ratio: int = 4        # whisper: frames = seq_len // ratio
     loss_chunk: int = 0              # >0: sequence-chunked CE (remat'd per
@@ -52,8 +52,9 @@ class Runtime:
 # Initialization
 # ---------------------------------------------------------------------------
 
-def _init_block(b: ParamBuilder, cfg: ModelConfig):
-    """One decoder block (params WITHOUT the layer axis; stacked by caller)."""
+def _init_block(b: ParamBuilder, cfg: ModelConfig, dense: bool = False):
+    """One decoder block (params WITHOUT the layer axis; stacked by caller);
+    `dense`: one of the leading dense-FFN layers of an MoE model."""
     init_rms_norm(b, "ln1", cfg.d_model)
     if cfg.family == "ssm":
         rwkv6.init_rwkv_timemix(b.scope("tmix"), cfg)
@@ -72,14 +73,21 @@ def _init_block(b: ParamBuilder, cfg: ModelConfig):
         init_rms_norm(b, "ln_cross", cfg.d_model)
         attn_mod.init_attention(b.scope("cross"), cfg, cross=True)
     init_rms_norm(b, "ln2", cfg.d_model)
-    if cfg.is_moe:
-        init_moe(b.scope("moe"), cfg.d_model, cfg.d_ff, cfg.n_experts)
+    if cfg.sandwich_norm:
+        init_rms_norm(b, "ln1_post", cfg.d_model)
+        init_rms_norm(b, "ln2_post", cfg.d_model)
+    if dense:
+        init_mlp(b.scope("mlp"), cfg.d_model, cfg.dense_d_ff or cfg.d_ff,
+                 cfg.gated_mlp)
+    elif cfg.is_moe:
+        init_moe(b.scope("moe"), cfg)
     else:
         init_mlp(b.scope("mlp"), cfg.d_model, cfg.d_ff, cfg.gated_mlp)
 
 
 def _init_stacked_layers(b: ParamBuilder, cfg: ModelConfig, n_layers: int,
-                         name: str, encoder: bool = False):
+                         name: str, encoder: bool = False,
+                         dense: bool = False):
     """Init `n_layers` blocks with a leading `layer` axis on every leaf.
 
     vmap over per-layer PRNG keys stacks every leaf while preserving each
@@ -90,14 +98,14 @@ def _init_stacked_layers(b: ParamBuilder, cfg: ModelConfig, n_layers: int,
 
     def one(key):
         pb = ParamBuilder(key, b.dtype)
-        _init_block(pb, cfg_blk)
+        _init_block(pb, cfg_blk, dense)
         return pb.params
 
     keys = jax.random.split(b._next_key(), n_layers)
     b.params[name] = jax.vmap(one)(keys)
 
     proto = ParamBuilder(jax.random.PRNGKey(0), b.dtype)
-    _init_block(proto, cfg_blk)
+    _init_block(proto, cfg_blk, dense)
     b.specs[name] = jax.tree.map(
         lambda sp: ("layer",) + tuple(sp), proto.specs,
         is_leaf=lambda x: isinstance(x, tuple))
@@ -107,7 +115,10 @@ def init_model(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32):
     """Returns (params, specs) — structurally identical trees."""
     b = ParamBuilder(rng, dtype)
     init_embedding(b, cfg.padded_vocab, cfg.d_model)
-    _init_stacked_layers(b, cfg, cfg.n_layers, "layers")
+    if cfg.n_dense_layers:
+        _init_stacked_layers(b, cfg, cfg.n_dense_layers, "dense_layers",
+                             dense=True)
+    _init_stacked_layers(b, cfg, cfg.n_layers - cfg.n_dense_layers, "layers")
     init_rms_norm(b, "final_norm", cfg.d_model)
     if not cfg.tie_embeddings:
         b.param("lm_head", (cfg.padded_vocab, cfg.d_model), ("vocab", "embed"))
@@ -138,11 +149,35 @@ def abstract_params(cfg: ModelConfig, dtype=jnp.float32):
     return aparams, holder["specs"]
 
 
+def layer_stacks(cfg: ModelConfig) -> Tuple[Tuple[str, int, int], ...]:
+    """(param stack, first layer, layers) of each stack in run order: the
+    leading dense-FFN layers, then the rest."""
+    nd = cfg.n_dense_layers
+    lead = (("dense_layers", 0, nd),) if nd else ()
+    return lead + (("layers", nd, cfg.n_layers - nd),)
+
+
 # layer-flag arrays (scanned along the layer axis)
-def layer_flags(cfg: ModelConfig) -> Dict[str, jax.Array]:
+def layer_flags(cfg: ModelConfig, first: int = 0,
+                n: Optional[int] = None) -> Dict[str, jax.Array]:
+    n = cfg.n_layers - first if n is None else n
     is_global = np.array([cfg.is_global_layer(i)
-                          for i in range(cfg.n_layers)])
+                          for i in range(first, first + n)])
     return {"is_global": jnp.asarray(is_global)}
+
+
+def ffn(pl_, cfg: ModelConfig, h, *, capacity_factor=None, rows=None):
+    """The layer's FFN: its dense MLP, or the MoE (dropless unless a
+    capacity is given).  Returns (y, held pairs or None)."""
+    if "moe" in pl_:
+        return moe(pl_["moe"], h, cfg, capacity_factor=capacity_factor,
+                   rows=rows)
+    return mlp(pl_["mlp"], h, cfg.gated_mlp), None
+
+
+def post_norm(pl_, name: str, cfg: ModelConfig, y):
+    """Sandwich norm of a sub-block's output, where the layer has one."""
+    return rms_norm(y, pl_[name], cfg.norm_eps) if name in pl_ else y
 
 
 # ---------------------------------------------------------------------------
@@ -164,20 +199,16 @@ def _attn_ffn_block(pl_, cfg: ModelConfig, x, flags, rt: Runtime,
         tail0 = jnp.zeros((B, ssm.CONV_K - 1, cfg.d_model), x.dtype)
         sout, _, _ = ssm.ssm_mixer(pl_["ssm"], cfg, h, state0, tail0)
         aout = (aout + sout) * 0.5
-    x = x + aout
+    x = x + post_norm(pl_, "ln1_post", cfg, aout)
     if enc_out is not None:
         h = rms_norm(x, pl_["ln_cross"], cfg.norm_eps)
         x = x + attn_mod.attention_train(pl_["cross"], cfg, h, kv_x=enc_out,
                                          impl=rt.attn_impl)
     h = rms_norm(x, pl_["ln2"], cfg.norm_eps)
-    if cfg.is_moe:
-        ff = moe(pl_["moe"], h, top_k=cfg.top_k,
-                 capacity_factor=rt.moe_capacity)
-        aux = moe_aux_loss(pl_["moe"], h, cfg.top_k)
-    else:
-        ff = mlp(pl_["mlp"], h, cfg.gated_mlp)
-        aux = jnp.zeros((), jnp.float32)
-    return x + ff, aux
+    ff, _ = ffn(pl_, cfg, h, capacity_factor=rt.moe_capacity)
+    aux = (moe_aux_loss(pl_["moe"], h, cfg.top_k) if "moe" in pl_
+           else jnp.zeros((), jnp.float32))
+    return x + post_norm(pl_, "ln2_post", cfg, ff), aux
 
 
 def _rwkv_block(pl_, cfg: ModelConfig, x, rt: Runtime):
@@ -202,6 +233,12 @@ def _rwkv_block(pl_, cfg: ModelConfig, x, rt: Runtime):
 # Full forward (train)
 # ---------------------------------------------------------------------------
 
+def embed_tokens(params, cfg: ModelConfig, tok, rt: Runtime):
+    """Token embeddings [..., D], times `cfg.embed_scale` where set."""
+    x = embed_lookup(params["embedding"], tok, rt.activ_dtype)
+    return x * cfg.embed_scale if cfg.embed_scale != 1.0 else x
+
+
 def embed_inputs(params, cfg: ModelConfig, batch: Dict[str, jax.Array],
                  rt: Runtime):
     """Builds the input activation sequence [B, S, D] + positions [B, S].
@@ -210,7 +247,7 @@ def embed_inputs(params, cfg: ModelConfig, batch: Dict[str, jax.Array],
     (encoder frames handled separately); hybrid: meta tokens prepended.
     """
     tok = batch["tokens"]
-    x = embed_lookup(params["embedding"], tok, rt.activ_dtype)
+    x = embed_tokens(params, cfg, tok, rt)
     parts = [x]
     if cfg.family == "vlm" and "patches" in batch:
         parts.insert(0, batch["patches"].astype(rt.activ_dtype))
@@ -237,9 +274,12 @@ def run_layers(params, cfg: ModelConfig, x, rt: Runtime, positions,
     parameter array into the loop (measured 5.4 TB/device/step at kimi-k2
     scale) and all-reduces full-stack gradients per iteration.
     """
-    flags = layer_flags(cfg)
     if stack == "encoder":
-        flags = {"is_global": jnp.ones((cfg.encoder_layers,), bool)}
+        stacks = [("encoder", {"is_global": jnp.ones((cfg.encoder_layers,),
+                                                      bool)})]
+    else:   # the decoder: leading dense-FFN layers first, then the rest
+        stacks = [(name, layer_flags(cfg, first, n))
+                  for name, first, n in layer_stacks(cfg)]
 
     def body(carry, layer_in):
         xc, aux = carry
@@ -269,8 +309,9 @@ def run_layers(params, cfg: ModelConfig, x, rt: Runtime, positions,
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable
         body = jax.checkpoint(body, policy=policy, prevent_cse=False)
 
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                               (params[stack], flags))
+    aux = jnp.zeros((), jnp.float32)
+    for name, flags in stacks:
+        (x, aux), _ = jax.lax.scan(body, (x, aux), (params[name], flags))
     return x, aux
 
 

@@ -63,6 +63,16 @@ _STAT_COUNTERS = (
      "Page visits per layer made by decode/verify attention walks"),
     ("decode_pages_live", "kvnand_decode_pages_live_total",
      "Pages holding the active rows' context in those walks"),
+    ("decode_pages_walked_w", "kvnand_decode_pages_walked_w_total",
+     "Page visits per window layer made by decode/verify attention walks"),
+    ("decode_pages_live_w", "kvnand_decode_pages_live_w_total",
+     "Pages holding the last window of the active rows' context there"),
+    ("decode_steps", "kvnand_decode_steps_total",
+     "Decode/verify steps enqueued"),
+    ("moe_pairs_routed", "kvnand_moe_pairs_routed_total",
+     "Token-expert pairs of decode steps (rows x top-k x MoE layers)"),
+    ("moe_pairs_held", "kvnand_moe_pairs_held_total",
+     "Those token-expert pairs routed to the experts held here"),
 )
 
 

@@ -234,6 +234,7 @@ class _Inflight:
     toks: jax.Array                 # device future until collect()
     lps: jax.Array
     acc: Optional[jax.Array] = None          # verify: accepted counts
+    held: Optional[jax.Array] = None         # MoE decode: held pairs
     allowed: Optional[np.ndarray] = None     # verify: per-row draft cap
     cap_finish: Set[int] = dataclasses.field(default_factory=set)
 
@@ -335,13 +336,19 @@ class ContinuousBatcher:
             # folding the select into the step keeps the overlapped
             # dispatch free of eager per-step ops on the host path
             t = jnp.where(chain[:, None], prev_t[:, None], t)
-            logits, c = self.engine.decode_step(p, c, t, active=a)
+            held = None
+            if cfg.is_moe:
+                # routing counts ride the step's own outputs (one fetch)
+                logits, c, held = self.engine.decode_step(
+                    p, c, t, active=a, route_counts=True)
+            else:
+                logits, c = self.engine.decode_step(p, c, t, active=a)
             with jax.named_scope("sampling"):
                 toks, lps = sample_with_logprobs(
                     logits, request_keys(seeds, pos),
                     true_vocab=self.cfg.vocab_size, temperature=temps,
                     top_k=tk, top_p=tp)
-            return toks, lps, c
+            return toks, lps, held, c
 
         self._decode = jax.jit(_decode_fn, donate_argnums=(1,))
         self._no_chain = self._put((np.zeros(self.B, bool),
@@ -389,11 +396,16 @@ class ContinuousBatcher:
                       "tier_prefetch_pages": 0, "tier_peak_hot": 0,
                       "phantom_tokens": 0, "deadline_drops": 0,
                       "device_idle_s": 0.0,
-                      "decode_pages_walked": 0, "decode_pages_live": 0}
+                      "decode_pages_walked": 0, "decode_pages_live": 0,
+                      "decode_pages_walked_w": 0, "decode_pages_live_w": 0,
+                      "decode_steps": 0, "moe_pairs_held": 0,
+                      "moe_pairs_routed": 0}
         # page visits per layer of one decode/verify step's paged walk:
         # every row of the batch times the pages of the kernel's grid
-        # (0: no global pool, nothing counted)
+        # (0: no global pool, nothing counted); the window pool's twin
         self._walk_pages = self.engine.decode_page_visits(self.cache)
+        self._walk_pages_w = self.engine.decode_page_visits(self.cache,
+                                                            pool="w")
         self._compile_keys = set()
         if self.shared:
             self._init_shared_pool(eng)
@@ -1113,13 +1125,16 @@ class ContinuousBatcher:
         got = None
         if inf is not None:
             with TraceAnnotation("kvnand.fetch", rows=len(inf.active)):
-                got = jax.device_get((inf.toks, inf.lps, inf.acc))
+                got = jax.device_get((inf.toks, inf.lps, inf.acc,
+                                      inf.held))
         with TraceAnnotation("kvnand.emit"):
             emitted = 0
             if inf is not None:
-                emitted = (self._emit_verify(inf, *got)
+                emitted = (self._emit_verify(inf, *got[:3])
                            if inf.kind == "verify"
                            else self._emit_decode(inf, *got[:2]))
+                if got[3] is not None:
+                    self._count_routing(len(inf.active), int(got[3]))
             self._tier_prefetch_tick()
         return emitted
 
@@ -1153,12 +1168,27 @@ class ContinuousBatcher:
         """Decode-walk counters of one enqueued step: the page visits
         per layer its paged attention makes (`decode_pages_walked`) and
         the pages holding the active rows' `context` tokens
-        (`decode_pages_live`)."""
-        if not self._walk_pages:
-            return
+        (`decode_pages_live`); for the window pool, the page visits per
+        window layer (`decode_pages_walked_w`) and the pages holding the
+        last `window` of each row's context (`decode_pages_live_w`)."""
+        self.stats["decode_steps"] += 1
         T = self.engine.eng.page_tokens
-        self.stats["decode_pages_walked"] += self._walk_pages
-        self.stats["decode_pages_live"] += int(np.sum(-(-context // T)))
+        if self._walk_pages:
+            self.stats["decode_pages_walked"] += self._walk_pages
+            self.stats["decode_pages_live"] += int(np.sum(-(-context // T)))
+        if self._walk_pages_w:
+            lo = np.maximum(context - self.cfg.window, 0)
+            self.stats["decode_pages_walked_w"] += self._walk_pages_w
+            self.stats["decode_pages_live_w"] += int(np.sum(
+                (context - 1) // T - lo // T + 1))
+
+    def _count_routing(self, rows: int, held: int):
+        """MoE counters of one collected decode step: token-expert pairs
+        of its rows (`moe_pairs_routed`: rows x top-k x MoE layers) and
+        those that landed on the experts held here (`moe_pairs_held`)."""
+        self.stats["moe_pairs_routed"] += rows * self.cfg.top_k * \
+            self.cfg.n_moe_layers
+        self.stats["moe_pairs_held"] += held
 
     def _dispatch_sequential(self, active: List[int]):
         """Enqueue one masked decode over `active` slots, sampling each
@@ -1199,7 +1229,7 @@ class ContinuousBatcher:
         self._count_compile("decode", self.B)
         # sampling params ride as traced per-slot arrays: any mix of
         # per-request combinations hits this one compiled signature
-        toks, lps, self.cache = self._decode(
+        toks, lps, held, self.cache = self._decode(
             self.params, self.cache, self._put(tokens), *self._put(
                 (ch, prev_t, mask, self._temps, self._topk, self._topp,
                  self._seeds, positions)))
@@ -1209,7 +1239,7 @@ class ContinuousBatcher:
                if self._lengths[i] + 1 >= self.max_context}
         self._inflight.append(_Inflight(
             "decode", list(active),
-            {i: self.slots[i] for i in active}, toks, lps,
+            {i: self.slots[i] for i in active}, toks, lps, held=held,
             cap_finish=cap))
 
     def _emit_decode(self, inf: _Inflight, toks: np.ndarray,

@@ -5,10 +5,13 @@ blocks whose last two dims are neither (8, 128)-aligned nor the array's
 own, vector loads from SMEM, shape casts it has no layout for.  These
 tests compile each kernel at qwen1.5-0.5b decode widths (B=8 slots, 16
 MHA kv-heads, d_head 64, 16-token pages, 128 pages per slot, a 1024-page
-shared pool) for a v5e chip that is described, not attached: nothing
-runs, no chip is needed, each compile takes about a second.  The last
-test compiles the whole serving decode step at both benchmark cells'
-shapes (a few seconds each) and reads its HLO for pool-sized copies.
+shared pool) and at trinity-mini-l8's (32 slots, 4 kv-heads of 8 query
+heads, d_head 128; a global pool of 256 pages per slot, window rings of
+136) for a v5e chip that is described, not attached: nothing runs, no
+chip is needed, each compile takes about a second.  The last test
+compiles the whole serving decode step at the benchmark cells' shapes
+(a few seconds each; ~30 s for trinity-mini-l8) and reads its HLO for
+pool-sized copies.
 
 The topology is described inside a module-scoped fixture (never at
 import), so every pytest-xdist worker collects the same tests and only
@@ -22,6 +25,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import dataclasses
+
 from repro.configs import EngineConfig, get_config
 from repro.core.engine import KVNANDEngine
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
@@ -30,7 +35,11 @@ from repro.kernels.paged_attention.kernel import (
 from repro.models.registry import Model
 from repro.models.transformer import Runtime
 
-B, K, G, DH, T, NP, POOL = 8, 16, 1, 64, 16, 128, 1024
+T = 16
+# (slots, kv heads, group, d_head, pages per slot, shared-pool pages)
+SHAPES = {"qwen": (8, 16, 1, 64, 128, 1024),
+          "trinity-global": (32, 4, 8, 128, 256, 8192),
+          "trinity-window": (32, 4, 8, 128, 136, 4352)}
 
 
 @pytest.fixture(scope="module")
@@ -57,23 +66,35 @@ def _compile_hlo(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
-def _pool_page(kv_quant, lead, sd):
+def _pool_page(kv_quant, lead, sd, dh):
     """Abstract page pool [*lead, T(/2), dh] in the format's storage dtype."""
     dtype, rows = {"none": (jnp.bfloat16, T), "kv8": (jnp.int8, T),
                    "kv4": (jnp.uint8, T // 2)}[kv_quant]
-    return jax.ShapeDtypeStruct(lead + (rows, DH), dtype, sharding=sd)
+    return jax.ShapeDtypeStruct(lead + (rows, dh), dtype, sharding=sd)
 
 
-@pytest.mark.parametrize("layout,kv_quant,partitions,layers", [
-    ("striped", "none", 1, 0), ("striped", "kv8", 1, 0),
-    ("striped", "kv4", 1, 0), ("striped", "none", 16, 0),
-    ("shared", "none", 1, 0), ("shared", "kv8", 1, 0), ("shared", "kv4", 1, 0),
-    ("shared", "kv8", 16, 0),
+def _case(layout, kv_quant, partitions, layers, shape="qwen"):
+    name = f"{layout}-{kv_quant}-{partitions}-{layers}"
+    return pytest.param(layout, kv_quant, partitions, layers, shape,
+                        id=name if shape == "qwen" else f"{name}-{shape}")
+
+
+@pytest.mark.parametrize("layout,kv_quant,partitions,layers,shape", [
+    _case("striped", "none", 1, 0), _case("striped", "kv8", 1, 0),
+    _case("striped", "kv4", 1, 0), _case("striped", "none", 16, 0),
+    _case("shared", "none", 1, 0), _case("shared", "kv8", 1, 0),
+    _case("shared", "kv4", 1, 0), _case("shared", "kv8", 16, 0),
     # the decode step's form: the stacked pool and a traced layer index
-    ("striped", "none", 1, 24), ("shared", "none", 1, 24),
+    _case("striped", "none", 1, 24), _case("shared", "none", 1, 24),
+    # trinity-mini-l8's: G = 8 query heads per kv head, d_head 128, its
+    # 2 global layers' pool and its 6 window layers' rings
+    _case("striped", "none", 1, 2, "trinity-global"),
+    _case("striped", "none", 1, 6, "trinity-window"),
+    _case("shared", "none", 1, 6, "trinity-window"),
 ])
 def test_paged_decode_kernel_compiles_for_v5e(one_chip, layout, kv_quant,
-                                              partitions, layers):
+                                              partitions, layers, shape):
+    B, K, G, DH, NP, POOL = SHAPES[shape]
     sd = one_chip
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sd)
     i32 = lambda shape: S(shape, jnp.int32)
@@ -83,7 +104,7 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, layout, kv_quant,
                              [i32((B, NP))])
     else:
         call, lead, table = paged_attention_pallas, (B, K, NP), []
-    pages = _pool_page(kv_quant, stack + lead, sd)
+    pages = _pool_page(kv_quant, stack + lead, sd, DH)
     args = [S((B, K, G, DH), jnp.float32), pages, pages, *table,
             i32((B, NP)), i32((B,))]
     named = {}
@@ -93,9 +114,11 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, layout, kv_quant,
     if layers:
         named["layer"] = i32(())
 
+    window = 2048 if shape == "trinity-window" else None
+
     def fn(*a):
         return call(*a[:len(args)], **dict(zip(named, a[len(args):])),
-                    kv_quant=kv_quant, partitions=partitions)
+                    kv_quant=kv_quant, partitions=partitions, window=window)
     assert "tpu_custom_call" in _compile_hlo(fn, *args, *named.values())
 
 
@@ -104,18 +127,24 @@ def test_flash_attention_kernel_compiles_for_v5e(one_chip):
     x = jax.ShapeDtypeStruct((1, 16, 1024, 128), jnp.float32,
                              sharding=one_chip)
     hlo = _compile_hlo(lambda q, k, v: flash_attention_pallas(
-        q, k, v, scale=DH ** -0.5, sq_valid=1024, sk_valid=1024), x, x, x)
+        q, k, v, scale=64 ** -0.5, sq_valid=1024, sk_valid=1024), x, x, x)
     assert "tpu_custom_call" in hlo
 
 
 _OP = re.compile(r"^\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(")
 
 
-def _decode_step_ops(sd, slots, max_context):
-    """(pool shape, [(dims, opcode)] of every array-valued instruction)
+ARCHS = {"qwen1.5-0.5b": ("qwen1.5-0.5b", {}),
+         "trinity-mini-l8": ("trinity-mini", {"n_layers": 8,
+                                              "n_experts_held": 16})}
+
+
+def _decode_step_ops(sd, slots, max_context, arch="qwen1.5-0.5b"):
+    """(pool shapes, [(dims, opcode)] of every array-valued instruction)
     of the serving decode step (striped bf16 pool, ragged appends,
-    Pallas kernel) at full qwen1.5-0.5b width, compiled for `sd`."""
-    cfg, rt = get_config("qwen1.5-0.5b"), Runtime()
+    Pallas kernel) at the full width of `arch`, compiled for `sd`."""
+    name, cut = ARCHS[arch]
+    cfg, rt = dataclasses.replace(get_config(name), **cut), Runtime()
     eng = KVNANDEngine(cfg, EngineConfig(page_tokens=T, uniform_lengths=False,
                                          attn_impl="pallas"), rt)
     on = lambda tree: jax.tree.map(
@@ -128,31 +157,41 @@ def _decode_step_ops(sd, slots, max_context):
                      jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=sd),
                      jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=sd)
                      ).compile().as_text()
-    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    # one paged-attention call per layer of each stack's scanned group
+    assert hlo.count('custom_call_target="tpu_custom_call"') == \
+        sum(st.period for st in eng.stacks)
     ops = [(tuple(int(d) for d in m.group(2).split(",") if d), m.group(3))
            for m in map(_OP.match, hlo.splitlines()) if m]
-    return cache.k_pages_g.shape, ops
+    pools = [p.shape for p in (cache.k_pages_g, cache.k_pages_w)
+             if p is not None]
+    return pools, ops
 
 
-@pytest.mark.parametrize("slots,max_context,pool_copies", [
-    (32, 1024, 0),   # qwen05b-chat's server
+@pytest.mark.parametrize("slots,max_context,pool_copies,arch", [
+    # qwen05b-chat's server
+    pytest.param(32, 1024, 0, "qwen1.5-0.5b", id="32-1024-0"),
     # qwen05b-longctx's server: with d_head 64 the cache's default device
     # layout puts the 256-page axis in the lanes ({3,5,4,2,1,0}), which
     # the kernel cannot read, so K and V are converted to row-major on
     # entry and back on exit: four whole-pool copies, none per layer
-    (8, 4096, 4),
+    pytest.param(8, 4096, 4, "qwen1.5-0.5b", id="8-4096-4"),
+    # trinity-mini-agent's server: d_head 128 keeps both the global pool
+    # and the window rings row-major, read in place with no copy
+    pytest.param(32, 4096, 0, "trinity-mini-l8",
+                 id="32-4096-0-trinity-mini-l8"),
 ])
 def test_decode_step_touches_pool_in_place(one_chip, slots, max_context,
-                                           pool_copies):
+                                           pool_copies, arch):
     """The compiled decode step reads and appends the KV pools in place:
-    no op outputs a layer's slice of the pool (the kernel reads the
-    stacked pool at a prefetched layer), and the pool is copied whole no
+    no op outputs a layer's slice of a pool (the kernel reads the
+    stacked pool at a prefetched layer), and a pool is copied whole no
     more often than the cache's own layout forces."""
-    pool, ops = _decode_step_ops(one_chip, slots, max_context)
-    layer_slices = {pool[1:], (1,) + pool[1:]}
-    assert [op for dims, op in ops if dims in layer_slices] == []
-    copies = [op for dims, op in ops
-              if dims == pool and op not in ("parameter", "get-tuple-element",
-                                             "dynamic-update-slice",
-                                             "bitcast")]
-    assert copies == ["copy"] * pool_copies
+    pools, ops = _decode_step_ops(one_chip, slots, max_context, arch)
+    for pool in pools:
+        layer_slices = {pool[1:], (1,) + pool[1:]}
+        assert [op for dims, op in ops if dims in layer_slices] == []
+        copies = [op for dims, op in ops
+                  if dims == pool and op not in (
+                      "parameter", "get-tuple-element",
+                      "dynamic-update-slice", "bitcast")]
+        assert copies == ["copy"] * pool_copies
