@@ -40,7 +40,8 @@ from jax.sharding import Mesh
 from repro.configs.base import EngineConfig, ModelConfig
 from repro.core import paged_kv, seqpar
 from repro.core.paged_kv import DecodeCache
-from repro.kernels.paged_attention import paged_attention_partial
+from repro.kernels.paged_attention import (decode_walk_pages,
+                                           paged_attention_partial)
 from repro.models import attention as attn_mod
 from repro.models import rwkv6 as rwkv_mod
 from repro.models import ssm as ssm_mod
@@ -198,21 +199,30 @@ class KVNANDEngine:
                                        **self._cache_kw(batch, max_context,
                                                         enc_len))
 
-    def decode_page_visits(self, cache: DecodeCache, pool: str = "g") -> int:
-        """Pages one decode (or verify) step's paged attention walks per
-        layer of the global (`pool="g"`) or window (`"w"`) pool: rows ×
-        pages per row of the walk's grid — the page-table width of a
-        shared pool, the stripe's (ring's) page count otherwise (what
-        `paged_attention_partial` sizes its grid from); 0 for archs with
-        no such pool."""
+    def decode_page_visits(self, cache: DecodeCache, pool: str = "g",
+                           lengths=None) -> int:
+        """Pages one step's paged attention reads per layer of the global
+        (`pool="g"`) or window (`"w"`) pool; 0 for archs with no such
+        pool.  With `lengths` — every row's keys in a decode step's
+        kernel walk, 0 for a row the step leaves alone — the pages of the
+        blocks the Pallas kernel fetches (`decode_walk_pages`).  Without,
+        rows × pages per row — the page-table width of a shared pool, the
+        stripe's (ring's) page count otherwise — which a verify step's
+        jnp walk reads."""
         pages = cache.k_pages_g if pool == "g" else cache.k_pages_w
         if pages is None:
             return 0
-        rows = cache.lengths.shape[0]
-        if self.eng.shared_pool:
+        shared = self.eng.shared_pool
+        if shared:
             table = cache.page_table_g if pool == "g" else cache.page_table_w
-            return rows * table.shape[1]
-        return rows * pages.shape[3]
+            NP = table.shape[1]
+        else:
+            NP = pages.shape[3]
+        if lengths is None:
+            return cache.lengths.shape[0] * NP
+        return decode_walk_pages(lengths, self.eng.page_tokens, NP,
+                                 shared=shared,
+                                 partitions=self.eng.attn_partitions)
 
     def _walk(self, params, body: Callable, carry, extra=None):
         """Every layer in order: ``body(carry, pl_, l, g, w, is_glob, xj)
@@ -331,6 +341,15 @@ class KVNANDEngine:
             jnp.arange(B)[:, None], table].set(
             jnp.arange(NP, dtype=jnp.int32)[None] * T)
 
+    def _kv_lengths(self, lengths):
+        """Keys each row's decode attention reads: its context and the
+        token just appended — none for a row the step leaves alone
+        (`active`), whose output is ignored, so the kernel fetches one
+        block for it instead of its stale pages."""
+        kv = lengths + 1
+        return kv if self._active is None else jnp.where(self._active, kv,
+                                                         0)
+
     # ------------------------------------------------------------------
     # per-layer attention (compact vs discrete)
     # ------------------------------------------------------------------
@@ -344,9 +363,9 @@ class KVNANDEngine:
             q, _, _ = attn_mod.project_qkv(pl_["attn"], self.cfg, x_norm,
                                            lengths[:, None], rope=rope)
         with jax.named_scope("paged_attn"):
-            return self._paged_attn(q[:, 0], kp, vp, base, lengths + 1,
-                                    plan, pool, window, ks, vs, table,
-                                    layer)
+            return self._paged_attn(q[:, 0], kp, vp, base,
+                                    self._kv_lengths(lengths), plan, pool,
+                                    window, ks, vs, table, layer)
 
     def _attend_discrete(self, pl_, x_norm, kp, vp, ks, vs, base, lengths,
                          plan, pool, window, table=None, rope=True):
@@ -372,9 +391,9 @@ class KVNANDEngine:
                     ks_i = jax.lax.dynamic_slice_in_dim(ks, i, 1, k_axis)
                     vs_i = jax.lax.dynamic_slice_in_dim(vs, i, 1, k_axis)
             with jax.named_scope("paged_attn"):
-                o = self._paged_attn(q_cur, kp_i, vp_i, base, lengths + 1,
-                                     plan, pool, window, ks_i, vs_i,
-                                     table)  # [B, G, dh]
+                o = self._paged_attn(q_cur, kp_i, vp_i, base,
+                                     self._kv_lengths(lengths), plan, pool,
+                                     window, ks_i, vs_i, table)  # [B, G, dh]
             return q_next, o
 
         with jax.named_scope("qkv"):

@@ -3,6 +3,7 @@ from repro.kernels.paged_attention.merge import (  # noqa: F401
     resolve_partitions,
 )
 from repro.kernels.paged_attention.ops import (  # noqa: F401
+    decode_walk_pages,
     paged_attention_partial,
     paged_chunk_attention,
 )

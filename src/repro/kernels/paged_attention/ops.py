@@ -18,6 +18,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels.paged_attention.kernel import (
     paged_attention_pallas, paged_attention_pallas_shared)
@@ -73,6 +74,25 @@ def _resolve_ppb(pages_per_block: int, num_pages: int) -> int:
             f"<= {want}); pass pages_per_block=1 explicitly for "
             "single-page blocks, or page-align the context length")
     return ppb
+
+
+def decode_walk_pages(lengths, page_tokens: int, num_pages: int, *,
+                      shared: bool = False, partitions: int = 0,
+                      pages_per_block: int = 8) -> int:
+    """Pages the Pallas decode walk fetches for rows of `lengths` keys
+    (the kernel's `length`) over `num_pages` pages per row (the stripe,
+    the ring or the table width), whatever their kv-head grouping: each
+    row's page blocks up to the one holding its last written page — all
+    of a ring's once it wraps — and the first block of a row with none.
+    Past its last live block a row's index map repeats that block, so
+    the rest of its walk fetches nothing.  Blocks are the ones
+    `paged_attention_partial` resolves: one page of a shared pool, else
+    `pages_per_block` halved to divide a partition's pages."""
+    P = resolve_partitions(partitions, num_pages)
+    ppb = 1 if shared else _resolve_ppb(pages_per_block, num_pages // P)
+    pages = np.minimum(-(-np.asarray(lengths, np.int64) // page_tokens),
+                       num_pages)
+    return int(np.maximum(-(-pages // ppb), 1).sum()) * ppb
 
 
 def paged_chunk_attention(q, k_pages, v_pages, page_base, start, q_pos, *,

@@ -400,12 +400,6 @@ class ContinuousBatcher:
                       "decode_pages_walked_w": 0, "decode_pages_live_w": 0,
                       "decode_steps": 0, "moe_pairs_held": 0,
                       "moe_pairs_routed": 0}
-        # page visits per layer of one decode/verify step's paged walk:
-        # every row of the batch times the pages of the kernel's grid
-        # (0: no global pool, nothing counted); the window pool's twin
-        self._walk_pages = self.engine.decode_page_visits(self.cache)
-        self._walk_pages_w = self.engine.decode_page_visits(self.cache,
-                                                            pool="w")
         self._compile_keys = set()
         if self.shared:
             self._init_shared_pool(eng)
@@ -1164,21 +1158,25 @@ class ContinuousBatcher:
             else:
                 self._dispatch_sequential(active)
 
-    def _count_walk(self, context: np.ndarray):
-        """Decode-walk counters of one enqueued step: the page visits
-        per layer its paged attention makes (`decode_pages_walked`) and
-        the pages holding the active rows' `context` tokens
-        (`decode_pages_live`); for the window pool, the page visits per
+    def _count_walk(self, context: np.ndarray, kv_lens=None):
+        """Decode-walk counters of one enqueued step: the pages per layer
+        its paged attention fetches (`decode_pages_walked`; `kv_lens`:
+        every row's keys in a decode step's kernel walk, 0 for the rows
+        it leaves alone; None for a verify step, whose jnp walk reads
+        every page) and the pages holding the active rows' `context`
+        tokens (`decode_pages_live`); for the window pool, the same per
         window layer (`decode_pages_walked_w`) and the pages holding the
         last `window` of each row's context (`decode_pages_live_w`)."""
         self.stats["decode_steps"] += 1
         T = self.engine.eng.page_tokens
-        if self._walk_pages:
-            self.stats["decode_pages_walked"] += self._walk_pages
+        walked = self.engine.decode_page_visits(self.cache, "g", kv_lens)
+        if walked:
+            self.stats["decode_pages_walked"] += walked
             self.stats["decode_pages_live"] += int(np.sum(-(-context // T)))
-        if self._walk_pages_w:
+        walked_w = self.engine.decode_page_visits(self.cache, "w", kv_lens)
+        if walked_w:
             lo = np.maximum(context - self.cfg.window, 0)
-            self.stats["decode_pages_walked_w"] += self._walk_pages_w
+            self.stats["decode_pages_walked_w"] += walked_w
             self.stats["decode_pages_live_w"] += int(np.sum(
                 (context - 1) // T - lo // T + 1))
 
@@ -1234,7 +1232,8 @@ class ContinuousBatcher:
                 (ch, prev_t, mask, self._temps, self._topk, self._topp,
                  self._seeds, positions)))
         self._lengths[active] += 1
-        self._count_walk(self._lengths[active])
+        self._count_walk(self._lengths[active],
+                         np.where(mask, self._lengths, 0))
         cap = {i for i in active
                if self._lengths[i] + 1 >= self.max_context}
         self._inflight.append(_Inflight(

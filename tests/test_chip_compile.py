@@ -5,7 +5,8 @@ blocks whose last two dims are neither (8, 128)-aligned nor the array's
 own, vector loads from SMEM, shape casts it has no layout for.  These
 tests compile each kernel at qwen1.5-0.5b decode widths (B=8 slots, 16
 MHA kv-heads, d_head 64, 16-token pages, 128 pages per slot, a 1024-page
-shared pool) and at trinity-mini-l8's (32 slots, 4 kv-heads of 8 query
+shared pool; and the chat and longctx cells' slots and pages) and at
+trinity-mini-l8's (32 slots, 4 kv-heads of 8 query
 heads, d_head 128; a global pool of 256 pages per slot, window rings of
 136) for a v5e chip that is described, not attached: nothing runs, no
 chip is needed, each compile takes about a second.  The last test
@@ -38,6 +39,8 @@ from repro.models.transformer import Runtime
 T = 16
 # (slots, kv heads, group, d_head, pages per slot, shared-pool pages)
 SHAPES = {"qwen": (8, 16, 1, 64, 128, 1024),
+          "qwen-chat": (32, 16, 1, 64, 64, 2048),
+          "qwen-longctx": (8, 16, 1, 64, 256, 2048),
           "trinity-global": (32, 4, 8, 128, 256, 8192),
           "trinity-window": (32, 4, 8, 128, 136, 4352)}
 
@@ -91,6 +94,10 @@ def _case(layout, kv_quant, partitions, layers, shape="qwen"):
     _case("striped", "none", 1, 2, "trinity-global"),
     _case("striped", "none", 1, 6, "trinity-window"),
     _case("shared", "none", 1, 6, "trinity-window"),
+    # the benchmark's Qwen cells: chat's 32 slots × 64 pages, and
+    # longctx's 8 × 256 pages in 16 partitions
+    _case("striped", "none", 1, 24, "qwen-chat"),
+    _case("striped", "none", 16, 24, "qwen-longctx"),
 ])
 def test_paged_decode_kernel_compiles_for_v5e(one_chip, layout, kv_quant,
                                               partitions, layers, shape):

@@ -260,3 +260,122 @@ def test_stacked_pool_at_layer_matches_layer_slice(layout, kv_quant,
         q, kp, vp, base, length, **kw))(kp[layer], vp[layer])
     for got, want in zip(stacked, sliced):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# ---------------------------------------------------------------------------
+# the folded, clamped walk: Kh kv-heads per grid step, and no dead block
+# fetched or scored — against the ref path and the dense oracle, on rows
+# whose live blocks end anywhere in the walk.
+
+def _kernel_walk(q, kp, vp, base, length, *, table=None, fmt="none",
+                 ks=None, vs=None, partitions=1, window=None,
+                 pages_per_block=4, heads="all"):
+    """The interpret-mode kernel called directly (so the test can force
+    Kh < K through its VMEM budget), partials merged: (o [B, H, dh],
+    m [B, H], l [B, H]) like `paged_attention_partial`."""
+    from repro.kernels.paged_attention import kernel as kmod
+    from repro.kernels.paged_attention import merge_partials
+    B, H, dh = q.shape
+    K = kp.shape[0] if table is not None else kp.shape[1]
+    G = H // K
+    kw = dict(window=window, interpret=True, kv_quant=fmt, k_scale=ks,
+              v_scale=vs, partitions=partitions)
+    if table is not None:
+        head_bytes = kp.shape[-2] * dh * kp.dtype.itemsize
+    else:
+        head_bytes = pages_per_block * kp.shape[-2] * dh * kp.dtype.itemsize
+    if heads == "split":        # room for two heads' K+V, double-buffered
+        kw["kv_vmem_bytes"] = 4 * 2 * head_bytes
+        assert kmod._heads_per_step(K, head_bytes, kw["kv_vmem_bytes"]) \
+            == 2 < K
+    if table is not None:
+        o, m, l = kmod.paged_attention_pallas_shared(
+            q.reshape(B, K, G, dh), kp, vp, table, base, length, **kw)
+    else:
+        o, m, l = kmod.paged_attention_pallas(
+            q.reshape(B, K, G, dh), kp, vp, base, length,
+            pages_per_block=pages_per_block, **kw)
+    if partitions > 1:
+        o, m, l = merge_partials(o, m, l, axis=2)
+    return o.reshape(B, H, dh), m.reshape(B, H), l.reshape(B, H)
+
+
+def _check_walk(got, q, kp, vp, base, length, kd, vd, *, table=None,
+                fmt="none", ks=None, vs=None, window=None):
+    """`got` against the ref path (o, m, l, every row) and, unquantized,
+    against the dense oracle (o, rows holding a key)."""
+    want = paged_attention_partial(q, kp, vp, base, length, window=window,
+                                   impl="ref", kv_quant=fmt, k_scale=ks,
+                                   v_scale=vs, page_table=table)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   atol=5e-4, rtol=5e-4)
+    if fmt != "none":
+        return
+    for b, L in enumerate(np.asarray(length)):
+        if L == 0:
+            assert float(jnp.max(got[2][b])) == 0.0    # the empty partial
+            continue
+        ref = dense_attention_ref(q[b:b + 1, None], kd[b:b + 1, :L],
+                                  vd[b:b + 1, :L], causal=True,
+                                  window=window, q_offset=int(L) - 1)
+        np.testing.assert_allclose(np.asarray(got[0][b]),
+                                   np.asarray(ref[0, 0]),
+                                   atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("heads", ["all", "split"])
+@pytest.mark.parametrize("fmt", PARITY_FMTS)
+@pytest.mark.parametrize("layout", ["striped", "shared"])
+def test_folded_walk_skips_dead_blocks(layout, fmt, heads):
+    """Rows of 0 keys, one key, exactly one 4-page block, the full stripe,
+    and 40 keys — whose second partition (pages 8-15) holds no live
+    block and finalizes as the empty partial."""
+    B, K, G, NP, T, dh = 5, 4, 2, 16, 8, 32
+    kd, vd, kp, vp, base = _build(B, K, NP, T, dh, jnp.float32)
+    kp, vp, ks, vs = _quantize(kp, vp, fmt)
+    table = None
+    if layout == "shared":
+        kp, vp, ks, vs, table = _shared_pool(kp, vp, ks, vs)
+    q = jax.random.normal(jax.random.PRNGKey(7), (B, K * G, dh))
+    length = jnp.asarray([0, 1, 4 * T, NP * T, 40], jnp.int32)
+    kw = dict(table=table, fmt=fmt, ks=ks, vs=vs)
+    got = _kernel_walk(q, kp, vp, base, length, partitions=2, heads=heads,
+                       **kw)
+    _check_walk(got, q, kp, vp, base, length, kd, vd, **kw)
+
+
+@pytest.mark.parametrize("fmt", ["none", "kv8"])
+@pytest.mark.parametrize("layout", ["striped", "shared"])
+def test_folded_walk_window_ring_before_and_after_wrap(layout, fmt):
+    """A window ring of 8 pages (a 40-key window at 8-token pages, as
+    `cache_spec` sizes it): a row that has not wrapped (30 keys: one live
+    block of two) and rows that have (100 and 129 keys: every block
+    live, the oldest pages outside the window)."""
+    from repro.core.paged_kv import window_page_positions
+    B, K, G, NPw, T, dh, window = 3, 2, 4, 8, 8, 32, 40
+    lengths = (30, 100, 129)
+    S = max(lengths)
+    ks_ = jax.random.split(jax.random.PRNGKey(5), 3)
+    kd = jax.random.normal(ks_[0], (B, S, K, dh), jnp.float32)
+    vd = jax.random.normal(ks_[1], (B, S, K, dh), jnp.float32)
+    base = np.stack([window_page_positions(L, NPw, T) for L in lengths])
+    pos = base[:, :, None] + np.arange(T)[None, None]         # [B, NPw, T]
+    idx = np.clip(pos, 0, S - 1)
+    rows = np.arange(B)[:, None, None]
+
+    def ring(dense):          # [B, S, K, dh] -> [B, K, NPw, T, dh]
+        return jnp.asarray(np.asarray(dense)[rows, idx]).transpose(
+            0, 3, 1, 2, 4)
+    kp, vp = ring(kd), ring(vd)
+    kp, vp, ks, vs = _quantize(kp, vp, fmt)
+    table = None
+    if layout == "shared":
+        kp, vp, ks, vs, table = _shared_pool(kp, vp, ks, vs)
+    base = jnp.asarray(base, jnp.int32)
+    length = jnp.asarray(lengths, jnp.int32)
+    q = jax.random.normal(ks_[2], (B, K * G, dh))
+    kw = dict(table=table, fmt=fmt, ks=ks, vs=vs, window=window)
+    got = _kernel_walk(q, kp, vp, base, length, **kw)
+    _check_walk(got, q, kp, vp, base, length, kd, vd, **kw)

@@ -35,7 +35,7 @@ def _close(a, b, tol=1e-4):
                                rtol=tol, atol=tol)
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(n=st.integers(2, 7), seed=st.integers(0, 1000))
 def test_merge_associativity(n, seed):
     """Folding a prefix first, then merging its result with the rest,
@@ -53,7 +53,7 @@ def test_merge_associativity(n, seed):
         _close(a, b)
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(n=st.integers(2, 8), seed=st.integers(0, 1000))
 def test_merge_permutation_invariance(n, seed):
     rng = np.random.default_rng(seed)
@@ -65,7 +65,7 @@ def test_merge_permutation_invariance(n, seed):
         _close(a, b)
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(n=st.integers(1, 6), n_empty=st.integers(1, 4),
        seed=st.integers(0, 1000))
 def test_empty_partition_is_identity(n, n_empty, seed):

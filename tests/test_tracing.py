@@ -148,23 +148,49 @@ def test_engine_loop_spans_route_and_commands(model, tmp_path):
     assert_leaves(spans)
 
 
+def kernel_lengths(b, box):
+    """Record every sequential decode step's kernel lengths: each row's
+    keys after the step's append, 0 for a row the step leaves alone."""
+    fn = b._dispatch_sequential
+
+    def wrapped(active):
+        out = fn(active)
+        box.append([int(b._lengths[i]) if i in active else 0
+                    for i in range(b.B)])
+        return out
+    b._dispatch_sequential = wrapped
+
+
+def fetched(n, pages, ppb):
+    """Pages the kernel fetches for a row of n keys over `pages` pages
+    per row in blocks of `ppb`: the blocks up to its last written page,
+    and one block for a row with none."""
+    live = min(-(-n // T), pages)
+    return max(-(-live // ppb), 1) * ppb
+
+
 def test_walk_counters_match_a_hand_count(model):
-    """walked: every decode step visits rows x pages per row of the
-    kernel's grid (2 x 64/16 here); live: a request with an n-token
-    prompt decodes max_new - 1 steps, the j-th over n + j tokens."""
+    """walked: every decode step fetches, per row, the 8-page blocks up
+    to its last written page of 256 // 16 (one block for an idle slot);
+    live: a request with an n-token prompt decodes max_new - 1 steps,
+    the j-th over n + j tokens."""
     cfg, params = model
-    b = ContinuousBatcher(cfg, params, batch_slots=2, max_context=64,
+    b = ContinuousBatcher(cfg, params, batch_slots=2, max_context=256,
                           prefill_chunk_tokens=T)
-    lens, max_new = (20, 7, 33), 6
+    lens, max_new = (20, 120, 7), 14
     for uid, n in enumerate(lens):
         b.submit(Request(uid, list(range(1, n + 1)), max_new=max_new))
-    calls = {}
-    counted(b, "_dispatch_sequential", calls)
+    steps = []
+    kernel_lengths(b, steps)
     b.run_to_completion()
     live = sum(-(-(n + j) // T) for n in lens for j in range(1, max_new))
     assert b.stats["decode_pages_live"] == live
-    assert b.stats["decode_pages_walked"] == \
-        calls["_dispatch_sequential"] * 2 * (64 // T)
+    walked = sum(fetched(n, 256 // T, 8) for row in steps for n in row)
+    assert b.stats["decode_pages_walked"] == walked
+    # the 120-token request crosses into its second block; no step walks
+    # the whole grid
+    assert max(max(row) for row in steps) > 8 * T
+    assert walked < len(steps) * 2 * (256 // T)
     text = ServingMetrics().render(b.stats)
     assert f"kvnand_decode_pages_walked_total " \
         f"{b.stats['decode_pages_walked']}" in text
@@ -172,34 +198,42 @@ def test_walk_counters_match_a_hand_count(model):
 
 
 def test_walk_counters_shared_pool_use_the_table_width(model):
-    """A shared pool walks the page table's width per row, whatever the
-    pool's physical size."""
+    """A shared pool's walk spans the page table's width per row,
+    whatever the pool's physical size, one page a block: a decode step
+    fetches each row's pages up to its last, and one page of an idle
+    slot."""
     cfg, params = model
     eng = EngineConfig(page_tokens=T, uniform_lengths=False,
                        shared_pool=True, total_pages=24)
     b = ContinuousBatcher(cfg, params, batch_slots=2, max_context=96,
                           eng=eng, prefill_chunk_tokens=T)
     assert b.engine.decode_page_visits(b.cache) == 2 * (96 // T)
+    assert b.engine.decode_page_visits(b.cache, lengths=[96, 0]) == \
+        96 // T + 1
     b.submit(Request(0, list(range(1, 18)), max_new=3))
     b.run_to_completion()
     assert b.stats["decode_pages_live"] == 2 + 2        # 18, 19 tokens
-    assert b.stats["decode_pages_walked"] == 2 * 2 * (96 // T)
+    assert b.stats["decode_pages_walked"] == (2 + 1) * 2
 
 
 def test_walk_counters_under_speculation(model):
-    """A verify step walks the same grid as a decode step, and its live
-    pages cover each row's span: walked counts every enqueued step once,
-    whichever kind it was."""
+    """A verify step's jnp walk reads every page of every row; a
+    sequential step (when no row may accept) fetches the kernel's live
+    blocks.  walked counts every enqueued step once, whichever kind it
+    was, and its live pages cover each row's span."""
     cfg, params = model
-    b = ContinuousBatcher(cfg, params, batch_slots=2, max_context=64,
+    b = ContinuousBatcher(cfg, params, batch_slots=2, max_context=256,
                           prefill_chunk_tokens=T, speculation_k=2)
     for uid, n in enumerate((20, 9)):
         b.submit(Request(uid, [1, 2, 3] * (n // 3) + [1] * (n % 3),
                          max_new=8))
-    calls = {}
+    calls, steps = {}, []
     counted(b, "_dispatch_decode", calls)
+    kernel_lengths(b, steps)
     b.run_to_completion()
     assert b.stats["spec_steps"] > 0
+    verify = calls["_dispatch_decode"] - len(steps)
     assert b.stats["decode_pages_walked"] == \
-        calls["_dispatch_decode"] * 2 * (64 // T)
+        verify * 2 * (256 // T) + sum(fetched(n, 256 // T, 8)
+                                      for row in steps for n in row)
     assert 0 < b.stats["decode_pages_live"] <= b.stats["decode_pages_walked"]
