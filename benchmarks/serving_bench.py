@@ -353,9 +353,8 @@ def run():
 
     cfg = get_config(ARCH).reduced()
     params = Model(cfg, Runtime()).init(jax.random.PRNGKey(0))
-    stripe = EngineConfig(page_tokens=PAGE_TOKENS, uniform_lengths=False)
-    shared = EngineConfig(page_tokens=PAGE_TOKENS, uniform_lengths=False,
-                          shared_pool=True)
+    stripe = EngineConfig(page_tokens=PAGE_TOKENS)
+    shared = EngineConfig(page_tokens=PAGE_TOKENS, shared_pool=True)
     prompts = _trace(cfg.vocab_size)
 
     outs = {}
@@ -401,8 +400,8 @@ def run():
 
     # capacity-proportional admission: 6 slots whose per-slot stripes
     # (6 × NPg pages) cannot fit the 16-page pool, yet the actual mix can
-    cap_eng = EngineConfig(page_tokens=PAGE_TOKENS, uniform_lengths=False,
-                           shared_pool=True, total_pages=16)
+    cap_eng = EngineConfig(page_tokens=PAGE_TOKENS, shared_pool=True,
+                           total_pages=16)
     rng = np.random.default_rng(13)
     cap_prompts = [rng.integers(1, cfg.vocab_size, 11).tolist()
                    for _ in range(6)]
@@ -452,10 +451,10 @@ def run():
     # demand faults relative to the ablation
     tier_uniq = _tier_trace(cfg.vocab_size)
     flat_eng = EngineConfig(page_tokens=PAGE_TOKENS,
-                            uniform_lengths=False, shared_pool=True,
+                            shared_pool=True,
                             total_pages=TIER_TOTAL_PAGES)
     tier_eng = EngineConfig(page_tokens=PAGE_TOKENS,
-                            uniform_lengths=False, shared_pool=True,
+                            shared_pool=True,
                             total_pages=TIER_TOTAL_PAGES,
                             hot_pages=TIER_HOT_PAGES)
     _, o_flat, _ = _drain_tiered(cfg, params, flat_eng, tier_uniq)

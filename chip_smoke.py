@@ -255,12 +255,10 @@ def one_chip(cfg, params, prompts, seed):
     served = {}
     phase("b", lambda: served.update(b=serve("b", None, prompts, TOL_POOL)))
     phase("e:b", lambda: kernel(served.pop("b"), "b"))
-    kv8 = EngineConfig(page_tokens=16, uniform_lengths=False,
-                       shared_pool=True, kv_quant="kv8")
+    kv8 = EngineConfig(page_tokens=16, shared_pool=True, kv_quant="kv8")
     phase("c", lambda: served.update(c=serve("c", kv8, prompts, TOL_POOL)))
     phase("e:c", lambda: kernel(served.pop("c"), "c"))
-    f32 = EngineConfig(page_tokens=16, uniform_lengths=False,
-                       kv_dtype="float32")
+    f32 = EngineConfig(page_tokens=16, kv_dtype="float32")
     phase("d", lambda: serve("d", f32, prompts[:1], TOL_F32, slots=1))
     return all(results.values())
 
@@ -268,8 +266,7 @@ def one_chip(cfg, params, prompts, seed):
 def replicas(cfg, params, prompts, seed, n, devs):
     """Four one-chip replicas behind a disaggregated router vs one
     server on device 0 (which is freed before the fleet is built)."""
-    eng = EngineConfig(page_tokens=16, uniform_lengths=False,
-                       shared_pool=True)
+    eng = EngineConfig(page_tokens=16, shared_pool=True)
     sc = server_config(eng, seed)
     solo = KVNANDServer(sc, cfg=cfg, params=params)
     want = drive(solo, prompts, "solo device 0")

@@ -32,8 +32,10 @@ def main():
     print("\n=== engine reconfiguration (paper: software-defined) ===")
     for seq in (1_000, 100_000):
         eng = dse.recommend_engine_config(arch, seq)
-        print(f"ctx {seq:>7}: variant={eng.variant:9s} quant={eng.quant} "
-              f"hg_pipeline={eng.hg_pipeline}")
+        point = dse.recommend_point(arch, seq)
+        system = point.system if point else "no fit"
+        print(f"ctx {seq:>7}: system={system:18s} quant={eng.quant} "
+              f"kv_quant={eng.kv_quant} partitions={eng.attn_partitions}")
     t = dse.takeaways(get_config("opt-30b"), get_config("llama3.1-70b"))
     print("\npaper takeaways reproduced:", t)
 

@@ -41,8 +41,7 @@ def main():
     # KVNANDServer owns engine + scheduler construction; pass the freshly
     # trained params instead of letting it initialize its own
     server = KVNANDServer(
-        ServerConfig(engine=EngineConfig(page_tokens=8,
-                                         uniform_lengths=False),
+        ServerConfig(engine=EngineConfig(page_tokens=8),
                      batch_slots=1, max_context=64),
         cfg=cfg, params=state.params, rt=rt)
     out = server.generate([[5, 17, 42, 7]],

@@ -30,8 +30,7 @@ def post(addr, payload):
 def main():
     config = ServerConfig(
         arch="qwen1.5-0.5b", reduced=True,
-        engine=EngineConfig(page_tokens=16, uniform_lengths=False,
-                            shared_pool=True),
+        engine=EngineConfig(page_tokens=16, shared_pool=True),
         batch_slots=2, max_context=96, prefill_chunk_tokens=16)
     with BackgroundServer(config,
                           AsyncServerConfig(max_queue=8)) as srv:

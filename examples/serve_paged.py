@@ -12,8 +12,7 @@ from repro.serving.api import KVNANDServer, SamplingParams, ServerConfig
 def main():
     server = KVNANDServer(ServerConfig(
         arch="qwen1.5-0.5b", reduced=True,
-        engine=EngineConfig(page_tokens=16, uniform_lengths=False,
-                            shared_pool=True),
+        engine=EngineConfig(page_tokens=16, shared_pool=True),
         batch_slots=3, max_context=128, prefill_chunk_tokens=32))
 
     rng = np.random.default_rng(0)

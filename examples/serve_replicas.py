@@ -16,8 +16,7 @@ from repro.serving.router import ReplicaRouter
 
 
 def _fleet(n, cfg, params, rt):
-    eng = EngineConfig(page_tokens=16, uniform_lengths=False,
-                       shared_pool=True, total_pages=48)
+    eng = EngineConfig(page_tokens=16, shared_pool=True, total_pages=48)
     sc = ServerConfig(arch="qwen1.5-0.5b", reduced=True, engine=eng,
                       batch_slots=2, max_context=64,
                       prefill_chunk_tokens=16, seed=7)

@@ -274,8 +274,6 @@ def shape_applicable(cfg: ModelConfig, shape: ShapeConfig) -> Tuple[bool, str]:
 
 @dataclass(frozen=True)
 class EngineConfig:
-    variant: str = "compact"        # "compact" (KVNAND-C) | "discrete" (KVNAND-D)
-    hg_pipeline: bool = False       # head-group pipelining (KVNAND-D dataflow)
     page_tokens: int = 64           # tokens per KV page (flash-page analogue)
     quant: str = "none"             # "none" | "w8a8" | "w4a16"
     kv_quant: str = "none"          # "none" | "kv8" | "kv4" paged-KV format
@@ -294,7 +292,6 @@ class EngineConfig:
     # 0 = single tier (the whole pool is hot).  DSE-selectable via
     # `core.dse.recommend_hot_pages`.
     hot_pages: int = 0
-    uniform_lengths: bool = True    # static batching: lockstep appends
     # draft-and-verify speculative decoding: tokens drafted per decode
     # step (prompt lookup) and verified in one pass; 0 = sequential.
     # DSE-selectable (`recommend_engine_config`) like the other knobs;
@@ -305,7 +302,6 @@ class EngineConfig:
     # the LSE merge core (0 = auto from the page count; must divide the
     # per-device page count when set).  DSE-searchable like kv_quant.
     attn_partitions: int = 0
-    gemv_impl: str = "auto"
     # training-side knobs
     remat: str = "block"            # "none" | "block" | "full"
     microbatches: int = 1

@@ -206,14 +206,36 @@ def recommend_hot_pages(sys: fs.SystemConfig, cfg: ModelConfig, seq: int,
     return hot
 
 
+def recommend_point(arch: str, seq: int, *, total_dies: int = 16,
+                    allow_kv_quant: bool = True) -> Optional[DSEPoint]:
+    """The Track-A DSE winner at context `seq`: the fastest design point
+    over die grouping (the KVNAND-D splits and KVNAND-C), W4A16 vs W8A8
+    and, with `allow_kv_quant`, the KV page format (fidelity-guarded, see
+    `recommend_engine_config`); None when nothing fits the flash budget."""
+    cfg = get_config(arch)
+    kv_axis = tuple(KV_FORMATS) if allow_kv_quant else (0,)
+    kv_fidelity_margin = 1.05
+    candidates = []
+    for wbits, abits in ((4, 16), (8, 8)):
+        for kv_bits in kv_axis:
+            p = best_config(cfg, seq, total_dies, wbits, abits, kv_bits)
+            if p is not None:
+                candidates.append(p)
+    if not candidates:
+        return None
+    best_lat = min(p.latency for p in candidates)
+    near = [p for p in candidates
+            if p.latency <= best_lat * kv_fidelity_margin]
+    return max(near, key=lambda p: (p.kv_bits == 0, p.kv_bits, -p.latency))
+
+
 def recommend_engine_config(arch: str, seq: int, *,
                             total_dies: int = 16,
                             allow_kv_quant: bool = True,
                             spec_accept_rate: float = 0.0) -> EngineConfig:
-    """Map the Track-A DSE winner onto Track-B engine knobs:
+    """Map the Track-A DSE winner (`recommend_point`) onto Track-B engine
+    knobs:
 
-    KVNAND-D winner  -> discrete plan (HG pipelining on)
-    KVNAND-C winner  -> compact plan
     W4A16 vs W8A8    -> whichever quantization wins at this context
     kv8/kv4 pages    -> cheapest KV format, but fidelity-guarded: the
                         bandwidth model is monotone in kv_bits (fewer
@@ -233,32 +255,24 @@ def recommend_engine_config(arch: str, seq: int, *,
                         (`recommend_attn_partitions`): long contexts
                         pick a plane-parallel split, short contexts
                         keep the monolithic walk.
+
+    The winner's die grouping (KVNAND-D or KVNAND-C) is a property of the
+    flash system and sets no engine knob: the TPU engine runs one decode
+    dataflow (DESIGN.md §2).
     """
-    cfg = get_config(arch)
-    kv_axis = tuple(KV_FORMATS) if allow_kv_quant else (0,)
-    kv_fidelity_margin = 1.05
-    candidates = []
-    for wbits, abits, quant in ((4, 16, "w4a16"), (8, 8, "w8a8")):
-        for kv_bits in kv_axis:
-            p = best_config(cfg, seq, total_dies, wbits, abits, kv_bits)
-            if p is not None:
-                candidates.append((p.latency, p, quant))
-    if not candidates:
-        # nothing fits the flash budget — compact + max quantization
-        return EngineConfig(variant="compact", quant="w4a16",
+    p = recommend_point(arch, seq, total_dies=total_dies,
+                        allow_kv_quant=allow_kv_quant)
+    if p is None:
+        # nothing fits the flash budget — max quantization
+        return EngineConfig(quant="w4a16",
                             kv_quant="kv4" if allow_kv_quant else "none")
-    best_lat = min(c[0] for c in candidates)
-    near = [c for c in candidates if c[0] <= best_lat * kv_fidelity_margin]
-    _, p, quant = max(near, key=lambda c: (c[1].kv_bits == 0, c[1].kv_bits,
-                                           -c[0]))
-    variant = "discrete" if p.system.startswith("KVNAND-D") else "compact"
+    cfg = get_config(arch)
     spec_k = 0
     if spec_accept_rate > 0.0:
         spec_k = recommend_speculation_k(_system_of(p), cfg, seq,
                                          spec_accept_rate)
     attn_parts = recommend_attn_partitions(_system_of(p), cfg, seq)
-    return EngineConfig(variant=variant, quant=quant,
-                        hg_pipeline=(variant == "discrete"),
+    return EngineConfig(quant="w4a16" if p.wbits == 4 else "w8a8",
                         kv_quant=KV_FORMATS[p.kv_bits],
                         speculation_k=spec_k,
                         attn_partitions=attn_parts)

@@ -1,4 +1,4 @@
-"""KVNAND engine — prefill + decode with paged KV, compact/discrete plans.
+"""KVNAND engine — prefill + decode with paged KV.
 
 The decode step realizes the paper's Figure 7(b) on a TPU mesh:
 
@@ -7,14 +7,13 @@ The decode step realizes the paper's Figure 7(b) on a TPU mesh:
     striped over `model` (± spare batch axes for batch-1 long context);
   * Logit/Attend are per-shard partials over local pages, merged by a
     log-sum-exp combine (the paper's NPU softmax-aggregation, Fig 8 ❺–❼);
-  * `variant="discrete"` pipelines head groups (Fig 9(c)/10(a)): the q-GEMV
-    of head-group i+1 is issued in the same scan step as the attention of
-    head-group i with no data dependence between them — XLA's latency-hiding
-    scheduler overlaps them exactly as the G1/G2 dies do.  On a TPU the
-    paper's *spatial* G1/G2 split would idle half the MXUs (flash PEs are
-    fixed-function; TPUs are not), so the split is temporal — see DESIGN.md.
-  * `variant="compact"` fuses all heads into single larger GEMVs (max TP,
-    Fig 10(b)).
+  * one dataflow per layer: a fused QKV GEMV over all heads (KVNAND-C,
+    Fig 10(b)), a ragged append of each row's new token into its own page,
+    and one paged-attention call in which each grid step walks all of a
+    row's KV heads.  On one device an unquantized pool is read in place at
+    the layer's index; quantized pools and meshes read a layer slice.  The
+    paper's head-group split (KVNAND-D) is a property of fixed-function
+    flash dies and lives in the DSE's flash model only — see DESIGN.md §2.
 
 Memory discipline (§Perf iteration 1): KV pools and recurrent states are
 scan CARRIES updated in place at a traced layer index — never scan xs/ys.
@@ -312,15 +311,6 @@ class KVNANDEngine:
     # ------------------------------------------------------------------
     # in-place pool ops (pools carried through the layer scan)
     # ------------------------------------------------------------------
-    def _append_token(self, pool, layer, phys, slot, val):
-        """pool: [L, B, K, NP, T, dh]; write one token's K or V in place
-        through the `paged_kv` writer family (KV004: pool-leaf writes live
-        in core/paged_kv.py; see its docstring for the uniform-lengths
-        fast-path rationale)."""
-        return paged_kv.append_token_inplace(
-            pool, layer, phys, slot, val,
-            uniform_lengths=self.eng.uniform_lengths)
-
     @staticmethod
     def _layer_slice(pool, layer):
         return jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
@@ -351,64 +341,14 @@ class KVNANDEngine:
                                                          0)
 
     # ------------------------------------------------------------------
-    # per-layer attention (compact vs discrete)
-    # ------------------------------------------------------------------
-    def _attend_compact(self, pl_, x_norm, kp, vp, ks, vs, base, lengths,
-                        plan, pool, window, table=None, layer=None,
-                        rope=True):
-        """Fused QKV gen + attention (KVNAND-C, Fig 10b).  kp/vp are the
-        already-appended layer slices (+scales when the pool is quantized),
-        or the stacked pools and their `layer`."""
-        with jax.named_scope("qkv"):
-            q, _, _ = attn_mod.project_qkv(pl_["attn"], self.cfg, x_norm,
-                                           lengths[:, None], rope=rope)
-        with jax.named_scope("paged_attn"):
-            return self._paged_attn(q[:, 0], kp, vp, base,
-                                    self._kv_lengths(lengths), plan, pool,
-                                    window, ks, vs, table, layer)
-
-    def _attend_discrete(self, pl_, x_norm, kp, vp, ks, vs, base, lengths,
-                         plan, pool, window, table=None, rope=True):
-        """Head-group pipelined attention (KVNAND-D, Fig 10a): q-GEMV of
-        group i+1 is independent of group i's attention -> overlapped."""
-        cfg = self.cfg
-        B = x_norm.shape[0]
-        K = cfg.n_kv_heads
-        x_tok = x_norm[:, 0]
-        k_axis = 0 if kp.ndim == 4 else 1   # shared pools are [K, P, T, dh]
-
-        def body(q_cur, i):
-            with jax.named_scope("qkv"):
-                q_next = attn_mod.project_q_group(
-                    pl_["attn"], cfg, x_tok, jnp.minimum(i + 1, K - 1),
-                    lengths, rope=rope)
-            # slice head group i on the K dim directly (no pool transpose)
-            with jax.named_scope("pool_view"):
-                kp_i = jax.lax.dynamic_slice_in_dim(kp, i, 1, k_axis)
-                vp_i = jax.lax.dynamic_slice_in_dim(vp, i, 1, k_axis)
-                ks_i = vs_i = None
-                if ks is not None:
-                    ks_i = jax.lax.dynamic_slice_in_dim(ks, i, 1, k_axis)
-                    vs_i = jax.lax.dynamic_slice_in_dim(vs, i, 1, k_axis)
-            with jax.named_scope("paged_attn"):
-                o = self._paged_attn(q_cur, kp_i, vp_i, base,
-                                     self._kv_lengths(lengths), plan, pool,
-                                     window, ks_i, vs_i, table)  # [B, G, dh]
-            return q_next, o
-
-        with jax.named_scope("qkv"):
-            q0 = attn_mod.project_q_group(pl_["attn"], cfg, x_tok,
-                                          jnp.zeros((), jnp.int32), lengths,
-                                          rope=rope)
-        _, outs = jax.lax.scan(body, q0, jnp.arange(K))
-        return outs.transpose(1, 0, 2, 3).reshape(B, cfg.n_heads,
-                                                  cfg.d_head)
-
-    # ------------------------------------------------------------------
     # decode blocks
     # ------------------------------------------------------------------
     def _decode_attn_layer(self, pl_, x, pools, g_idx, w_idx, lengths,
                            plan, is_glob):
+        """Norm, fused QKV, ragged append of each row's new K/V, paged
+        attention over the row's pages (all its KV heads per grid step)
+        and the output projection.  Returns (normed x, attention output,
+        pools)."""
         cfg = self.cfg
         shared = self.eng.shared_pool
         h = rms_norm(x, pl_["ln1"], cfg.norm_eps)
@@ -449,88 +389,52 @@ class KVNANDEngine:
             # writer discards the write
             phys = jnp.where(self._active, phys, drop)
         page_axes = (plan.page_axes_w if use_window else plan.page_axes_g)
-        sharded = (self.mesh is not None and self.mesh.size > 1
-                   and bool(page_axes))
+        one_device = self.mesh is None or self.mesh.size == 1
         fmt = self.eng.kv_quant
         ksname = "k_scale_w" if use_window else "k_scale_g"
         vsname = "v_scale_w" if use_window else "v_scale_g"
+        names = (kname, vname) + ((ksname, vsname) if fmt != "none" else ())
         with jax.named_scope("kv_append"):
-            if sharded and shared:
-                # shared pool sharded over P_total: the owning shard
-                # translates the global physical index to its local range
-                # and scatters
-                out = seqpar.sharded_append_shared(
+            if not one_device and page_axes:
+                # append INSIDE the shard that owns each row's page (the
+                # paper's direct G2-die write)
+                pools.update(zip(names, seqpar.sharded_append(
                     pools[kname], pools[vname], idx, k1, v1, phys, slot,
                     self.mesh, batch_axes=plan.batch_axes,
                     page_axes=page_axes, k_scale=pools.get(ksname),
-                    v_scale=pools.get(vsname), kv_quant=fmt)
-                if fmt != "none":
-                    (pools[kname], pools[vname], pools[ksname],
-                     pools[vsname]) = out
-                else:
-                    pools[kname], pools[vname] = out
-            elif sharded and self.eng.uniform_lengths:
-                # append INSIDE the owning shard (paper: direct G2-die
-                # write); a pjit-level update on the sharded page dim
-                # lowers to a full-pool ownership select per layer (§Perf
-                # iteration 2)
-                if fmt != "none":
-                    (pools[kname], pools[vname], pools[ksname],
-                     pools[vsname]) = seqpar.sharded_append_uniform(
-                        pools[kname], pools[vname], idx, k1, v1, phys,
-                        slot, self.mesh, batch_axes=plan.batch_axes,
-                        page_axes=page_axes, k_scale=pools[ksname],
-                        v_scale=pools[vsname], kv_quant=fmt)
-                else:
-                    (pools[kname],
-                     pools[vname]) = seqpar.sharded_append_uniform(
-                        pools[kname], pools[vname], idx, k1, v1, phys,
-                        slot, self.mesh, batch_axes=plan.batch_axes,
-                        page_axes=page_axes)
+                    v_scale=pools.get(vsname), kv_quant=fmt)))
             elif fmt != "none":
-                # page-granular requantizing append (tentpole write path)
-                if shared:
-                    append = paged_kv.append_token_quant_shared
-                else:
-                    append = (paged_kv.append_token_quant_uniform
-                              if self.eng.uniform_lengths
-                              else paged_kv.append_token_quant)
+                # page-granular requantizing append
+                append = (paged_kv.append_token_quant_shared if shared
+                          else paged_kv.append_token_quant)
                 pools[kname], pools[ksname] = append(
                     pools[kname], pools[ksname], idx, phys, slot, k1, fmt)
                 pools[vname], pools[vsname] = append(
                     pools[vname], pools[vsname], idx, phys, slot, v1, fmt)
-            elif shared:
-                pools[kname] = paged_kv.append_global_shared(
-                    pools[kname], idx, phys, slot, k1)
-                pools[vname] = paged_kv.append_global_shared(
-                    pools[vname], idx, phys, slot, v1)
             else:
-                pools[kname] = self._append_token(pools[kname], idx, phys,
-                                                  slot, k1)
-                pools[vname] = self._append_token(pools[vname], idx, phys,
-                                                  slot, v1)
-        pool = "w" if use_window else "g"
-        if (fmt == "none" and (self.mesh is None or self.mesh.size == 1)
-                and self.eng.variant != "discrete"
-                and not self.eng.hg_pipeline):
+                append = (paged_kv.append_global_shared if shared
+                          else paged_kv.append_token_inplace)
+                pools[kname] = append(pools[kname], idx, phys, slot, k1)
+                pools[vname] = append(pools[vname], idx, phys, slot, v1)
+        if fmt == "none" and one_device:
             # the kernel reads this layer of the stacked pools in place:
             # a layer slice would be a copy of it, in every layer
-            o = self._attend_compact(pl_, h, pools[kname], pools[vname],
-                                     None, None, base, lengths, plan, pool,
-                                     window, table, layer=idx, rope=rope)
+            kp, vp, layer = pools[kname], pools[vname], idx
+            ks = vs = None
         else:
+            # kv8/kv4 pools and meshes read a copy of the layer
             with jax.named_scope("pool_view"):
-                kp = self._layer_slice(pools[kname], idx)
-                vp = self._layer_slice(pools[vname], idx)
-                ks = vs = None
-                if fmt != "none":
-                    ks = self._layer_slice(pools[ksname], idx)
-                    vs = self._layer_slice(pools[vsname], idx)
-            attend = (self._attend_discrete
-                      if self.eng.variant == "discrete"
-                      or self.eng.hg_pipeline else self._attend_compact)
-            o = attend(pl_, h, kp, vp, ks, vs, base, lengths, plan, pool,
-                       window, table, rope=rope)
+                views = [self._layer_slice(pools[n], idx) for n in names]
+            kp, vp, ks, vs = views + [None] * (4 - len(views))
+            layer = None
+        with jax.named_scope("qkv"):
+            q, _, _ = attn_mod.project_qkv(pl_["attn"], cfg, h,
+                                           lengths[:, None], rope=rope)
+        with jax.named_scope("paged_attn"):
+            o = self._paged_attn(q[:, 0], kp, vp, base,
+                                 self._kv_lengths(lengths), plan,
+                                 "w" if use_window else "g", window, ks, vs,
+                                 table, layer)
         with jax.named_scope("attn_out"):
             aout = attn_mod.project_out(pl_["attn"], cfg, o[:, None], h)
         return h, aout, pools
@@ -651,9 +555,6 @@ class KVNANDEngine:
         (an int32 scalar; 0 for a model without them).
         """
         cfg, rt = self.cfg, self.rt
-        if active is not None and self.eng.uniform_lengths:
-            raise ValueError("active-mask decode requires the ragged "
-                             "(uniform_lengths=False) append path")
         self._active = active
         B = tokens.shape[0]
         lengths = cache.lengths
@@ -760,10 +661,6 @@ class KVNANDEngine:
             raise NotImplementedError(
                 "sharded verify_step is not wired; run speculation "
                 "single-host (the mesh path covers sequential decode)")
-        if self.eng.uniform_lengths:
-            raise ValueError("verify_step requires the ragged "
-                             "(uniform_lengths=False) append path: slots "
-                             "accept different span lengths")
         B, S = tokens.shape
         lengths = cache.lengths
         shared = self.eng.shared_pool

@@ -427,48 +427,15 @@ def window_page_positions_dyn(true_len, NP: int, T: int) -> jax.Array:
 # live — they hold a recycled occupant's stale K/V or bucket padding.
 # Those slots are masked at read time, but they MUST NOT enter the new
 # amax: a 10×-larger stale value would inflate the scale and crush the
-# real tokens' precision.  The appends therefore zero the dead tail
+# real tokens' precision.  The append therefore zeroes the dead tail
 # before requantizing.
 
-def _zero_dead_slots(page, slot):
-    """page: [..., T, dh]; keep slots 0..slot, zero the rest."""
-    T = page.shape[-2]
-    live = jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0) <= \
-        jnp.reshape(slot, (1, 1))
-    return jnp.where(live, page, 0.0)
-
-
-def append_token_quant_uniform(pool, scale, layer, phys, slot, val,
-                               fmt: str):
-    """Lockstep append into a quantized stacked pool.
-
-    pool: [L, B, K, NP, Ts, dh] int codes; scale: [L, B, K, NP] f32;
-    phys/slot: [B] uniform positions; val: [B, K, dh].
-    """
-    L, B, K, NP, Ts, dh = pool.shape
-    zero = jnp.zeros((), jnp.int32)
-    pidx = (layer, zero, zero, phys[0], zero, zero)
-    qpage = jax.lax.dynamic_slice(pool, pidx,
-                                  (1, B, K, 1, Ts, dh))[0, :, :, 0]
-    s = jax.lax.dynamic_slice(scale, (layer, zero, zero, phys[0]),
-                              (1, B, K, 1))[0, :, :, 0]        # [B, K]
-    page = quant.dequantize_kv_page(qpage, s, fmt)             # [B, K, T, dh]
-    page = jax.lax.dynamic_update_slice(
-        page, val[:, :, None, :].astype(page.dtype),
-        (zero, zero, slot[0], zero))
-    page = _zero_dead_slots(page, slot[0])
-    q2, s2 = quant.quantize_kv_page(page, fmt)
-    pool = jax.lax.dynamic_update_slice(pool, q2[:, :, None][None], pidx)
-    scale = jax.lax.dynamic_update_slice(scale, s2[:, :, None][None],
-                                         (layer, zero, zero, phys[0]))
-    return pool, scale
-
-
 def append_token_quant(pool, scale, layer, phys, slot, val, fmt: str):
-    """Ragged (per-sequence position) append into a quantized pool.
+    """Ragged (per-sequence position) append into a quantized striped
+    pool, for continuous and static batches alike.
 
     Gathers each sequence's touched page, requantizes it with the new
-    token, scatters page + scale back (continuous-batching path).
+    token, scatters page + scale back; phys >= NP drops the write.
     """
     L, B, K, NP, Ts, dh = pool.shape
     b_idx = jnp.arange(B)
@@ -947,23 +914,17 @@ def _put_tokens(pool, upd, phys, slot, start):
     return pool
 
 
-def append_token_inplace(pool, layer, phys, slot, val, *,
-                         uniform_lengths: bool = False):
+def append_token_inplace(pool, layer, phys, slot, val):
     """pool: [L, B, K, NP, T, dh]; write one token's K or V in place.
 
-    Uniform-length fast path: all sequences advance in lockstep (static
-    decode batching — every dry-run cell), so the append is ONE
-    dynamic_update_slice.  The ragged path (continuous batching) writes
-    each sequence's [K, dh] token with its own dynamic_update_slice at
-    (layer, b, 0, phys, slot, 0), and phys >= NP drops the write
-    (`_put_tokens`): the pool keeps the layout it has, so the paged
-    kernel can read it in place.
+    The one striped bf16/f32 append, for continuous and static batches
+    alike: each row's [K, dh] token lands with its own
+    dynamic_update_slice at (layer, b, 0, phys[b], slot[b], 0), and
+    phys >= NP drops the write (`_put_tokens`).  The pool keeps the layout
+    it has, so the paged kernel can read it in place.  On a mesh the
+    shard that owns a row's page runs this writer on its local pool
+    (`seqpar.sharded_append`).
     """
-    if uniform_lengths:
-        upd = val[None, :, :, None, None, :].astype(pool.dtype)
-        zero = jnp.zeros((), jnp.int32)
-        return jax.lax.dynamic_update_slice(
-            pool, upd, (layer, zero, zero, phys[0], slot[0], zero))
     zero = jnp.zeros((), jnp.int32)
     return _put_tokens(
         pool, val[:, None, None, :, None, None, :], phys, slot,

@@ -19,7 +19,7 @@ insight.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -173,112 +173,6 @@ def _shard_page_offset(page_axes: Sequence[str], np_local: int):
     for a in page_axes:
         idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx * np_local
-
-
-def local_append_uniform(pool_local, phys, slot, val, page_axes):
-    """Append one token's K or V inside the owning shard (no cross-shard
-    select): read-modify-write of a single [B, K, 1, 1, dh] slice.  phys and
-    slot are uniform across the batch (lockstep decode).
-
-    pool_local: [B, K, NP_local, T, dh]; val: [B, K, dh].
-    """
-    B, K, NPl, T, dh = pool_local.shape
-    p_loc = phys[0] - _shard_page_offset(page_axes, NPl)
-    owned = (p_loc >= 0) & (p_loc < NPl)
-    p_c = jnp.clip(p_loc, 0, NPl - 1)
-    zero = jnp.zeros((), jnp.int32)
-    cur = jax.lax.dynamic_slice(pool_local, (zero, zero, p_c, slot[0], zero),
-                                (B, K, 1, 1, dh))
-    upd = jnp.where(owned, val[:, :, None, None, :].astype(pool_local.dtype),
-                    cur)
-    return jax.lax.dynamic_update_slice(pool_local, upd,
-                                        (zero, zero, p_c, slot[0], zero))
-
-
-def sharded_append_uniform(pool_k, pool_v, layer, k_new, v_new, phys, slot,
-                           mesh: Mesh, *,
-                           batch_axes: Sequence[str] = ("data",),
-                           page_axes: Sequence[str] = ("model",),
-                           k_scale=None, v_scale=None,
-                           kv_quant: str = "none"):
-    """In-place append of one token's K/V into FULL stacked pools
-    [L, B, K, NP, T, dh] at a traced layer index, inside the owning shard
-    (the paper's direct G2-die write).  Uniform lockstep positions.
-
-    Quantized pools (kv8/kv4) carry per-page×head scales [L, B, K, NP]:
-    the owning shard dequantizes ONLY the touched page, inserts the token,
-    requantizes, and writes page + scale back — still O(page) per layer.
-    Returns (k, v) or (k, v, k_scale, v_scale) when quantized.
-    """
-    from repro.core import quant
-
-    bspec = _axes_spec(batch_axes)
-    pspec = P(None, bspec, None, _axes_spec(page_axes), None, None)
-    sspec = P(None, bspec, None, _axes_spec(page_axes))
-    nspec = P(bspec, None, None)
-    lspec = P(bspec)
-
-    def local(kp, vp, kn, vn, ph, sl, layer):
-        L, B, K, NPl, T, dh = kp.shape
-        p_loc = ph[0] - _shard_page_offset(page_axes, NPl)
-        owned = (p_loc >= 0) & (p_loc < NPl)
-        p_c = jnp.clip(p_loc, 0, NPl - 1)
-        zero = jnp.zeros((), jnp.int32)
-        idx = (layer, zero, zero, p_c, sl[0], zero)
-
-        def put(pool, val):
-            cur = jax.lax.dynamic_slice(pool, idx, (1, B, K, 1, 1, dh))
-            upd = jnp.where(owned,
-                            val[None, :, :, None, None, :].astype(pool.dtype),
-                            cur)
-            return jax.lax.dynamic_update_slice(pool, upd, idx)
-
-        return put(kp, kn), put(vp, vn)
-
-    def local_quant(kp, vp, ks, vs, kn, vn, ph, sl, layer):
-        L, B, K, NPl, Ts, dh = kp.shape
-        p_loc = ph[0] - _shard_page_offset(page_axes, NPl)
-        owned = (p_loc >= 0) & (p_loc < NPl)
-        p_c = jnp.clip(p_loc, 0, NPl - 1)
-        zero = jnp.zeros((), jnp.int32)
-        pidx = (layer, zero, zero, p_c, zero, zero)
-        sidx = (layer, zero, zero, p_c)
-
-        def put(pool, scl, val):
-            from repro.core.paged_kv import _zero_dead_slots
-            cur_q = jax.lax.dynamic_slice(pool, pidx, (1, B, K, 1, Ts, dh))
-            cur_s = jax.lax.dynamic_slice(scl, sidx, (1, B, K, 1))
-            page = quant.dequantize_kv_page(cur_q[0, :, :, 0],
-                                            cur_s[0, :, :, 0], kv_quant)
-            page = jax.lax.dynamic_update_slice(
-                page, val[:, :, None, :].astype(page.dtype),
-                (zero, zero, sl[0], zero))
-            page = _zero_dead_slots(page, sl[0])
-            q2, s2 = quant.quantize_kv_page(page, kv_quant)
-            q2 = jnp.where(owned, q2[:, :, None][None], cur_q)
-            s2 = jnp.where(owned, s2[:, :, None][None], cur_s)
-            return (jax.lax.dynamic_update_slice(pool, q2, pidx),
-                    jax.lax.dynamic_update_slice(scl, s2, sidx))
-
-        kp, ks = put(kp, ks, kn)
-        vp, vs = put(vp, vs, vn)
-        return kp, vp, ks, vs
-
-    if kv_quant != "none":
-        return shard_map(local_quant, mesh=mesh,
-                         in_specs=(pspec, pspec, sspec, sspec, nspec, nspec,
-                                   lspec, lspec, P()),
-                         out_specs=(pspec, pspec, sspec, sspec),
-                         check_vma=False)(
-            pool_k, pool_v, k_scale, v_scale, k_new, v_new, phys, slot,
-            jnp.asarray(layer, jnp.int32))
-
-    return shard_map(local, mesh=mesh,
-                     in_specs=(pspec, pspec, nspec, nspec, lspec, lspec,
-                               P()),
-                     out_specs=(pspec, pspec), check_vma=False)(
-        pool_k, pool_v, k_new, v_new, phys, slot,
-        jnp.asarray(layer, jnp.int32))
 
 
 def sharded_prefill_fill(pool, kv_seq, layer, mesh: Mesh, *,
@@ -523,7 +417,6 @@ def paged_decode_attention_sharded(
     batch_axes: Sequence[str] = ("data",),
     page_axes: Sequence[str] = ("model",),
     impl: str = "auto",
-    append: Optional[Tuple] = None,   # (k_new [B,K,dh], v_new, phys, slot)
     kv_quant: str = "none",
     k_scale=None, v_scale=None,       # [B, K, NP] per-page×head scales
     partitions: int = 0,              # split of each shard's local walk
@@ -533,20 +426,10 @@ def paged_decode_attention_sharded(
     length: [B] context length INCLUDING the token being decoded.
 
     Pages sharded over `page_axes`; batch over `batch_axes`; combine via
-    psum over `page_axes` (the paper's NPU aggregation step).  When `append`
-    is given, the new token's K/V land in the owning shard *inside* the
-    shard_map (the paper's direct G2 write) — a pjit-level update on the
-    sharded page dim would lower to a full-pool ownership select per layer
-    (measured: the dominant decode HLO traffic).
-
-    Returns o, or (o, new_k_pages, new_v_pages) when appending.
+    psum over `page_axes` (the paper's NPU aggregation step).  The new
+    token's K/V are already in the owning shard (`sharded_append`).
     """
     from repro.kernels.paged_attention.ops import paged_attention_partial
-
-    if append is not None and kv_quant != "none":
-        raise NotImplementedError(
-            "fused append+attention does not support quantized pools; "
-            "the engine appends via sharded_append_uniform instead")
 
     n_page_shards = 1
     for a in page_axes:
@@ -558,7 +441,6 @@ def paged_decode_attention_sharded(
     sspec = P(bspec, None, _axes_spec(page_axes))
     basespec = P(bspec, _axes_spec(page_axes))
     lenspec = P(bspec)
-    nspec = P(bspec, None, None)
 
     def run(qq, kp, vp, base, ln, ks=None, vs=None):
         o, m, l = paged_attention_partial(qq, kp, vp, base, ln,
@@ -570,30 +452,16 @@ def paged_decode_attention_sharded(
             o = combine_partials(o, m, l, tuple(page_axes))
         return o.astype(qq.dtype)
 
-    if append is None and kv_quant != "none":
+    if kv_quant != "none":
         return shard_map(run, mesh=mesh,
                          in_specs=(qspec, pspec, pspec, basespec, lenspec,
                                    sspec, sspec),
                          out_specs=qspec, check_vma=False)(
             q, k_pages, v_pages, page_base, length, k_scale, v_scale)
-
-    if append is None:
-        return shard_map(run, mesh=mesh,
-                         in_specs=(qspec, pspec, pspec, basespec, lenspec),
-                         out_specs=qspec, check_vma=False)(
-            q, k_pages, v_pages, page_base, length)
-
-    def run_append(qq, kp, vp, base, ln, kn, vn, phys, slot):
-        kp = local_append_uniform(kp, phys, slot, kn, page_axes)
-        vp = local_append_uniform(vp, phys, slot, vn, page_axes)
-        return run(qq, kp, vp, base, ln), kp, vp
-
-    k_new, v_new, phys, slot = append
-    return shard_map(run_append, mesh=mesh,
-                     in_specs=(qspec, pspec, pspec, basespec, lenspec,
-                               nspec, nspec, lenspec, lenspec),
-                     out_specs=(qspec, pspec, pspec), check_vma=False)(
-        q, k_pages, v_pages, page_base, length, k_new, v_new, phys, slot)
+    return shard_map(run, mesh=mesh,
+                     in_specs=(qspec, pspec, pspec, basespec, lenspec),
+                     out_specs=qspec, check_vma=False)(
+        q, k_pages, v_pages, page_base, length)
 
 
 # ---------------------------------------------------------------------------
@@ -666,54 +534,61 @@ def paged_decode_attention_sharded_shared(
         q, k_pages, v_pages, page_table, page_base, length)
 
 
-def sharded_append_shared(pool_k, pool_v, layer, k_new, v_new, phys, slot,
-                          mesh: Mesh, *,
-                          batch_axes: Sequence[str] = ("data",),
-                          page_axes: Sequence[str] = ("model",),
-                          k_scale=None, v_scale=None,
-                          kv_quant: str = "none"):
-    """One-token append into FULL stacked shared pools [L, K, P, T, dh]
-    at a traced layer index: the shard owning each sequence's physical
-    page scatters locally; everyone else's write drops (ragged positions,
-    so this is the continuous-batching path on a mesh).
+def sharded_append(pool_k, pool_v, layer, k_new, v_new, phys, slot,
+                   mesh: Mesh, *,
+                   batch_axes: Sequence[str] = ("data",),
+                   page_axes: Sequence[str] = ("model",),
+                   k_scale=None, v_scale=None,
+                   kv_quant: str = "none"):
+    """One-token append into FULL stacked pools at a traced layer index,
+    inside the shard that owns each row's page (the paper's direct G2-die
+    write; a pjit-level update on the sharded page axis would lower to a
+    full-pool ownership select per layer).  Each shard turns a row's
+    global physical page `phys` into a local one, or into its drop
+    sentinel when another shard owns it, and runs `paged_kv`'s own ragged
+    writer on its local pool (pool writes stay in paged_kv.py, KV004).
+
+    Striped pools [L, B, K, NP, T, dh] split their rows over `batch_axes`
+    with the new tokens.  Shared pools [L, K, P, T, dh] have no batch
+    axis, so over the batch axes the pool is replicated: every replica
+    must apply the SAME full-batch append or the copies diverge, and the
+    new tokens ([B, K, dh] against a pool measured in GB) and positions
+    are replicated into the shard_map.
 
     Returns (k, v) or (k, v, k_scale, v_scale) when quantized.
-
-    NB: the shared pool has no batch dim, so over any BATCH mesh axes the
-    pool is replicated — every replica must apply the SAME full-batch
-    append or the copies diverge.  The new-token values/positions are
-    therefore replicated into the shard_map (a [B, K, dh] vector against
-    a pool measured in GB), and only the PAGE axes select which shard's
-    local range actually lands the write.
     """
     from repro.core import paged_kv as pk
 
-    del batch_axes                       # see NB above — values replicate
-    pspec = P(None, None, _axes_spec(page_axes), None, None)
-    sspec = P(None, None, _axes_spec(page_axes))
-    nspec = P(None, None, None)
-    lspec = P(None)
+    shared = pool_k.ndim == 5
+    page_spec = _axes_spec(page_axes)
+    if shared:
+        bspec, page_axis = None, 2
+        pspec = P(None, None, page_spec, None, None)
+        sspec = P(None, None, page_spec)
+        append, append_quant = (pk.append_global_shared,
+                                pk.append_token_quant_shared)
+    else:
+        bspec, page_axis = _axes_spec(batch_axes), 3
+        pspec = P(None, bspec, None, page_spec, None, None)
+        sspec = P(None, bspec, None, page_spec)
+        append, append_quant = (pk.append_token_inplace,
+                                pk.append_token_quant)
+    nspec = P(bspec, None, None)
+    lspec = P(bspec)
+
+    def local_pages(kp, ph):
+        n = kp.shape[page_axis]
+        ph_loc = ph - _shard_page_offset(page_axes, n)
+        return jnp.where((ph_loc >= 0) & (ph_loc < n), ph_loc, n)
 
     def local(kp, vp, kn, vn, ph, sl, lyr):
-        P_local = kp.shape[2]
-        off = _shard_page_offset(page_axes, P_local)
-        ph_loc = ph - off
-        ph_drop = jnp.where((ph_loc >= 0) & (ph_loc < P_local), ph_loc,
-                            P_local)
-        kp = pk.append_global_shared(kp, lyr, ph_drop, sl, kn)
-        vp = pk.append_global_shared(vp, lyr, ph_drop, sl, vn)
-        return kp, vp
+        ph = local_pages(kp, ph)
+        return (append(kp, lyr, ph, sl, kn), append(vp, lyr, ph, sl, vn))
 
     def local_quant(kp, vp, ks, vs, kn, vn, ph, sl, lyr):
-        P_local = kp.shape[2]
-        off = _shard_page_offset(page_axes, P_local)
-        ph_loc = ph - off
-        ph_drop = jnp.where((ph_loc >= 0) & (ph_loc < P_local), ph_loc,
-                            P_local)
-        kp, ks = pk.append_token_quant_shared(kp, ks, lyr, ph_drop, sl, kn,
-                                              kv_quant)
-        vp, vs = pk.append_token_quant_shared(vp, vs, lyr, ph_drop, sl, vn,
-                                              kv_quant)
+        ph = local_pages(kp, ph)
+        kp, ks = append_quant(kp, ks, lyr, ph, sl, kn, kv_quant)
+        vp, vs = append_quant(vp, vs, lyr, ph, sl, vn, kv_quant)
         return kp, vp, ks, vs
 
     lyr = jnp.asarray(layer, jnp.int32)

@@ -114,8 +114,6 @@ def main():
     ap.add_argument("--multi-pod-only", action="store_true")
     ap.add_argument("--single-pod-only", action="store_true")
     ap.add_argument("--quant", default=None, choices=["w8a8", "w4a16"])
-    ap.add_argument("--variant", default=None,
-                    choices=["compact", "discrete"])
     ap.add_argument("--page-tokens", type=int, default=None)
     ap.add_argument("--cell-timeout", type=int, default=1800)
     args = ap.parse_args()
@@ -123,8 +121,6 @@ def main():
     overrides = {}
     if args.quant:
         overrides["quant"] = args.quant
-    if args.variant:
-        overrides["variant"] = args.variant
     if args.page_tokens:
         overrides["page_tokens"] = args.page_tokens
 

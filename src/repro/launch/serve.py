@@ -14,7 +14,7 @@ import jax
 import numpy as np
 
 from repro.configs import EngineConfig
-from repro.core.dse import recommend_engine_config
+from repro.core.dse import recommend_engine_config, recommend_point
 from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.api import (KVNANDServer, SamplingParams, ServerConfig,
                                accepted_tokens_per_step,
@@ -43,7 +43,7 @@ def serve(argv=None):
     ap.add_argument("--chunk-tokens", type=int, default=64,
                     help="prefill chunk size (multiple of page_tokens)")
     ap.add_argument("--use-dse", action="store_true",
-                    help="pick variant/quant from the Track-A DSE")
+                    help="pick the engine knobs from the Track-A DSE")
     ap.add_argument("--shared-pool", action="store_true",
                     help="shared-pool paged KV (§IV-D FTL mapping): one "
                     "physical page pool, admission by free pages, "
@@ -99,13 +99,12 @@ def serve(argv=None):
     if args.use_dse:
         eng = recommend_engine_config(args.arch, args.max_context)
         eng = EngineConfig(**{**eng.__dict__, "page_tokens": 16,
-                              "uniform_lengths": False, "quant": "none",
-                              **pool_kw})
-        print(f"[serve] DSE picked variant={eng.variant} "
+                              "quant": "none", **pool_kw})
+        point = recommend_point(args.arch, args.max_context)
+        print(f"[serve] DSE picked {point.system if point else 'no fit'} "
               f"kv_quant={eng.kv_quant}")
     else:
-        eng = EngineConfig(page_tokens=16, uniform_lengths=False,
-                           **pool_kw)
+        eng = EngineConfig(page_tokens=16, **pool_kw)
 
     spec_k = (args.speculation_k if args.speculation_k is not None
               else eng.speculation_k)

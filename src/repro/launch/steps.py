@@ -4,7 +4,7 @@ inputs + sharding trees.  Used by the dry-run, the drivers, and benchmarks.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,23 +33,6 @@ def runtime_for(cfg: ModelConfig, kind: str = "serve") -> Runtime:
     # temp allocation at 150K–260K vocabs (§Perf iteration T1)
     return Runtime(activ_dtype=jnp.bfloat16, attn_impl="ref",
                    loss_chunk=1024 if kind == "train" else 0)
-
-
-def engine_config_for(cfg: ModelConfig, shape: ShapeConfig,
-                      overrides: Optional[Dict[str, Any]] = None
-                      ) -> EngineConfig:
-    kw: Dict[str, Any] = dict(remat="block")
-    if shape.kind == "decode":
-        # The compact plan computes page validity/masks once instead of
-        # per head-group, and the paper's HG overlap is a property of
-        # fixed-function flash dies that a TPU's fungible MXUs do not
-        # share — so compact is the decode default.  (Not measured on a
-        # chip.)  The paper-faithful discrete plan remains selectable
-        # (--variant discrete).
-        kw.update(variant="compact")
-    if overrides:
-        kw.update(overrides)
-    return EngineConfig(**kw)
 
 
 def _axes_or_none(axes: Tuple[str, ...]):
@@ -198,7 +181,7 @@ def build_train_cell(arch: str, mesh: Mesh, *, multi_pod: bool,
         overrides = {"embed": None, "moe_mlp": "data"}
     rules = shd.make_rules(fsdp=fsdp, multi_pod=multi_pod,
                            overrides=overrides)
-    eng = engine_config_for(cfg, shape, eng_overrides)
+    eng = EngineConfig(**(eng_overrides or {}))
 
     aparams, specs = transformer.abstract_params(cfg, jnp.bfloat16)
     params_sh = shd.tree_shardings(aparams, specs, rules, mesh)
@@ -270,7 +253,7 @@ def build_prefill_cell(arch: str, mesh: Mesh, *, multi_pod: bool,
     shape = SHAPES["prefill_32k"]
     rt = runtime_for(cfg)
     rules = shd.make_rules(fsdp=False, multi_pod=multi_pod)
-    eng = engine_config_for(cfg, shape, eng_overrides)
+    eng = EngineConfig(**(eng_overrides or {}))
     engine = KVNANDEngine(cfg, eng, rt, mesh)
 
     aparams, specs = transformer.abstract_params(cfg, jnp.bfloat16)
@@ -295,7 +278,7 @@ def build_prefill_cell(arch: str, mesh: Mesh, *, multi_pod: bool,
     jitted = jax.jit(prefill_step, in_shardings=(params_sh, batch_sh),
                      out_shardings=(None, cache_sh))
     return Cell(arch, shape, "prefill", jitted, (aparams, abatch), mesh.size,
-                note=f"variant={eng.variant}",
+                note=f"quant={eng.quant}",
                 fusible_last2=_train_fusible_hints(cfg, shape.seq_len, mesh))
 
 
@@ -305,7 +288,7 @@ def build_decode_cell(arch: str, shape_name: str, mesh: Mesh, *,
     shape = SHAPES[shape_name]
     rt = runtime_for(cfg)
     rules = shd.make_rules(fsdp=False, multi_pod=multi_pod)
-    eng = engine_config_for(cfg, shape, eng_overrides)
+    eng = EngineConfig(**(eng_overrides or {}))
     engine = KVNANDEngine(cfg, eng, rt, mesh)
 
     aparams, specs = transformer.abstract_params(cfg, jnp.bfloat16)
@@ -330,8 +313,7 @@ def build_decode_cell(arch: str, shape_name: str, mesh: Mesh, *,
                      out_shardings=(None, cache_sh), donate_argnums=(1,))
     return Cell(arch, shape, "decode", jitted, (aparams, acache, atoks),
                 mesh.size,
-                note=f"variant={eng.variant} quant={eng.quant} "
-                     f"pages={plan.page_axes_g}",
+                note=f"quant={eng.quant} pages={plan.page_axes_g}",
                 fusible_last2=_decode_fusible_hints(cfg, acache, eng, mesh,
                                                     plan))
 

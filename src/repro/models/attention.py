@@ -1,14 +1,12 @@
 """Attention block: projections (+QKV bias), RoPE, flash-attention call.
 
 Weight layout is *head-group-major*: wq [K, D, G·dh], wk/wv [K, D, dh]
-(K = kv heads, G = q heads per group).  Head-group g's projection is a plain
-index on the unsharded K dim — the KVNAND-D head-group pipeline slices
-groups without touching the sharded feature dim (no resharding, no
-all-gather of weights).  Head order is therefore kv-major (h = k·G + g),
-which is exactly the GQA convention the kernels assume (kv head = h // G).
+(K = kv heads, G = q heads per group), so head order is kv-major
+(h = k·G + g), which is exactly the GQA convention the kernels assume
+(kv head = h // G).
 
 Exposes split phases (`project_qkv` / `project_out`) so the decode engine
-can interpose the paged KV cache and the head-group pipeline between them.
+can interpose the paged KV cache between them.
 """
 from __future__ import annotations
 
@@ -74,33 +72,6 @@ def project_qkv(
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
-
-
-def project_q_group(params, cfg: ModelConfig, x_tok: jax.Array,
-                    group: jax.Array, positions: jax.Array, *,
-                    rope: bool = True) -> jax.Array:
-    """One head-group's q projection (the KVNAND-D pipelined GEMV).
-
-    x_tok: [B, D] (single decode token); group: scalar index; returns
-    [B, G, dh] roped at `positions` [B].
-    """
-    w = params["wq_w"]
-    if type(w).__name__ == "QuantizedWeight":
-        from repro.core.quant import dequantize
-        w = dequantize(w, x_tok.dtype)
-    wg = jax.lax.dynamic_index_in_dim(w, group, 0, keepdims=False)  # [D, G·dh]
-    q = jnp.einsum("bd,df->bf", x_tok, wg.astype(x_tok.dtype))
-    b = params.get("wq_b")
-    if b is not None:
-        q = q + jax.lax.dynamic_index_in_dim(b, group, 0,
-                                             keepdims=False).astype(q.dtype)
-    B = x_tok.shape[0]
-    q = q.reshape(B, 1, cfg.group_size, cfg.d_head)
-    if "q_norm" in params:
-        q = rms_norm(q, params["q_norm"], cfg.norm_eps)
-    if not rope:
-        return q[:, 0]
-    return apply_rope(q, positions[:, None], cfg.rope_theta)[:, 0]
 
 
 def use_rope(cfg: ModelConfig, is_global) -> bool:

@@ -21,8 +21,8 @@ Chunked prefill interleaved with batched decode:
     so a steady stream of admits can never starve the decoders, and an
     idle decode batch drains the admission queue at full tilt;
   * decode steps carry an `active` mask so slots that are empty or still
-    mid-prefill get no append / length advance (the ragged scatter path,
-    `uniform_lengths=False`);
+    mid-prefill get no append / length advance (each row's append is its
+    own, so rows advance out of lockstep);
   * per-slot prefill progress (cursor into the prompt, sampled-token
     handoff; ring base positions live in the cache) is host bookkeeping —
     `_PrefillState`;
@@ -248,13 +248,7 @@ class ContinuousBatcher:
                  step_token_budget: Optional[int] = None,
                  speculation_k: int = 0, tier_prefetch: bool = True,
                  device: Optional[jax.Device] = None):
-        eng = eng or EngineConfig(page_tokens=16, uniform_lengths=False)
-        if eng.uniform_lengths:
-            raise ValueError(
-                "continuous batching needs the ragged append path: pass "
-                "an EngineConfig with uniform_lengths=False (slots advance "
-                "out of lockstep, and masked decode steps require the "
-                "per-sequence scatter)")
+        eng = eng or EngineConfig(page_tokens=16)
         if prefill_chunk_tokens % eng.page_tokens:
             raise ValueError(
                 f"prefill_chunk_tokens={prefill_chunk_tokens} must be a "

@@ -35,8 +35,7 @@ def _model():
 def _server(scheduler="interleaved", eng_kw=None, slots=2, ctx=96,
             chunk=16, **kw):
     cfg, params = _model()
-    eng = EngineConfig(page_tokens=16, uniform_lengths=False,
-                       **(eng_kw or {}))
+    eng = EngineConfig(page_tokens=16, **(eng_kw or {}))
     return KVNANDServer(
         ServerConfig(scheduler=scheduler, engine=eng, batch_slots=slots,
                      max_context=ctx, prefill_chunk_tokens=chunk, **kw),

@@ -37,8 +37,8 @@ def _configs(slots=2, max_queue=32, overlap=True):
     cfg, params = _model()
     return dict(
         config=ServerConfig(
-            engine=EngineConfig(page_tokens=16, uniform_lengths=False,
-                                shared_pool=True, total_pages=64),
+            engine=EngineConfig(page_tokens=16, shared_pool=True,
+                                total_pages=64),
             batch_slots=slots, max_context=96, prefill_chunk_tokens=16),
         async_config=AsyncServerConfig(max_queue=max_queue,
                                        overlap=overlap),
@@ -137,11 +137,15 @@ def test_stop_token_finish_over_http(srv):
     status, _, body = _post(srv.address,
                             {"prompt": PROMPT, "max_tokens": 8})
     toks = json.loads(body)["choices"][0]["token_ids"]
+    # stop on the first token that the greedy stream has not emitted
+    # before it (it may repeat a token)
+    j = next((j for j in range(1, len(toks)) if toks[j] not in toks[:j]),
+             0)
     status, _, body = _post(srv.address, {
-        "prompt": PROMPT, "max_tokens": 8, "stop_token_ids": [toks[1]]})
+        "prompt": PROMPT, "max_tokens": 8, "stop_token_ids": [toks[j]]})
     choice = json.loads(body)["choices"][0]
     assert choice["finish_reason"] == "stop"
-    assert choice["token_ids"] == toks[:2]
+    assert choice["token_ids"] == toks[:j + 1]
 
 
 # ---------------------------------------------------------------------------

@@ -152,8 +152,8 @@ def _decode_step_ops(sd, slots, max_context, arch="qwen1.5-0.5b"):
     Pallas kernel) at the full width of `arch`, compiled for `sd`."""
     name, cut = ARCHS[arch]
     cfg, rt = dataclasses.replace(get_config(name), **cut), Runtime()
-    eng = KVNANDEngine(cfg, EngineConfig(page_tokens=T, uniform_lengths=False,
-                                         attn_impl="pallas"), rt)
+    eng = KVNANDEngine(cfg, EngineConfig(page_tokens=T, attn_impl="pallas"),
+                       rt)
     on = lambda tree: jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sd), tree)
     params = on(jax.eval_shape(Model(cfg, rt).init, jax.random.PRNGKey(0)))

@@ -32,7 +32,9 @@ def test_recommend_engine_config():
     eng_long = dse.recommend_engine_config("llama3.1-70b", 100_000)
     eng_short = dse.recommend_engine_config("llama3.1-70b", 128)
     assert eng_long.quant in ("w4a16", "w8a8")
-    assert eng_short.variant in ("compact", "discrete")
+    point = dse.recommend_point("llama3.1-70b", 128)
+    assert point.system.startswith(("KVNAND-C", "KVNAND-D"))
+    assert eng_short.quant == ("w4a16" if point.wbits == 4 else "w8a8")
 
 
 def test_recommend_attn_partitions_by_context():

@@ -1,7 +1,7 @@
 """Golden decode tests: prefill+decode through the KVNAND engine must
 reproduce the full-forward logits exactly (f32 cache), for every assigned
-arch × both variants.  This exercises paged pools (global + window ring),
-the head-group pipeline, RWKV/SSM state carry, and whisper cross-attention.
+arch.  This exercises paged pools (global + window ring), RWKV/SSM state
+carry, and whisper cross-attention.
 """
 import jax
 import jax.numpy as jnp
@@ -13,15 +13,14 @@ from repro.models.registry import Model
 from repro.models.transformer import Runtime
 
 
-def run_golden(arch, variant, n_decode=3, S_prompt=21, page_tokens=8):
+def run_golden(arch, n_decode=3, S_prompt=21, page_tokens=8):
     cfg = get_config(arch).reduced()
     cap = (cfg.n_experts / cfg.top_k) if cfg.is_moe else 1.25  # no-drop MoE
     rt = Runtime(moe_capacity=cap)
     m = Model(cfg, rt)
     params = m.init(jax.random.PRNGKey(0))
     eng = KVNANDEngine(
-        cfg, EngineConfig(variant=variant, page_tokens=page_tokens,
-                          kv_dtype="float32"), rt)
+        cfg, EngineConfig(page_tokens=page_tokens, kv_dtype="float32"), rt)
     B = 2
     toks = jax.random.randint(jax.random.PRNGKey(42),
                               (B, S_prompt + n_decode), 0, cfg.vocab_size,
@@ -50,18 +49,12 @@ def run_golden(arch, variant, n_decode=3, S_prompt=21, page_tokens=8):
 
 @pytest.mark.parametrize("arch", ASSIGNED_ARCHS)
 def test_decode_matches_forward_compact(arch):
-    assert run_golden(arch, "compact") < 2e-4
-
-
-@pytest.mark.parametrize("arch", ["qwen2.5-32b", "gemma3-12b", "hymba-1.5b",
-                                  "dbrx-132b", "whisper-base"])
-def test_decode_matches_forward_discrete(arch):
-    assert run_golden(arch, "discrete") < 2e-4
+    assert run_golden(arch) < 2e-4
 
 
 def test_window_ring_recycling():
     """Decode past the window: ring pages recycle, logits stay faithful."""
-    assert run_golden("gemma3-12b", "compact", n_decode=8, S_prompt=70,
+    assert run_golden("gemma3-12b", n_decode=8, S_prompt=70,
                       page_tokens=8) < 2e-4
 
 
@@ -71,8 +64,8 @@ def test_ragged_lengths_path():
     rt = Runtime()
     m = Model(cfg, rt)
     params = m.init(jax.random.PRNGKey(0))
-    eng = KVNANDEngine(cfg, EngineConfig(page_tokens=8, kv_dtype="float32",
-                                         uniform_lengths=False), rt)
+    eng = KVNANDEngine(cfg, EngineConfig(page_tokens=8, kv_dtype="float32"),
+                       rt)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 24), 0,
                               cfg.vocab_size, jnp.int32)
     logits_full, _ = m.forward(params, {"tokens": toks})

@@ -17,8 +17,8 @@ from repro.serving.scheduler import (ContinuousBatcher, Request,
 
 ARCH = "qwen1.5-0.5b"
 
-F32 = dict(page_tokens=16, uniform_lengths=False, kv_dtype="float32")
-KV8 = dict(page_tokens=16, uniform_lengths=False, kv_quant="kv8")
+F32 = dict(page_tokens=16, kv_dtype="float32")
+KV8 = dict(page_tokens=16, kv_quant="kv8")
 
 PROMPTS = [list(range(1, 8)), list(range(3, 24)), list(range(2, 13)),
            [5, 4, 3]]
@@ -207,8 +207,8 @@ def test_chunk_window_fill_padded_chunk_wider_than_ring():
 
 def test_engine_prefill_chunk_matches_full():
     cfg, rt, params = _model()
-    eng = KVNANDEngine(cfg, EngineConfig(page_tokens=8, kv_dtype="float32",
-                                         uniform_lengths=False), rt)
+    eng = KVNANDEngine(cfg, EngineConfig(page_tokens=8, kv_dtype="float32"),
+                       rt)
     B, ctx, n, C = 3, 64, 21, 16
     prompt = jnp.arange(1, n + 1, dtype=jnp.int32)[None]
     lg_ref, c1 = eng.prefill(params, {"tokens": prompt}, ctx)
@@ -237,8 +237,8 @@ def test_engine_active_mask_freezes_inactive_slots():
     """A decode step with an active mask must leave inactive slots'
     stripes and lengths bit-identical."""
     cfg, rt, params = _model()
-    eng = KVNANDEngine(cfg, EngineConfig(page_tokens=8, kv_dtype="float32",
-                                         uniform_lengths=False), rt)
+    eng = KVNANDEngine(cfg, EngineConfig(page_tokens=8, kv_dtype="float32"),
+                       rt)
     cache = eng.init_cache(2, 64)
     _, cache = eng.prefill_chunk(
         params, cache, {"tokens": jnp.arange(1, 17, dtype=jnp.int32)[None]},
@@ -259,7 +259,7 @@ def test_decode_step_kernel_in_place_matches_ref():
     inactive: same logits for the active rows, same pools, and the
     inactive row's stripe and length untouched."""
     cfg, rt, params = _model()
-    kw = dict(page_tokens=8, kv_dtype="float32", uniform_lengths=False)
+    kw = dict(page_tokens=8, kv_dtype="float32")
     B, ctx = 3, 64
     cache0 = None
     for impl in ("ref", "interpret"):

@@ -51,7 +51,7 @@ def test_100k_decode_shared_kv8():
     rt = Runtime()
     params = Model(cfg, rt).init(jax.random.PRNGKey(0))
     eng = EngineConfig(shared_pool=True, kv_quant="kv8",
-                       page_tokens=PAGE_T, uniform_lengths=False)
+                       page_tokens=PAGE_T)
     api = KVNANDEngine(cfg, eng, rt)
 
     # the auto ladder actually splits at this page count
@@ -77,8 +77,7 @@ def test_100k_decode_partition_count_invariant():
     logits = []
     for parts in (1, 0):           # monolithic vs auto (16 at 1568 pages)
         eng = EngineConfig(shared_pool=True, kv_quant="kv8",
-                           page_tokens=PAGE_T, uniform_lengths=False,
-                           attn_partitions=parts)
+                           page_tokens=PAGE_T, attn_partitions=parts)
         api = KVNANDEngine(cfg, eng, rt)
         cache = _fabricate_cache(api, cfg)
         lg, _ = api.decode_step(params, cache, jnp.array([[7]], jnp.int32))

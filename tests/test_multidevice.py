@@ -58,20 +58,25 @@ with jax.set_mesh(mesh):
 o_ref, _, _ = paged_attention_partial(q, kp, vp, base, length, impl="ref")
 np.testing.assert_allclose(np.asarray(o_sh), np.asarray(o_ref),
                            atol=3e-5, rtol=3e-5)
-# in-shard uniform append == direct write
-pool_k = jnp.zeros((L, B, K, NP, T, dh))
-pool_v = jnp.zeros((L, B, K, NP, T, dh))
-k1 = jax.random.normal(ks[3], (B, K, dh))
-v1 = jax.random.normal(ks[4], (B, K, dh))
-phys = jnp.full((B,), 5, jnp.int32)   # page 5 -> owned by shard 2 of 4
-slot = jnp.full((B,), 7, jnp.int32)
+# in-shard ragged append == direct write: rows on pages that shards 0, 1
+# and 2 of 4 own, and one row at the drop sentinel (page NP)
+ks2 = jax.random.split(ks[3], 2)
+pool_k = jax.random.normal(ks2[0], (L, B, K, NP, T, dh))
+pool_v = -pool_k
+k1 = jax.random.normal(ks2[1], (B, K, dh))
+v1 = 2.0 * k1
+phys = jnp.array([0, 3, 5, NP], jnp.int32)
+slot = jnp.array([7, 0, 15, 4], jnp.int32)
 with jax.set_mesh(mesh):
-    nk, nv = jax.jit(lambda *a: seqpar.sharded_append_uniform(
+    nk, nv = jax.jit(lambda *a: seqpar.sharded_append(
         *a, mesh, batch_axes=("data",), page_axes=("model",)))(
         pool_k, pool_v, 1, k1, v1, phys, slot)
-expect = pool_k.at[1, :, :, 5, 7].set(k1)
-np.testing.assert_allclose(np.asarray(nk), np.asarray(expect), atol=1e-6)
-assert float(jnp.abs(nv[0]).max()) == 0.0
+ek, ev = pool_k, pool_v
+for b_ in range(3):
+    ek = ek.at[1, b_, :, phys[b_], slot[b_]].set(k1[b_])
+    ev = ev.at[1, b_, :, phys[b_], slot[b_]].set(v1[b_])
+np.testing.assert_array_equal(np.asarray(nk), np.asarray(ek))
+np.testing.assert_array_equal(np.asarray(nv), np.asarray(ev))
 print("paged sharded OK")
 """)
 
@@ -311,11 +316,10 @@ def chunked(engine, cache, use_jit):
         lg, cache = fn(params, cache, toks[:, c0:c0 + C], 2,
                        jnp.asarray(c0, jnp.int32), jnp.asarray(cl, jnp.int32))
     return lg, cache
-eng1 = KVNANDEngine(cfg, EngineConfig(page_tokens=4, kv_dtype="float32",
-                                      uniform_lengths=False), rt)
+eng1 = KVNANDEngine(cfg, EngineConfig(page_tokens=4, kv_dtype="float32"), rt)
 lg1, _ = chunked(eng1, eng1.init_cache(4, ctx), False)
-engN = KVNANDEngine(cfg, EngineConfig(page_tokens=4, kv_dtype="float32",
-                                      uniform_lengths=False), rt, mesh=mesh)
+engN = KVNANDEngine(cfg, EngineConfig(page_tokens=4, kv_dtype="float32"), rt,
+                    mesh=mesh)
 with jax.set_mesh(mesh):
     lgN, _ = chunked(engN, engN.init_cache(4, ctx), True)
 np.testing.assert_allclose(np.asarray(lg1), np.asarray(lgN),
@@ -359,7 +363,7 @@ slot = jnp.array([0, 5, 15, 2], jnp.int32)
 kn = jax.random.normal(ks[3], (B, K, dh))
 with jax.set_mesh(mesh):
     ok, ov = jax.jit(lambda kp, vp, kn, vn, ph, sl:
-                     seqpar.sharded_append_shared(
+                     seqpar.sharded_append(
                          kp, vp, 1, kn, vn, ph, sl, mesh,
                          batch_axes=("data",), page_axes=("model",)))(
         pools_k, pools_v, kn, -kn, phys, slot)

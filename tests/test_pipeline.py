@@ -42,7 +42,7 @@ POOLS = {
 def _server(pool="striped", *, overlap, spec_k=0, slots=2, ctx=96,
             chunk=16, **kw):
     cfg, params = _model()
-    eng = EngineConfig(page_tokens=16, uniform_lengths=False, **POOLS[pool])
+    eng = EngineConfig(page_tokens=16, **POOLS[pool])
     return KVNANDServer(
         ServerConfig(engine=eng, batch_slots=slots, max_context=ctx,
                      prefill_chunk_tokens=chunk, overlap=overlap,

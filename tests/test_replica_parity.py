@@ -36,8 +36,8 @@ def model():
 
 def _server(model, kv_quant="none", hot_pages=0, slots=3):
     cfg, rt, params = model
-    eng = EngineConfig(page_tokens=16, uniform_lengths=False,
-                       shared_pool=True, total_pages=TOTAL_PAGES,
+    eng = EngineConfig(page_tokens=16, shared_pool=True,
+                       total_pages=TOTAL_PAGES,
                        hot_pages=hot_pages, kv_quant=kv_quant)
     sc = ServerConfig(arch="qwen1.5-0.5b", reduced=True, engine=eng,
                       batch_slots=slots, max_context=64,

@@ -55,8 +55,8 @@ def clock(monkeypatch):
 
 def _server(model, slots=2):
     cfg, rt, params = model
-    eng = EngineConfig(page_tokens=16, uniform_lengths=False,
-                       shared_pool=True, total_pages=TOTAL_PAGES)
+    eng = EngineConfig(page_tokens=16, shared_pool=True,
+                       total_pages=TOTAL_PAGES)
     sc = ServerConfig(arch="qwen1.5-0.5b", reduced=True, engine=eng,
                       batch_slots=slots, max_context=64,
                       prefill_chunk_tokens=16, seed=7)
@@ -155,8 +155,8 @@ def test_replicas_on_distinct_devices(model):
 
     cfg, rt, params = model
     devs = jax.devices()
-    eng = EngineConfig(page_tokens=16, uniform_lengths=False,
-                       shared_pool=True, total_pages=TOTAL_PAGES)
+    eng = EngineConfig(page_tokens=16, shared_pool=True,
+                       total_pages=TOTAL_PAGES)
     sc = ServerConfig(arch="qwen1.5-0.5b", reduced=True, engine=eng,
                       batch_slots=2, max_context=64,
                       prefill_chunk_tokens=16, seed=7)

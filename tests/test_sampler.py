@@ -118,7 +118,7 @@ def test_topk_then_topp_compose_sequentially():
     assert set(toks.tolist()) == {0}
 
 
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)   # the first example compiles
 @given(seed=st.integers(0, 2**16),
        temp=st.floats(0.0, 4.0),
        tk=st.integers(0, 12),

@@ -134,8 +134,7 @@ def test_scheduler_with_quantized_kv():
     """Continuous batching over kv8 pools: ragged requantizing appends +
     jitted splice of the scale leaves."""
     cfg, rt, params = _model()
-    eng = EngineConfig(page_tokens=16, uniform_lengths=False,
-                       kv_quant="kv8")
+    eng = EngineConfig(page_tokens=16, kv_quant="kv8")
     outs = _run(cfg, params, PROMPTS[:2], bucket=True, max_new=4, eng=eng)
     assert sorted(outs) == [0, 1]
     assert all(len(v) == 4 for v in outs.values())

@@ -37,9 +37,8 @@ def _drain(cfg, params, eng, prompts, *, slots=2, ctx=96, chunk=16,
 
 
 def _engs(**kw):
-    stripe = EngineConfig(page_tokens=16, uniform_lengths=False, **kw)
-    shared = EngineConfig(page_tokens=16, uniform_lengths=False,
-                          shared_pool=True, **kw)
+    stripe = EngineConfig(page_tokens=16, **kw)
+    shared = EngineConfig(page_tokens=16, shared_pool=True, **kw)
     return stripe, shared
 
 
@@ -93,7 +92,7 @@ def test_oneshot_prefill_shared_matches_stripe():
     outs = []
     for shared in (False, True):
         eng = KVNANDEngine(cfg, EngineConfig(
-            page_tokens=16, uniform_lengths=False, kv_dtype="float32",
+            page_tokens=16, kv_dtype="float32",
             shared_pool=shared), rt)
         lg, cache = eng.prefill(params, {"tokens": toks}, 96)
         seq = [np.asarray(lg)]
@@ -116,8 +115,7 @@ def test_capacity_proportional_admission():
     fit the 16-page pool are admitted concurrently and drain with outputs
     identical to the stripe layout."""
     cfg, rt, params = _model()
-    shared = EngineConfig(page_tokens=16, uniform_lengths=False,
-                          kv_dtype="float32", shared_pool=True,
+    shared = EngineConfig(page_tokens=16, kv_dtype="float32", shared_pool=True,
                           total_pages=16)
     prompts = [list(range(1 + i, 12 + i)) for i in range(6)]
     o2, b = _drain(cfg, params, shared, prompts, slots=6, ctx=128)
@@ -127,8 +125,7 @@ def test_capacity_proportional_admission():
     assert 6 * npg > b.stats["pool_total_pages"]   # old layout: impossible
     assert b.stats["pool_peak_pages"] <= 16
     b.alloc.check()
-    stripe = EngineConfig(page_tokens=16, uniform_lengths=False,
-                          kv_dtype="float32")
+    stripe = EngineConfig(page_tokens=16, kv_dtype="float32")
     o1, _ = _drain(cfg, params, stripe, prompts, slots=6, ctx=128)
     assert o1 == o2
 
@@ -136,8 +133,7 @@ def test_capacity_proportional_admission():
 def test_admission_waits_for_pages_then_drains():
     """A pool two requests wide: the third waits, no deadlock, FIFO kept."""
     cfg, rt, params = _model()
-    shared = EngineConfig(page_tokens=16, uniform_lengths=False,
-                          kv_dtype="float32", shared_pool=True,
+    shared = EngineConfig(page_tokens=16, kv_dtype="float32", shared_pool=True,
                           total_pages=4)
     prompts = [list(range(1, 18))] * 3          # 2 pages each incl. max_new
     o, b = _drain(cfg, params, shared, prompts, slots=3, ctx=96)
@@ -150,8 +146,7 @@ def test_admission_discounts_pinned_cache_pages():
     count them as evictable slack: an exact repeat whose growth does not
     fit must WAIT (not crash the allocator mid-flight)."""
     cfg, rt, params = _model()
-    shared = EngineConfig(page_tokens=16, uniform_lengths=False,
-                          kv_dtype="float32", shared_pool=True,
+    shared = EngineConfig(page_tokens=16, kv_dtype="float32", shared_pool=True,
                           total_pages=10)
     b = ContinuousBatcher(cfg, params, batch_slots=2, max_context=160,
                           temperature=0.0, eng=shared,
@@ -173,8 +168,7 @@ def test_admission_discounts_pinned_cache_pages():
 
 def test_submit_rejects_impossible_footprint():
     cfg, rt, params = _model()
-    shared = EngineConfig(page_tokens=16, uniform_lengths=False,
-                          kv_dtype="float32", shared_pool=True,
+    shared = EngineConfig(page_tokens=16, kv_dtype="float32", shared_pool=True,
                           total_pages=2)
     b = ContinuousBatcher(cfg, params, batch_slots=2, max_context=96,
                           eng=shared, prefill_chunk_tokens=16)

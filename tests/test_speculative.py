@@ -93,7 +93,7 @@ def test_speculative_accept_greedy_counts_leading_matches():
                          ids=["f32", "kv8"])
 def test_spec_matches_sequential_formats(kw):
     cfg, rt, params = _model()
-    eng = EngineConfig(page_tokens=16, uniform_lengths=False, **kw)
+    eng = EngineConfig(page_tokens=16, **kw)
     o0, _ = _drain(cfg, params, eng, PROMPTS, spec_k=0)
     o4, b4 = _drain(cfg, params, eng, PROMPTS, spec_k=4)
     assert o0 == o4
@@ -106,8 +106,7 @@ def test_spec_matches_sequential_window_ring():
     tokens only advance the ring bases."""
     cfg, rt, params = _model("gemma3-12b")
     prompts = PROMPTS + [list(range(1, 78))]     # > reduced window of 64
-    eng = EngineConfig(page_tokens=16, uniform_lengths=False,
-                       kv_dtype="float32")
+    eng = EngineConfig(page_tokens=16, kv_dtype="float32")
     o0, _ = _drain(cfg, params, eng, prompts, spec_k=4, max_new=4)
     o1, _ = _drain(cfg, params, eng, prompts, spec_k=0, max_new=4)
     assert o0 == o1
@@ -115,8 +114,7 @@ def test_spec_matches_sequential_window_ring():
 
 def test_spec_matches_sequential_shared_pool():
     cfg, rt, params = _model()
-    eng = EngineConfig(page_tokens=16, uniform_lengths=False,
-                       shared_pool=True, kv_dtype="float32")
+    eng = EngineConfig(page_tokens=16, shared_pool=True, kv_dtype="float32")
     o0, _ = _drain(cfg, params, eng, PROMPTS, spec_k=0)
     o4, b4 = _drain(cfg, params, eng, PROMPTS, spec_k=4)
     assert o0 == o4
@@ -131,8 +129,7 @@ def test_spec_seeded_stochastic_stream_parity():
     fold_in(seed, position) stream, so seeded outputs are identical with
     speculation on or off."""
     cfg, rt, params = _model()
-    eng = EngineConfig(page_tokens=16, uniform_lengths=False,
-                       kv_dtype="float32")
+    eng = EngineConfig(page_tokens=16, kv_dtype="float32")
     sp = SamplingParams(temperature=0.9, top_k=8, seed=123,
                         max_new_tokens=8)
     o0, _ = _drain(cfg, params, eng, PROMPTS, spec_k=0, sp=sp)
@@ -144,8 +141,7 @@ def test_spec_per_request_opt_out():
     """SamplingParams.speculation=0 keeps the request out of drafting
     (no drafts offered) without changing its tokens."""
     cfg, rt, params = _model()
-    eng = EngineConfig(page_tokens=16, uniform_lengths=False,
-                       kv_dtype="float32")
+    eng = EngineConfig(page_tokens=16, kv_dtype="float32")
     sp = SamplingParams(max_new_tokens=8, speculation=0)
     o0, _ = _drain(cfg, params, eng, [REP], spec_k=0)
     o4, b4 = _drain(cfg, params, eng, [REP], spec_k=4, sp=sp)
@@ -176,7 +172,6 @@ def test_request_output_acceptance_stats():
         ServerConfig(batch_slots=2, max_context=96,
                      prefill_chunk_tokens=16, speculation_k=4,
                      engine=EngineConfig(page_tokens=16,
-                                         uniform_lengths=False,
                                          kv_dtype="float32")),
         cfg=cfg, params=params)
     [out] = server.generate([REP], SamplingParams(max_new_tokens=12))
@@ -196,8 +191,7 @@ def test_spec_stop_token_truncates_span_and_stats():
     acceptance counters reflect EMITTED tokens only (every finish reason
     keeps len(output) == 1 + spec_accepted + spec_steps)."""
     cfg, rt, params = _model()
-    eng = EngineConfig(page_tokens=16, uniform_lengths=False,
-                       kv_dtype="float32")
+    eng = EngineConfig(page_tokens=16, kv_dtype="float32")
     # learn greedy continuation, then stop on its 3rd emitted token
     ref, _ = _drain(cfg, params, eng, [REP], spec_k=4, max_new=10)
     stop = ref[0][2]
@@ -214,8 +208,7 @@ def test_spec_stop_token_truncates_span_and_stats():
 # ---------------------------------------------------------------------------
 
 def _shared_eng(total_pages=0):
-    return EngineConfig(page_tokens=4, uniform_lengths=False,
-                        kv_dtype="float32", shared_pool=True,
+    return EngineConfig(page_tokens=4, kv_dtype="float32", shared_pool=True,
                         total_pages=total_pages)
 
 

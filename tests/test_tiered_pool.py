@@ -39,8 +39,8 @@ def _trace(vocab):
 
 
 def _eng(hot_pages=0):
-    return EngineConfig(page_tokens=16, uniform_lengths=False,
-                        shared_pool=True, total_pages=TOTAL_PAGES,
+    return EngineConfig(page_tokens=16, shared_pool=True,
+                        total_pages=TOTAL_PAGES,
                         hot_pages=hot_pages)
 
 

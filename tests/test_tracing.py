@@ -120,8 +120,7 @@ def test_engine_loop_spans_route_and_commands(model, tmp_path):
     import http.client
     import json
     cfg, params = model
-    config = ServerConfig(engine=EngineConfig(page_tokens=T,
-                                              uniform_lengths=False),
+    config = ServerConfig(engine=EngineConfig(page_tokens=T),
                           batch_slots=2, max_context=64,
                           prefill_chunk_tokens=T)
     with BackgroundServer(config, AsyncServerConfig(), cfg=cfg,
@@ -203,8 +202,7 @@ def test_walk_counters_shared_pool_use_the_table_width(model):
     fetches each row's pages up to its last, and one page of an idle
     slot."""
     cfg, params = model
-    eng = EngineConfig(page_tokens=T, uniform_lengths=False,
-                       shared_pool=True, total_pages=24)
+    eng = EngineConfig(page_tokens=T, shared_pool=True, total_pages=24)
     b = ContinuousBatcher(cfg, params, batch_slots=2, max_context=96,
                           eng=eng, prefill_chunk_tokens=T)
     assert b.engine.decode_page_visits(b.cache) == 2 * (96 // T)

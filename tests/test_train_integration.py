@@ -84,8 +84,7 @@ def test_continuous_batching_matches_sequential():
 
     batcher = ContinuousBatcher(
         cfg, params, batch_slots=2, max_context=64,
-        eng=EngineConfig(page_tokens=8, kv_dtype="float32",
-                         uniform_lengths=False))
+        eng=EngineConfig(page_tokens=8, kv_dtype="float32"))
     for uid, p in enumerate(prompts):
         batcher.submit(Request(uid=uid, prompt=list(p), max_new=6))
     done = batcher.run_to_completion()

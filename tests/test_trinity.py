@@ -102,7 +102,7 @@ def test_chunked_prefill_matches_reference(weights, tokens, ref_logits,
     """Chunks of 16 rows (every held expert on every row) and 80 rows
     (pairs sorted into ragged expert groups), then decode."""
     eng = KVNANDEngine(config(), EngineConfig(
-        page_tokens=8, kv_dtype="float32", uniform_lengths=False), RT)
+        page_tokens=8, kv_dtype="float32"), RT)
     cache = eng.init_cache(2, 128)
     chunk_fn = jax.jit(eng.prefill_chunk, static_argnames=("first",))
     n = 88
@@ -128,8 +128,7 @@ def _serve(weights, prompts, max_new=6):
     srv = KVNANDServer(
         ServerConfig(arch="trinity-mini", batch_slots=4, max_context=128,
                      prefill_chunk_tokens=16,
-                     engine=EngineConfig(page_tokens=8, kv_dtype="float32",
-                                         uniform_lengths=False)),
+                     engine=EngineConfig(page_tokens=8, kv_dtype="float32")),
         cfg=config(), params=weights)
     outs = srv.generate(prompts, SamplingParams(max_new_tokens=max_new,
                                                 logprobs=True))
